@@ -10,12 +10,11 @@ The deployment half of the LM story (gpt2_finetune.py covers tuning):
    `build_transformer` builder spec;
 3. `serve.make_server` hosts it, and `POST /v1/models/default:generate`
    returns kv-cache greedy/sampled continuations (the server casts the
-   f32 masters to the model's compute width — measured 1.6x decode
-   throughput, BASELINE.md round 3).  Requests decode through the
-   continuous-batching slot engine (round 5); `--kv_page_size/
-   --kv_pages` switch its cache to the PAGED pool (resident kv
-   proportional to actual need — measured 4x less kv and 1.8x faster
-   on a short-request mix, BASELINE.md round 5).
+   f32 masters to the model's compute width — half the weight bytes per
+   token).  Requests decode through the continuous-batching slot engine
+   (round 5); `--kv_page_size/--kv_pages` switch its cache to the PAGED
+   pool (resident kv proportional to actual need; speed on this chip:
+   not measured).
 
 Run:
     python examples/lm/llama_serve.py --new_tokens 16

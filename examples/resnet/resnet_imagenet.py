@@ -12,8 +12,8 @@ conversion shape).
 TPU-first: uint8 pixels cross host->HBM (4x less transfer than f32);
 normalization fuses into the first conv inside the step
 (image.normalize_batch).  Default model is the normalizer-free ResNet-50
-(--norm none), the HBM-optimal variant (BASELINE.md round 3: 3,082 img/s
-vs 1,973 for GroupNorm on one v5e chip).
+(--norm none), the variant with the least HBM traffic (speed against
+GroupNorm on this chip: not measured).
 
 Standalone:
     python examples/resnet/resnet_imagenet.py --synth --steps 20

@@ -97,7 +97,7 @@ typedef struct tos_exec {
 } tos_exec;
 
 // Create-option marshalling: kinds 0 = string, 1 = int64.  Plugins like
-// libtpu take no options; tunneled/proxying plugins require them (their
+// libtpu take no options; a proxying plugin may require them (its
 // PJRT_Client_Create rejects an empty NamedValue list), so the extended
 // entry point forwards key/value pairs as PJRT_NamedValues.
 tos_runner* tos_runner_create_opts(const char* plugin_path,

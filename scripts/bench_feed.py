@@ -4,8 +4,7 @@ Measures the InputMode.SPARK feed path end-to-end across a real process
 boundary — producer process runs `node._push_chunks` (exactly what the
 feeder task runs), consumer runs `feed.DataFeed.next_numpy_batch` — for
 both transports, plus the raw ring bandwidth ceiling. The workload is
-the round-1 baseline shape (MNIST-like rows: 784 f32 + 1 int64 label)
-so numbers are comparable with BASELINE.md's 9.6 MB/s round-1 record.
+the round-1 baseline shape (MNIST-like rows: 784 f32 + 1 int64 label).
 
     python scripts/bench_feed.py [--rows-mb 256] [--raw-mb 2048] [--skip-queue]
 """

@@ -1,5 +1,5 @@
 """LM training throughput harness (not driver-run; bench.py stays the
-single driver metric).  Reproduces the BASELINE.md self-measured rows:
+single driver metric):
 
     python scripts/bench_lm.py                 # 56M params, B16 S1024 bf16
     python scripts/bench_lm.py --attention dense   # XLA-dense comparison

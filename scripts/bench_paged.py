@@ -1,6 +1,6 @@
 """Serving-engine feature benches: paged kv, speculative slots, prefix cache.
 
-Reproduces the BASELINE.md round-5 rows measured on the real chip:
+Not driver-run; no figure from it has been taken on this chip:
 
     python scripts/bench_paged.py                 # all three sections
     python scripts/bench_paged.py --only paged    # dense vs paged pool
@@ -14,9 +14,9 @@ Sections:
   the pool, so right-sizing is a SPEED win too, not just capacity).
 - spec: fused speculative rounds with a SELF-draft (acceptance ~1 —
   the mechanical ceiling, and the worst case for round cost).
-- prefix: cold vs cached admission of a repeated long prompt; on
-  tunneled runtimes the dispatch round trip dominates (documented
-  negative); the section reports prefill_tokens_shared either way.
+- prefix: cold vs cached admission of a repeated long prompt; where
+  the dispatch round trip dominates the win vanishes; the section
+  reports prefill_tokens_shared either way.
 """
 import argparse
 import json
@@ -183,13 +183,9 @@ def main(argv=None):
 
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ.get("TFOS_TPU_JAX_CACHE",
-                                         "/tmp/tfos_jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-    except Exception:
-        pass
+    from tensorflowonspark_tpu import util
+
+    util.enable_compile_cache()
 
     model, params = _build(args)
     out = {"platform": jax.devices()[0].platform}

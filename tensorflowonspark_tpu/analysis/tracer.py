@@ -15,7 +15,7 @@ set; assignments, arithmetic, subscripts, and calls propagate it; the
 static-under-trace attributes (``.shape``/``.dtype``/``.ndim``) launder it.
 Jitted functions are found by decorator (``@jax.jit``,
 ``@partial(jax.jit, ...)``, ``@shard_map``-style) and by same-module
-wrapping calls (``f2 = jax.jit(f)``, ``compat.shard_map(f, mesh=...)``).
+wrapping calls (``f2 = jax.jit(f)``, ``jax.shard_map(f, mesh=...)``).
 
 Interprocedural tier: calls out of a staged function to a resolvable
 project helper consult the helper's dataflow summary

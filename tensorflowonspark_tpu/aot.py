@@ -56,8 +56,7 @@ def export_aot(export_dir, apply_fn, params, signature, batch_sizes=(1, 64),
     ``matmul_precision`` ("highest"/"float32" etc.) pins the dot/conv
     precision INTO the artifact: TPU compilers lower default-precision
     f32 matmuls to bf16 passes, so an artifact exported without this
-    only matches a float32 host reference to ~bf16 tolerance (measured
-    on a real chip — BASELINE.md round 5).
+    only matches a float32 host reference to ~bf16 tolerance.
 
     One artifact is written PER platform (jax.export cross-lowers, so a CPU
     host can export for TPU serving): single-platform modules keep the plain
@@ -217,8 +216,8 @@ class NativeRunner:
     def __init__(self, mlir_text, compile_options, plugin_path=None,
                  create_options=None):
         """``create_options`` ({key: str|int}) are forwarded to
-        PJRT_Client_Create as NamedValues — libtpu needs none, but
-        tunneled/proxying plugins reject an optionless create."""
+        PJRT_Client_Create as NamedValues — libtpu needs none; a
+        proxying plugin may reject an optionless create."""
         self._lib = _load_runner_lib()
         plugin = plugin_path or default_plugin_path()
         err = ctypes.create_string_buffer(4096)

@@ -1,9 +1,8 @@
 """Shared benchmark definitions: chip peaks and the flagship-LM config.
 
 Single source of truth for the driver metric (bench.py) and the repro
-harness (scripts/bench_lm.py) so the two cannot drift — the recorded
-numbers in BASELINE.md are only comparable if every harness builds the
-exact same step.
+harness (scripts/bench_lm.py) so the two cannot drift — recorded
+numbers are only comparable if every harness builds the exact same step.
 """
 
 # bf16 matmul peaks by device_kind substring (public spec sheet numbers)
@@ -22,15 +21,14 @@ def bf16_peak(device_kind):
     return next((v for k, v in PEAK_BF16.items() if k in device_kind), None)
 
 
-# The round-3 flagship-LM benchmark config (BASELINE.md round 3): 0.87B
-# params, the north-star workload class on one chip.  Frozen — changing any
-# value invalidates vs_baseline comparability and requires a BASELINE.md
-# methodology note.
+# The round-3 flagship-LM benchmark config: 0.87B params, the north-star
+# workload class on one chip.  Frozen — changing any value invalidates
+# comparability with every earlier record of it.
 FLAGSHIP_LM = dict(
     vocab_size=32000, d_model=2048, n_heads=16, n_kv_heads=8,
     n_layers=16, d_ff=8192, max_seq_len=1024, dtype="bfloat16",
     rope=True, attention_impl="auto")
-# Round-5 re-baseline (BASELINE.md round 5): same dims, RMSNorm — the
+# Round-5 re-baseline: same dims, RMSNorm — the
 # config this framework RECOMMENDS for new decoder-only models since
 # round 3 (the frozen v1 kept LayerNorm only for comparability; the
 # round-4 verdict called the freeze stale).  v1 stays measured in aux
@@ -44,7 +42,10 @@ FLAGSHIP_MU_DTYPE = "bfloat16"
 # The optax reference stays measurable via make_flagship_step(
 # optimizer="adamw") and bench.py's transition aux row.
 FLAGSHIP_OPTIMIZER = "adamw_fused"
-ROUND1_LM_MFU = 47.0  # BASELINE.md round-1 flagship-LM row (vs_baseline denom)
+# bench.py's vs_baseline denominator: the round-1 flagship-LM figure, taken
+# on an earlier runtime and never re-measured on this chip (the benchmark
+# PR, ROADMAP S1, replaces it with a ledger row)
+ROUND1_LM_MFU = 47.0
 
 # The decode_ms segment workload (bench.py --segments): steady-state
 # paged slot decode on the flagship dims, sized for the gather path's

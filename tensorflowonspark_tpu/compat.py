@@ -1,45 +1,9 @@
-"""Version shims for the JAX stack (maps reference compat.py:1-31).
+"""What is left of the reference's compat.py (compat.py:1-31).
 
-The reference shimmed TF1/TF2 API drift; here we pin down the couple of JAX
-API locations that have moved across releases so the rest of the codebase
-imports from one place.
+The reference shimmed TF1/TF2 API drift.  This package is written for the
+one installation it runs on (jax/jaxlib 0.9.0) and calls the current JAX
+API where it is used — no version shims live here.
 """
-
-
-def tree_map(f, *trees):
-    import jax
-    if hasattr(jax, "tree"):
-        return jax.tree.map(f, *trees)
-    return jax.tree_util.tree_map(f, *trees)
-
-
-def shard_map():
-    """Return the shard_map callable across jax versions, normalized to
-    the current kwarg spelling: call sites pass ``check_vma``; on older
-    jax (experimental entry point, ``check_rep``) a shim translates."""
-    import inspect
-
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if "check_vma" in inspect.signature(sm).parameters:
-        return sm
-
-    def _compat(f, **kw):
-        kw["check_rep"] = kw.pop("check_vma", True)
-        return sm(f, **kw)
-    return _compat
-
-
-def make_mesh(axis_shapes, axis_names, devices=None):
-    """Build a Mesh; prefers jax.make_mesh (better device ordering for ICI)."""
-    import jax
-    import numpy as np
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    return jax.sharding.Mesh(devs.reshape(tuple(axis_shapes)), tuple(axis_names))
 
 
 def export_chief_only(save_fn, is_chief, *args, **kwargs):

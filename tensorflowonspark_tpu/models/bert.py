@@ -1,7 +1,7 @@
 """BERT encoder family — masked-LM pretraining on TPU.
 
 Net-new relative to the reference (whose model zoo stops at MNIST CNN /
-ResNet-CIFAR / UNet, SURVEY.md §2.5); BASELINE.md lists BERT-base
+ResNet-CIFAR / UNet, SURVEY.md §2.5); BASELINE.json lists BERT-base
 pretraining through the pipeline Estimator as a target config.  Built from
 the same `transformer.Block` the causal LM uses (bidirectional: causal=False),
 so the tensor-parallel sharding rules (parallel/sharding.DEFAULT_RULES)

@@ -21,17 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
-# SUBMODULE-path imports (graftcheck note): `tensorflowonspark_tpu.ops`
-# rebinds the attribute `paged_attention` to the re-exported FUNCTION
-# (ops/__init__), so the availability helpers are only reachable through
-# the submodule path.  Hoisted to module scope — these used to run on
-# every traced layer call inside _paged_attention_body.
-from tensorflowonspark_tpu.ops.paged_attention import (
-    paged_attention, paged_attention_available)
-from tensorflowonspark_tpu.ops.paged_prefill import (
-    paged_prefill, paged_prefill_available)
-from tensorflowonspark_tpu.ops.quant_matmul import (
-    quant_matmul, quant_matmul_available)
+from tensorflowonspark_tpu.ops.paged_attention import paged_attention
+from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
+from tensorflowonspark_tpu.ops.quant_matmul import quant_matmul
 
 logger = logging.getLogger(__name__)
 
@@ -210,8 +202,7 @@ class QuantDense(nn.Module):
             dtype = (jnp.promote_types(jnp.result_type(x), jnp.float32)
                      if self.dtype is None else jnp.dtype(self.dtype))
             x = x.astype(dtype)
-            if (self.impl == "kernel" and quant_matmul_available()
-                    and _ambient_mesh() is None):
+            if self.impl == "kernel" and _ambient_mesh() is None:
                 y = quant_matmul(x, qleaf)
             else:
                 w = quantize.dequantize_leaf(qleaf, dtype)
@@ -435,8 +426,7 @@ class Attention(nn.Module):
             # ONE payload blend for both storages: int8 payloads blend
             # at the ACTIVATION dtype (±127 is exact in bf16/f32; a
             # wider blend would double the write traffic that dominates
-            # this op — an f32 blend measured 26% SLOWER end-to-end
-            # serving, BASELINE.md round 5) and the trailing
+            # this op) and the trailing
             # astype(store) is a no-op when store == dtype
             oh = onehot.astype(dtype)
             ck.value = jnp.where(write_mask, jnp.einsum(
@@ -523,8 +513,8 @@ def _paged_attention_body(attn_self, q, k, v):
     stores + one online softmax over [occupied context pages || chunk],
     O(chunk) traffic with the blend below kept as the parity reference
     and the mesh fallback.  Decode steps (S == 1) and the "blend"
-    impl follow the measured slot-cache rule (one-hot masked blend,
-    never a scatter: BASELINE.md round 4).
+    impl follow the slot-cache rule (one-hot masked blend, never a
+    scatter).
     Reads go through ``cfg.paged_attn_impl``: "kernel" (the default)
     runs the Pallas flash-decode kernel, which walks each row's page
     table in place and touches only its OCCUPIED pages — per-token read
@@ -583,7 +573,7 @@ def _paged_attention_body(attn_self, q, k, v):
     L = max_pages * P
     idx = ci.value
     if (S > 1 and cfg.paged_prefill_impl == "kernel"
-            and paged_prefill_available() and _ambient_mesh() is None):
+            and _ambient_mesh() is None):
         # Pallas paged-prefill kernels (ops/paged_prefill.py): the
         # chunk's k/v store page-granular IN PLACE into the pool
         # (int8 requantization fused, bit-identical to the blend's
@@ -633,8 +623,7 @@ def _paged_attention_body(attn_self, q, k, v):
             "bsn,bso,bsh->noh", oh_p.astype(jnp.float32),
             oh_o.astype(jnp.float32), v_sc), pvs.value)
     ci.value = idx + S
-    if (cfg.paged_attn_impl == "kernel" and paged_attention_available()
-            and _ambient_mesh() is None):
+    if cfg.paged_attn_impl == "kernel" and _ambient_mesh() is None:
         # in-place page walk: lengths = the post-write cache_index (the
         # kernel derives the visibility rule j <= idx + s from it)
         return paged_attention(
@@ -663,39 +652,14 @@ def _paged_attention_body(attn_self, q, k, v):
 
 
 def _ambient_mesh():
-    """`jax.sharding.get_abstract_mesh()` or None, across jax versions.
-
-    Older jax has no abstract-mesh tracking; there the ambient mesh is
-    the ``with mesh:`` thread resource (empty → None, like the new API's
-    empty AbstractMesh).
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        mesh = get_am()
-        return None if mesh is None or mesh.empty else mesh
-    try:
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
+    """The mesh set by `jax.set_mesh`, or None when there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 
 def _bound_axes(mesh):
-    """Mesh axes already bound manual by an enclosing shard_map.
-
-    New jax records them on the abstract mesh (`manual_axes`); older jax
-    exposes them only through the tracing axis env.
-    """
-    if mesh is not None:
-        manual = getattr(mesh, "manual_axes", None)
-        if manual is not None:
-            return manual
-    try:
-        from jax._src import core as _core
-        return tuple(_core.get_axis_env().axis_sizes)
-    except (ImportError, AttributeError):
-        return ()
+    """Mesh axes already bound manual by an enclosing shard_map."""
+    return mesh.manual_axes if mesh is not None else ()
 
 
 def _seqpar_dispatch(q, k, v, cfg):
@@ -794,11 +758,10 @@ def _flash_dispatch(q, k, v, cfg):
     import functools
     from jax.sharding import PartitionSpec as P
 
-    from tensorflowonspark_tpu.parallel.ring_attention import _get_shard_map
     spec = P(dp, None, tp, None)
     local = functools.partial(flash_attention, causal=cfg.causal)
-    return _get_shard_map()(local, mesh=mesh, in_specs=(spec, spec, spec),
-                            out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def dot_product_attention(q, k, v, causal=True, mask=None):

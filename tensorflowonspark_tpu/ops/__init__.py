@@ -36,13 +36,12 @@ from tensorflowonspark_tpu.ops.fused_optim import adamw_fused, lion_fused
 from tensorflowonspark_tpu.ops.layernorm import fused_layernorm
 from tensorflowonspark_tpu.ops.paged_attention import paged_attention
 from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
-from tensorflowonspark_tpu.ops.quant_matmul import (quant_matmul,
-                                                    quant_matmul_available)
+from tensorflowonspark_tpu.ops.quant_matmul import quant_matmul
 from tensorflowonspark_tpu.ops.xent import fused_unembed_xent
 
 __all__ = ["flash_attention", "fused_layernorm", "fused_unembed_xent",
            "adamw_fused", "lion_fused", "paged_attention",
-           "paged_prefill", "quant_matmul", "quant_matmul_available"]
+           "paged_prefill", "quant_matmul"]
 
 
 def default_interpret():

@@ -23,21 +23,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs; interpret mode needs it not
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-finite: exp(NEG_INF - m) == 0 without inf-inf NaNs
 
 
 def _scratch(shape, dtype=jnp.float32):
-    if _VMEM is not None:
-        return pltpu.VMEM(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def _block_mask(qi, ki, block_q, block_k, seq_len, causal):

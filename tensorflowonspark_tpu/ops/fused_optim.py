@@ -4,8 +4,7 @@ The optax update for the flagship LM is a chain of elementwise
 transforms — clip -> moments -> weight decay -> lr scale -> apply — and
 each link reads and writes the full f32 optimizer state in HBM.  At
 0.87B params that is several complete passes over ~10 GB of state per
-step, pure bandwidth the matmuls cannot hide (BENCH_r05: the optimizer
-dominates the non-matmul remainder at 71.4% MFU).  These kernels apply
+step, pure bandwidth the matmuls cannot hide.  These kernels apply
 the ENTIRE update in one pass per parameter block:
 
     read  grad, param, mu[, nu]   (once)
@@ -42,12 +41,16 @@ tier (interpret=True) exercises the real kernel code.
 State layout: the moments keep each parameter's exact shape and mirror
 the parameter pytree (``FusedAdamWState.mu/nu``), so under explicit
 shardings the state shards by the param's OWN spec — fsdp and tp axes
-alike — with zero extra machinery (``parallel.train._opt_state_
-shardings`` maps the mirrored tree onto the param shardings, the same
-placement rule f32 optax moments get).  Blocking to the kernel's
-(rows, 128) grid happens on flat views inside the jitted update, which
-XLA lowers to bitcasts (plus a pad copy only for parameters whose size
-is not a lane multiple — none of the flagship's are).
+alike (``parallel.train._opt_state_shardings`` maps the mirrored tree
+onto the param shardings, the same placement rule f32 optax moments
+get).  Over a mesh ``make_train_step`` passes those shardings to
+``apply(..., shardings=)`` and each leaf's kernel runs under
+``shard_map`` by its param's spec — Mosaic kernels cannot be partitioned
+by GSPMD (see ``_run_leaf``); ``update`` takes no shardings and is the
+single-device form.  Blocking to the kernel's (rows, 128) grid
+happens on flat views of the (local) leaf inside the jitted update,
+which XLA lowers to bitcasts (plus a pad copy only for parameters whose
+size is not a lane multiple — none of the flagship's are).
 
 ``mu_dtype="bfloat16"`` stores the first moment in bf16 exactly like
 ``optax.adamw(mu_dtype=...)`` (compute stays f32 in VMEM; the narrow
@@ -63,13 +66,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs; interpret mode needs it not
-    from jax.experimental.pallas import tpu as pltpu
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128               # TPU lane width: last dim of every block
 DEFAULT_BLOCK_ROWS = 256  # (256, 128) f32 block = 128 KB per operand in VMEM
@@ -173,7 +170,8 @@ def _from_blocks(y, shape):
     return y.reshape(-1)[:n].reshape(shape)
 
 
-def _run_leaf(kernel, scalars, arrays, out_dtypes, block_rows, interpret):
+def _run_leaf(kernel, scalars, arrays, out_dtypes, block_rows, interpret,
+              sharding=None):
     """Run `kernel` over same-shaped leaf `arrays` blocked to (bm, LANE).
 
     `arrays[0]` supplies the logical shape; outputs are the first
@@ -181,47 +179,59 @@ def _run_leaf(kernel, scalars, arrays, out_dtypes, block_rows, interpret):
     Padding lanes hold zeros; both kernels map zero grad/state to zero
     output (eps keeps the adam quotient finite), so the pad never NaNs.
 
-    Two deliberate sharding choices, both found the hard way on the
-    8-device mesh: (1) NO pallas-level input_output_aliases — under GSPMD
-    the compiler may pick different shardings for the flattened operand
-    and its output, and the runtime alias check then fails on mismatched
-    per-shard sizes; aliasing only saves a buffer allocation, not HBM
-    traffic (the read+write still happen exactly once here), and the
-    train step's jit donation already recycles the old state buffers.
-    (2) every output is pinned to its input's sharding via shard_alike —
-    the flatten/unflatten reshapes break GSPMD's propagation, and a
-    freshly-chosen output sharding makes the train step's donated state
-    aliases fail the same way.
-    """
-    from jax.experimental.shard_alike import shard_alike
+    `sharding` — the leaf's NamedSharding when the caller jits over a
+    mesh (None/False: a single device).  Mosaic refuses to be partitioned
+    by GSPMD ("Mosaic kernels cannot be automatically partitioned" — the
+    interpreter's plain-XLA lowering never showed it), so there the leaf
+    runs under shard_map by the param's own spec: each device blocks and
+    updates its LOCAL shard (a replicated leaf is updated redundantly on
+    every device, which is what data parallelism means), and the outputs
+    leave with the same spec, so the train step's donated state aliases
+    line up.
 
-    shape = arrays[0].shape
-    n = math.prod(shape) if shape else 1
-    bm = _block_rows_for(n, block_rows)
-    blocks = [_to_blocks(a, bm) for a in arrays]
-    rows = blocks[0].shape[0]
-    bspec = pl.BlockSpec((bm, LANE), lambda i: (i, 0))
-    if _SMEM is not None:
-        sspec = pl.BlockSpec((1, 4), lambda i: (0, 0), memory_space=_SMEM)
-    else:  # pragma: no cover - CPU-only jaxlib
-        # interpret-mode only (no TPU ext -> no SMEM): a (1, 4) scalar
-        # block is never vector-tiled here
-        # graftcheck: disable-next-line=pallas-tile
-        sspec = pl.BlockSpec((1, 4), lambda i: (0, 0))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(rows // bm,),
-        in_specs=[sspec] + [bspec] * len(blocks),
-        out_specs=[bspec] * len(out_dtypes),
-        out_shape=[jax.ShapeDtypeStruct((rows, LANE), d)
-                   for d in out_dtypes],
-        interpret=interpret,
-    )(scalars, *blocks)
-    outs = [_from_blocks(o, shape) for o in outs]
-    # outputs correspond positionally to the TRAILING inputs (adamw:
-    # out/new_mu/new_nu <- p/mu/nu; lion: out/new_mu <- p/mu)
-    srcs = arrays[len(arrays) - len(outs):]
-    return tuple(shard_alike(s, o)[1] for s, o in zip(srcs, outs))
+    NO pallas-level input_output_aliases: aliasing only saves a buffer
+    allocation, not HBM traffic, and the train step's jit donation
+    already recycles the old state buffers.
+    """
+    def local(scalars, *arrays):
+        shape = arrays[0].shape
+        n = math.prod(shape) if shape else 1
+        bm = _block_rows_for(n, block_rows)
+        blocks = [_to_blocks(a, bm) for a in arrays]
+        rows = blocks[0].shape[0]
+        bspec = pl.BlockSpec((bm, LANE), lambda i: (i, 0))
+        sspec = pl.BlockSpec((1, 4), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM)
+        outs = pl.pallas_call(
+            kernel,
+            grid=(rows // bm,),
+            in_specs=[sspec] + [bspec] * len(blocks),
+            out_specs=[bspec] * len(out_dtypes),
+            out_shape=[jax.ShapeDtypeStruct((rows, LANE), d)
+                       for d in out_dtypes],
+            interpret=interpret,
+        )(scalars, *blocks)
+        return tuple(_from_blocks(o, shape) for o in outs)
+
+    if sharding:
+        from jax.sharding import PartitionSpec
+
+        spec = sharding.spec
+        return jax.shard_map(
+            local, mesh=sharding.mesh,
+            in_specs=(PartitionSpec(),) + (spec,) * len(arrays),
+            out_specs=(spec,) * len(out_dtypes),
+            check_vma=False)(scalars, *arrays)
+
+    return local(scalars, *arrays)
+
+
+def _leaf_shardings(shardings, like):
+    """`shardings` (a NamedSharding per param leaf, or None) as a tree
+    `tree_map` can zip with `like`; False stands for "none"."""
+    if shardings is not None:
+        return shardings
+    return jax.tree_util.tree_map(lambda _: False, like)
 
 
 # ---------------------------------------------------------------------------
@@ -293,7 +303,7 @@ def adamw_fused(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
                 params),
             nu=jax.tree_util.tree_map(jnp.zeros_like, params))
 
-    def _run(updates, state, params, write_param):
+    def _run(updates, state, params, write_param, shardings):
         if params is None:
             if weight_decay:
                 raise ValueError(
@@ -307,18 +317,20 @@ def adamw_fused(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
                         updates)
         wds = _decay_tree(updates, weight_decay, mask)
 
-        def leaf(g, p, mu, nu, wd):
+        def leaf(g, p, mu, nu, wd, sharding):
             kern = functools.partial(
                 _adamw_kernel, b1=float(b1), b2=float(b2), eps=float(eps),
                 wd=float(wd), write_param=write_param)
             out_dtype = p.dtype if write_param else g.dtype
             out, new_mu, new_nu = _run_leaf(
                 kern, scal, [g, p, mu, nu],
-                [out_dtype, mu.dtype, nu.dtype], block_rows, interp)
+                [out_dtype, mu.dtype, nu.dtype], block_rows, interp,
+                sharding)
             return _LeafOut(out, new_mu, new_nu)
 
         flat = jax.tree_util.tree_map(leaf, updates, params, state.mu,
-                                      state.nu, wds)
+                                      state.nu, wds,
+                                      _leaf_shardings(shardings, updates))
         is_out = lambda x: isinstance(x, _LeafOut)  # noqa: E731
         import optax
         new_state = FusedAdamWState(
@@ -329,10 +341,10 @@ def adamw_fused(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
         return out, new_state
 
     def update_fn(updates, state, params=None):
-        return _run(updates, state, params, write_param=False)
+        return _run(updates, state, params, False, None)
 
-    def apply_fn(updates, state, params):
-        return _run(updates, state, params, write_param=True)
+    def apply_fn(updates, state, params, shardings=None):
+        return _run(updates, state, params, True, shardings)
 
     return FusedOptimizer(init_fn, update_fn, apply_fn)
 
@@ -353,7 +365,7 @@ def lion_fused(learning_rate, b1=0.9, b2=0.99, weight_decay=0.0, mask=None,
                 lambda p: jnp.zeros_like(p, dtype=mu_dtype or p.dtype),
                 params))
 
-    def _run(updates, state, params, write_param):
+    def _run(updates, state, params, write_param, shardings):
         if params is None:
             if weight_decay:
                 raise ValueError(
@@ -366,17 +378,18 @@ def lion_fused(learning_rate, b1=0.9, b2=0.99, weight_decay=0.0, mask=None,
                         updates)
         wds = _decay_tree(updates, weight_decay, mask)
 
-        def leaf(g, p, mu, wd):
+        def leaf(g, p, mu, wd, sharding):
             kern = functools.partial(
                 _lion_kernel, b1=float(b1), b2=float(b2), wd=float(wd),
                 write_param=write_param)
             out_dtype = p.dtype if write_param else g.dtype
             out, new_mu = _run_leaf(
                 kern, scal, [g, p, mu], [out_dtype, mu.dtype],
-                block_rows, interp)
+                block_rows, interp, sharding)
             return _LeafOut(out, new_mu, None)
 
-        flat = jax.tree_util.tree_map(leaf, updates, params, state.mu, wds)
+        flat = jax.tree_util.tree_map(leaf, updates, params, state.mu, wds,
+                                      _leaf_shardings(shardings, updates))
         is_out = lambda x: isinstance(x, _LeafOut)  # noqa: E731
         import optax
         new_state = FusedLionState(
@@ -386,10 +399,10 @@ def lion_fused(learning_rate, b1=0.9, b2=0.99, weight_decay=0.0, mask=None,
         return out, new_state
 
     def update_fn(updates, state, params=None):
-        return _run(updates, state, params, write_param=False)
+        return _run(updates, state, params, False, None)
 
-    def apply_fn(updates, state, params):
-        return _run(updates, state, params, write_param=True)
+    def apply_fn(updates, state, params, shardings=None):
+        return _run(updates, state, params, True, shardings)
 
     return FusedOptimizer(init_fn, update_fn, apply_fn)
 

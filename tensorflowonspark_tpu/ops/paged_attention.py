@@ -48,28 +48,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-finite: exp(NEG_INF - m) == 0 without inf-inf NaNs
 _LANES = 128     # m/l carry a lane-replicated trailing dim for layout
 
 
-def paged_attention_available():
-    """True when the TPU pallas extension (scalar prefetch) imported —
-    callers fall back to the einsum reference read otherwise."""
-    return pltpu is not None
-
-
 def _scratch(shape, dtype=jnp.float32):
-    if _VMEM is not None:
-        return pltpu.VMEM(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def _pick_splits(requested, max_pages):
@@ -171,11 +157,6 @@ def paged_attention(q, pages_key, pages_value, page_table, lengths, *,
 
     Returns ``[B, S, H, Dh]`` in q's dtype.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "paged_attention needs jax.experimental.pallas.tpu (scalar "
-            "prefetch); use the einsum read path "
-            "(TransformerConfig.paged_attn_impl='einsum') instead")
     B, S, H, Dh = q.shape
     NP, page, n_kv, Dh_kv = pages_key.shape
     if pages_value.shape != pages_key.shape or Dh_kv != Dh:

@@ -52,28 +52,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-finite: exp(NEG_INF - m) == 0 without inf-inf NaNs
 _LANES = 128     # m/l carry a lane-replicated trailing dim for layout
 
 
-def paged_prefill_available():
-    """True when the TPU pallas extension (scalar prefetch) imported —
-    callers fall back to the blend write + gather read otherwise."""
-    return pltpu is not None
-
-
 def _scratch(shape, dtype=jnp.float32):
-    if _VMEM is not None:
-        return pltpu.VMEM(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def _quantize(x):
@@ -400,11 +386,6 @@ def paged_prefill(q, k, v, pages_key, pages_value, page_table, starts, *,
     updated pool leaves (inputs are aliased to outputs so the pool
     updates in place under jit; scale leaves are None without int8).
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "paged_prefill needs jax.experimental.pallas.tpu (scalar "
-            "prefetch); use the blend write path "
-            "(TransformerConfig.paged_prefill_impl='blend') instead")
     B, S, H, Dh = q.shape
     NP, page, n_kv, Dh_kv = pages_key.shape
     if pages_value.shape != pages_key.shape or Dh_kv != Dh:

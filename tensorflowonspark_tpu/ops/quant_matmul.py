@@ -40,27 +40,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-enabled jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 
 
-def quant_matmul_available():
-    """True when the TPU pallas extension imported — QuantDense falls
-    back to the inline-dequant einsum path otherwise."""
-    return pltpu is not None
-
-
 def _scratch(shape, dtype=jnp.float32):
-    if _VMEM is not None:
-        return pltpu.VMEM(shape, dtype)
-    return jax.ShapeDtypeStruct(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def _round_up(x, mult):
@@ -226,11 +212,6 @@ def quant_matmul(x, w, *, block_m=128, block_n=128, block_k=512,
     """
     from tensorflowonspark_tpu import quantize
 
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "quant_matmul needs jax.experimental.pallas.tpu; use the "
-            "inline dequantize path "
-            "(TransformerConfig.quant_matmul_impl='dequant') instead")
     if interpret is None:
         from tensorflowonspark_tpu.ops import default_interpret
         interpret = default_interpret()

@@ -17,11 +17,10 @@ rematerialization — trade ~1 extra chunk matmul for the full logits
 round trip).  Peak extra memory is one ``[chunk, V]`` float32 tile plus
 the float32 kernel-gradient accumulator.
 
-Measured reality (BASELINE.md round 3, v5e, 0.87B/32k-vocab config): step
-time is at PARITY with the materialized-logits `lm_loss` (the scan
-serializes the head matmul and the backward recompute costs what the
-saved logits round-trip saved), so this op is a MEMORY feature, not a
-speed one: it removes the [B, S, V] float32 logits tensor from both
+Step time against the materialized-logits `lm_loss` on this chip: not
+measured (the scan serializes the head matmul, and the backward
+recompute competes with the logits round-trip it saves).  Treat this op
+as a MEMORY feature: it removes the [B, S, V] float32 logits tensor from both
 passes, which is what lets long-sequence / large-vocab configs fit on a
 chip at all.
 

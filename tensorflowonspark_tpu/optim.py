@@ -73,10 +73,8 @@ def make_optimizer(name="adamw", learning_rate=1e-3, schedule="constant",
     `mu_dtype` (adam/adamw/lion and the fused variants) stores the first
     moment in a narrower dtype — ``"bfloat16"`` halves that state's HBM
     footprint AND the optimizer update's bandwidth (momentum is
-    noise-tolerant; the second moment stays float32).  On one v5e chip
-    this took the 0.87B flagship-LM step from 351 ms (61.8% MFU) to
-    326 ms (66.6% MFU, the canonical bench.py run); see BASELINE.md
-    round 3.
+    noise-tolerant; the second moment stays float32).  Its effect on
+    the flagship step time on this chip: not measured.
 
     ``adamw_fused`` / ``lion_fused`` run the whole update — clip scale,
     moments, decay, lr — as ONE Pallas pass per parameter block

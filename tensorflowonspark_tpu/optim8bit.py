@@ -5,11 +5,10 @@ flagship config the float32 m/v are ~7 GB resident.  Storing both
 moments as int8 with per-block float32 scales cuts that to ~1.8 GB —
 the headroom that decides whether the next model size fits on a chip.
 
-Measured reality (v5e, flagship config, BASELINE.md round 3): step TIME
-is at parity with f32 adamw (357 vs 351 ms) — the quantize/requantize
-arithmetic costs what the state bandwidth saves on this part, so for
-pure speed prefer ``adamw(mu_dtype=bfloat16)`` (326 ms).  Choose
-adamw8bit for its MEMORY footprint.
+Step time against f32 adamw on this chip: not measured.  The
+quantize/requantize arithmetic competes with what the smaller state
+saves in bandwidth, so choose adamw8bit for its MEMORY footprint; for
+speed the single-pass ``adamw_fused`` is the candidate.
 
 Quantization scheme (chosen for XLA friendliness — everything is a
 reshape + absmax + multiply, no tables):

@@ -70,19 +70,14 @@ class MeshSpec:
         return self.dp * self.fsdp
 
 
-def _axis_types_kwargs():
+def _axis_types():
     """Auto axis types = classic GSPMD propagation: the compiler may insert
     collectives (partial-sum allreduce for row-parallel matmuls,
     reduce-scatter/all-gather at SP boundaries) instead of treating
-    shardings as assertions, which is what Megatron-style TP+SP needs.
-    Older jax has no AxisType — there Auto/GSPMD propagation is the only
-    behavior, so passing nothing means the same thing."""
+    shardings as assertions, which is what Megatron-style TP+SP needs."""
     import jax
 
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * len(ALL_AXES)}
+    return (jax.sharding.AxisType.Auto,) * len(ALL_AXES)
 
 
 def build_mesh(spec=None, devices=None):
@@ -92,14 +87,14 @@ def build_mesh(spec=None, devices=None):
 
     devs = list(devices) if devices is not None else jax.devices()
     spec = (spec or MeshSpec()).resolve(len(devs))
-    kw = _axis_types_kwargs()
-    if devices is None and hasattr(jax, "make_mesh"):
+    if devices is None:
         # make_mesh picks a device order that keeps inner axes on short ICI
         # paths — use it whenever we're not given an explicit device list.
-        mesh = jax.make_mesh(spec.shape, ALL_AXES, **kw)
+        mesh = jax.make_mesh(spec.shape, ALL_AXES, axis_types=_axis_types())
     else:
         mesh = jax.sharding.Mesh(
-            np.asarray(devs).reshape(spec.shape), ALL_AXES, **kw)
+            np.asarray(devs).reshape(spec.shape), ALL_AXES,
+            axis_types=_axis_types())
     logger.info("built mesh %s over %d devices", dict(zip(ALL_AXES, spec.shape)),
                 len(devs))
     return mesh
@@ -209,7 +204,7 @@ def build_hybrid_mesh(spec=None, devices=None, num_slices="auto"):
         return build_mesh(spec, devices=devices)
     if arr is None:
         arr = hybrid_device_array(spec, devs, num_slices)
-    mesh = jax.sharding.Mesh(arr, ALL_AXES, **_axis_types_kwargs())
+    mesh = jax.sharding.Mesh(arr, ALL_AXES, axis_types=_axis_types())
     logger.info("built hybrid mesh %s over %d devices in %d slices",
                 dict(zip(ALL_AXES, spec.shape)), len(devs), num_slices)
     return mesh

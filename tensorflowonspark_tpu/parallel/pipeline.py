@@ -75,8 +75,7 @@ def pipeline_apply(stage_fn, stacked_params, x_micro, mesh, axis="pp",
     """
     from jax.sharding import PartitionSpec as P
 
-    from tensorflowonspark_tpu.parallel.ring_attention import _get_shard_map
-    shard_map = _get_shard_map()
+    shard_map = jax.shard_map
 
     n_micro = x_micro.shape[0]
     param_specs = jax.tree_util.tree_map(

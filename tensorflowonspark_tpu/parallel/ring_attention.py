@@ -192,17 +192,9 @@ def ring_attention(q, k, v, axis_name="tp", causal=True, mesh=None,
                                      interpret=interpret)
 
     from jax.sharding import PartitionSpec as P
-    shard_map = _get_shard_map()
     spec = P(batch_axes, axis_name, None, None)
     fn = functools.partial(_ring_attention_local, axis_name=axis_name,
                            causal=causal, use_flash=use_flash,
                            interpret=interpret)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
-
-
-def _get_shard_map():
-    """shard_map normalized to the current kwarg spelling (compat.py owns
-    the version translation — check_vma vs the older check_rep)."""
-    from tensorflowonspark_tpu.compat import shard_map
-    return shard_map()
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -102,9 +102,12 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
         fused_apply = getattr(optimizer, "apply", None)
         if callable(fused_apply):
             # single-pass fused optimizer: param write fused into the
-            # kernel's one pass over grad/moments (no apply_updates pass)
-            params, opt_state = fused_apply(grads, state.opt_state,
-                                            state.params)
+            # kernel's one pass over grad/moments (no apply_updates pass).
+            # Over a mesh each leaf's kernel is shard_mapped by the
+            # param's own sharding (Mosaic cannot be GSPMD-partitioned).
+            params, opt_state = fused_apply(
+                grads, state.opt_state, state.params,
+                shardings=fused_param_sh)
         else:
             updates, opt_state = optimizer.update(grads, state.opt_state,
                                                   state.params)
@@ -117,6 +120,7 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
                    "grad_norm": optax.global_norm(grads)}
         return new_state, metrics
 
+    fused_param_sh = None   # NamedSharding per param leaf, over a mesh
     if mesh is None and param_shardings is not None:
         # derive the mesh from the shardings rather than silently
         # compiling an unsharded step
@@ -139,23 +143,33 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
             # from the first state actually passed in.
             cache = {}
 
-            def step(state, batch, rng):
+            def _jitted(state):
+                nonlocal fused_param_sh
                 if "fn" not in cache:
                     state_sh = jax.tree_util.tree_map(
                         lambda x: x.sharding
                         if isinstance(x.sharding, NamedSharding) else repl,
                         state)
+                    fused_param_sh = state_sh.params
                     cache["fn"] = jax.jit(
                         _step,
                         in_shardings=(state_sh, batch_shard, repl),
                         out_shardings=(state_sh, repl),
                         donate_argnums=(0,) if donate else ())
-                return cache["fn"](state, batch, rng)
+                return cache["fn"]
+
+            def step(state, batch, rng):
+                return _jitted(state)(state, batch, rng)
+
+            # AOT like the plain-jit returns of this function
+            step.lower = lambda state, batch, rng: _jitted(state).lower(
+                state, batch, rng)
             return step
         state_shardings = None  # let jit infer from input placement
         in_shardings = (None, batch_shard, repl)
         out_shardings = (None, repl)
     else:
+        fused_param_sh = param_shardings
         state_shardings = TrainState(
             step=repl, params=param_shardings,
             opt_state=_opt_state_shardings(optimizer, param_shardings, repl,

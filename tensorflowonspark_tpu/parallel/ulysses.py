@@ -18,6 +18,7 @@ ring when S alone exceeds HBM.
 """
 import functools
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -97,11 +98,9 @@ def ulysses_attention(q, k, v, axis_name="tp", causal=True, mesh=None,
                               narrow_ok=narrow_ok)
 
     from jax.sharding import PartitionSpec as P
-    from tensorflowonspark_tpu.parallel.ring_attention import _get_shard_map
-    shard_map = _get_shard_map()
     spec = P(batch_axes, axis_name, None, None)
     fn = functools.partial(_ulysses_local, axis_name=axis_name,
                            causal=causal, attn_fn=attn_fn,
                            narrow_ok=narrow_ok)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

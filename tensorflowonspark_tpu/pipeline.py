@@ -204,7 +204,7 @@ class TFModel(TFParams):
           Spark sinks — ``createDataFrame``, JSON — choke on numpy types,
           and those rows pay Spark serialization anyway); plain local
           partitions keep numpy row views (per-element ``.tolist()``
-          dominated serving cost; see BASELINE.md serving round 2).
+          is pure host overhead there).
         - ``True`` / ``False`` — force either behavior.
         """
         return self._transform(dataset, backend, box=box)

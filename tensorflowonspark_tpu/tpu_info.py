@@ -64,11 +64,13 @@ def _count_local_chips():
     """Count local TPU chips WITHOUT initializing the JAX runtime.
 
     Order matters: initializing JAX in this process would lock every chip
-    (libtpu takes an exclusive lock at runtime init) and make a later
-    ``TPU_VISIBLE_CHIPS`` restriction a no-op for this process.  So we count
-    via env override, then devfs, and only fall back to a JAX probe (which is
-    accurate but locks the chips — fine when this process is the one that
-    will use them all anyway).
+    (libtpu takes an exclusive lock at runtime init), make a later
+    ``TPU_VISIBLE_CHIPS`` restriction a no-op, and — this process being
+    the executor that goes on to START the node process — leave the node
+    to hang or fail on a chip its own parent holds.  So we count via env
+    override, then devfs (a v5e host shows ``/dev/vfio/<n>``, one per
+    chip), and otherwise ask a throwaway child, which takes the chips,
+    counts, and gives them back by exiting.
     """
     env = os.environ.get("TFOS_TPU_LOCAL_CHIPS")
     if env:
@@ -77,7 +79,16 @@ def _count_local_chips():
     accels = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
     if accels:
         return len(accels)
-    return len(_probe_devices())
+    import subprocess
+    import sys
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(len(jax.local_devices()))"],
+        capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        raise RuntimeError(
+            f"chip-count probe failed: {probe.stderr.strip()[-500:]}")
+    return int(probe.stdout.split()[-1])
 
 
 def assign_chips(num_chips, worker_index=-1, fmt=AS_STRING):

@@ -224,6 +224,30 @@ def pin_platform(platform):
     jax.config.update("jax_platforms", platform)
 
 
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ONE rule, for the node bootstrap of `chip_smoke.py`, `bench.py` and
+    the `scripts/` harnesses: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX already honours it and nothing is set in code; where it is not,
+    the cache lives in ``.jax_cache/`` at the root of this checkout
+    (git-ignored).  The path is part of the cache key, so it is fixed and
+    absolute — never under a tempdir, a pid or the clock — and never
+    relative: executors chdir into per-run scratch directories.  Call
+    before the first compile of the process.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def absolutize_args(args, keys=("data_dir", "model_dir", "export_dir",
                                 "output", "tfrecord_dir", "log_dir")):
     """Resolve path-valued args on the DRIVER: executor processes run in

@@ -1,0 +1,222 @@
+"""The kernels compiled by the chip's own compiler, at flagship widths.
+
+Interpret-mode parity tests (test_ops, test_fused_optim, ...) prove the
+kernel BODIES; they cannot see what Mosaic refuses — block shapes that
+break the (8, 128) tiling rule, VMEM overflow.  The TPU compiler is
+installed with jaxlib and compiles for a chip that is described, not
+attached (`jax.experimental.topologies`), so these run on the CPU tier
+at no chip time.  Nothing executes: a pass here is a compile, never a
+chip run.
+
+The train-path kernels must lower (each asserts `tpu_custom_call` in the
+compiled text — an interpret-mode lowering would compile too, and prove
+nothing).  The two paged serving kernels are recorded as they stand:
+refused, strict xfail with the compiler's words (ROADMAP.md Speed S0).
+
+One process may load libtpu, and keeps it until exit: the topology is
+described inside a fixture of THIS file (never at import, so every xdist
+worker collects the same tests and only the one handed this file loads
+the library), and every compile runs in the test's own process.
+"""
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from tensorflowonspark_tpu import benchmarks, quantize
+from tensorflowonspark_tpu.ops import (flash_attention, fused_layernorm,
+                                       paged_attention, paged_prefill,
+                                       quant_matmul)
+from tensorflowonspark_tpu.ops.fused_optim import adamw_fused
+
+LM = benchmarks.FLAGSHIP_LM_V2
+B, S = benchmarks.FLAGSHIP_BATCH, LM["max_seq_len"]
+D, H, N_KV = LM["d_model"], LM["n_heads"], LM["n_kv_heads"]
+DH, D_FF, VOCAB = D // H, LM["d_ff"], LM["vocab_size"]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """`chip(shape, dtype)` -> a ShapeDtypeStruct placed on one described
+    v5e chip.  A compile for a described device is written to the
+    persistent cache but cannot be read back without the chip (the next
+    run would warn and recompile), so the cache is off for this module."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    """The chip compiler's verdict on `fn(*args)`: compiled HLO text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _qkv(chip):
+    return (chip((B, S, H, DH), jnp.bfloat16),
+            chip((B, S, N_KV, DH), jnp.bfloat16),
+            chip((B, S, N_KV, DH), jnp.bfloat16))
+
+
+def test_flash_forward_lowers(chip):
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    assert "tpu_custom_call" in _compile(fn, *_qkv(chip))
+
+
+def test_flash_backward_lowers(chip):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip))
+    # forward + dq + dk/dv kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_adamw_fused_apply_lowers(chip):
+    """The flagship's three leaf classes: a wide MLP kernel, the
+    embedding table, and a norm scale vector (pads to one sublane
+    tile)."""
+    opt = adamw_fused(3e-4, mu_dtype=jnp.bfloat16, clip_norm=1.0,
+                      weight_decay=0.1, interpret=False)
+    shapes = {"mlp": (D, D_FF), "embed": (VOCAB, D), "scale": (D,)}
+    params = {k: chip(s, jnp.float32) for k, s in shapes.items()}
+    state = jax.eval_shape(opt.init, params)
+    one = params["scale"].sharding
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        state)
+    text = _compile(opt.apply, params, state, params)
+    assert text.count("tpu_custom_call") >= len(shapes)
+
+
+def test_adamw_fused_apply_lowers_under_a_mesh(topo, chip):
+    """Over a mesh each leaf's kernel must run under `shard_map` by its
+    param's spec: handed to GSPMD, the chip's compiler refuses it ("Mosaic
+    kernels cannot be automatically partitioned") — which the interpreter
+    on a virtual CPU mesh never showed, and only `chip_smoke.py --chips 4`
+    on four chips did.  One leaf sharded over an axis, one replicated."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "fsdp"))
+    specs = {"mlp": P("fsdp", None), "scale": P()}
+    shapes = {"mlp": (D, D_FF), "scale": (D,)}
+    shardings = {k: NamedSharding(mesh, spec) for k, spec in specs.items()}
+    params = {k: jax.ShapeDtypeStruct(shapes[k], jnp.float32,
+                                      sharding=shardings[k])
+              for k in shapes}
+    opt = adamw_fused(3e-4, mu_dtype=jnp.bfloat16, clip_norm=1.0,
+                      weight_decay=0.1, interpret=False)
+    state = jax.eval_shape(opt.init, params)
+    state = state._replace(
+        count=jax.ShapeDtypeStruct((), state.count.dtype,
+                                   sharding=NamedSharding(mesh, P())),
+        **{m: {k: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=shardings[k])
+               for k, x in getattr(state, m).items()}
+           for m in ("mu", "nu")})
+
+    def apply(grads, state, params):
+        return opt.apply(grads, state, params, shardings=shardings)
+
+    text = _compile(apply, params, state, params)
+    assert text.count("tpu_custom_call") >= len(shapes)
+
+
+def test_layernorm_lowers(chip):
+    fn = functools.partial(fused_layernorm, interpret=False)
+    text = _compile(fn, chip((B * S, D), jnp.bfloat16),
+                    chip((D,), jnp.float32), chip((D,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_int8_quant_matmul_lowers(chip, rows):
+    w = {"q": chip((D, D_FF), jnp.int8),
+         "scale": chip((1, D_FF), jnp.float32)}
+    assert quantize.is_quantized_leaf(w)
+    fn = functools.partial(quant_matmul, interpret=False)
+    text = _compile(fn, chip((rows, D), jnp.bfloat16), w)
+    assert "tpu_custom_call" in text
+
+
+# --- the paged serving kernels: refused today ----------------------------
+#
+# Both block the pool / chunk `(1, page, 1, Dh)` over a `[.., .., n_kv,
+# Dh]` operand: a second-minor block of 1 over n_kv=8 is neither a
+# multiple of 8 nor the whole dim.  strict: the PR that redoes the
+# blocking turns these into plain passing tests, and cannot forget to.
+
+_DEC = benchmarks.FLAGSHIP_DECODE
+_PRE = benchmarks.FLAGSHIP_PREFILL_KERNEL
+
+
+def _pool(chip, n_slots, page, max_seq):
+    max_pages = max_seq // page
+    kv_pages = n_slots * max_pages + 1            # + the sink page
+    return (chip((kv_pages, page, N_KV, DH), jnp.bfloat16),
+            chip((kv_pages, page, N_KV, DH), jnp.bfloat16),
+            chip((n_slots, max_pages), jnp.int32),
+            chip((n_slots,), jnp.int32))
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="The Pallas TPU lowering currently requires that the last two "
+           "dimensions of your block shape are divisible by 8 and 128 "
+           "respectively, or be equal to the respective dimensions of the "
+           "overall array. Block spec for args[3] in pallas_call "
+           "_decode_kernel at ops/paged_attention.py:85 has block shape "
+           "(1, 64, 1, 128), array shape (1025, 64, 8, 128)")
+def test_paged_attention_decode_lowers(chip):
+    pk, pv, table, lengths = _pool(chip, _DEC["n_slots"],
+                                   _DEC["page_size"], _DEC["max_seq"])
+    fn = functools.partial(paged_attention, interpret=False)
+    text = _compile(fn, chip((_DEC["n_slots"], 1, H, DH), jnp.bfloat16),
+                    pk, pv, table, lengths)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.xfail(
+    strict=True, raises=ValueError,
+    reason="The Pallas TPU lowering currently requires that the last two "
+           "dimensions of your block shape are divisible by 8 and 128 "
+           "respectively, or be equal to the respective dimensions of the "
+           "overall array. Block spec for args[3] in pallas_call "
+           "_prefill_read_kernel at ops/paged_prefill.py:235 has block "
+           "shape (1, 256, 1, 128), array shape (4, 256, 8, 128)")
+def test_paged_prefill_lowers(chip):
+    n, chunk = _PRE["n_slots"], _PRE["chunk"]
+    pk, pv, table, starts = _pool(chip, n, _PRE["page_size"],
+                                  _PRE["max_seq"])
+    fn = functools.partial(paged_prefill, interpret=False)
+
+    def run(q, k, v, pk, pv, table, starts):
+        out, pools = fn(q, k, v, pk, pv, table, starts)
+        return out, pools[0], pools[1]
+
+    text = _compile(run, chip((n, chunk, H, DH), jnp.bfloat16),
+                    chip((n, chunk, N_KV, DH), jnp.bfloat16),
+                    chip((n, chunk, N_KV, DH), jnp.bfloat16),
+                    pk, pv, table, starts)
+    assert "tpu_custom_call" in text

@@ -18,11 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.ops.paged_attention import (
-    paged_attention, paged_attention_available, paged_attention_reference)
-
-pytestmark = pytest.mark.skipif(
-    not paged_attention_available(),
-    reason="pallas tpu extension (scalar prefetch) unavailable")
+    paged_attention, paged_attention_reference)
 
 
 def _make_case(seed, B, S, H, n_kv, Dh, page, max_pages, lengths,

@@ -26,12 +26,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.ops.paged_prefill import (
-    paged_prefill, paged_prefill_available)
-
-pytestmark = pytest.mark.skipif(
-    not paged_prefill_available(),
-    reason="pallas tpu extension (scalar prefetch) unavailable")
+from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
 
 
 def _blend_ref(q, k, v, pages_key, pages_value, table, starts,

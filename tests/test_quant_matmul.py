@@ -18,10 +18,6 @@ from tensorflowonspark_tpu import quantize
 import importlib
 qm = importlib.import_module("tensorflowonspark_tpu.ops.quant_matmul")
 
-pytestmark = pytest.mark.skipif(
-    not qm.quant_matmul_available(),
-    reason="jax.experimental.pallas.tpu unavailable")
-
 # rows deliberately off the sublane grid, K/N off the 128-lane grid in
 # the tall/wide cases, so the zero-pad + slice path is always exercised
 SHAPES = {"tall": (5, 384, 128), "wide": (4, 128, 320),

@@ -9,10 +9,6 @@ from tensorflowonspark_tpu.models.transformer import dot_product_attention
 from tensorflowonspark_tpu.parallel import mesh as mesh_mod
 from tensorflowonspark_tpu.parallel.ring_attention import ring_attention
 
-# jax.set_mesh landed after 0.4.x; there Mesh is itself the context
-# manager for the same global-mesh scope.
-_set_mesh = getattr(jax, "set_mesh", None) or (lambda mesh: mesh)
-
 
 @pytest.fixture(scope="module")
 def qkv():
@@ -47,7 +43,7 @@ def test_ring_under_jit_and_grad(qkv):
     def f_dense(q, k, v):
         return dot_product_attention(q, k, v, causal=True).sum()
 
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_ring = jax.grad(f)(q, k, v)
     g_dense = jax.grad(f_dense)(q, k, v)
     np.testing.assert_allclose(np.asarray(g_ring), np.asarray(g_dense),

@@ -74,3 +74,18 @@ def test_slice_topology_env(monkeypatch):
     monkeypatch.setenv("TPU_WORKER_ID", "2")
     topo = tpu_info.get_slice_topology()
     assert topo == {"worker_id": 2, "num_workers": 4, "hosts": ["h0", "h1", "h2", "h3"]}
+
+
+def test_count_without_devfs_never_probes_in_process(monkeypatch):
+    """No env override, no device nodes: the count comes from a throwaway
+    child — a JAX probe HERE would take the chip in the executor process
+    that then starts the node."""
+    import glob
+
+    monkeypatch.delenv("TFOS_TPU_LOCAL_CHIPS", raising=False)
+    monkeypatch.setattr(glob, "glob", lambda _pattern: [])
+    monkeypatch.setattr(
+        tpu_info, "_probe_devices",
+        mock.Mock(side_effect=AssertionError("probed in-process")))
+    # the child inherits conftest's 8 virtual CPU devices
+    assert tpu_info._count_local_chips() == 8

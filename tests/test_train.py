@@ -62,8 +62,16 @@ def test_dp_training_converges_to_known_weights():
     step = train_mod.make_train_step(loss_fn, opt, mesh, shardings)
     rng = jax.random.key(0)
     metrics = None
-    for _ in range(200):
+    for i in range(200):
         state, metrics = step(state, (X, y), rng)
+        if i % 16 == 15:
+            # Keep the dispatch queue short.  Past 32 programs in flight
+            # per device, XLA's CPU client can deadlock an 8-way
+            # all-reduce when the box is loaded: 7 of 8 threads join the
+            # rendezvous and the runtime aborts the process after 40 s
+            # (jaxlib 0.9.0; 5 of 8 runs under 12 busy processes at the
+            # seed, 0 of 8 with this wait).
+            jax.block_until_ready(metrics["loss"])
     assert float(metrics["loss"]) < 1e-3
     np.testing.assert_allclose(np.asarray(state.params["w"]), w_true, atol=1e-2)
     np.testing.assert_allclose(float(state.params["b"]), b_true, atol=1e-2)

@@ -10,10 +10,6 @@ from tensorflowonspark_tpu.models.transformer import dot_product_attention
 from tensorflowonspark_tpu.parallel import mesh as mesh_mod
 from tensorflowonspark_tpu.parallel.ulysses import ulysses_attention
 
-# jax.set_mesh landed after 0.4.x; there Mesh is itself the context
-# manager for the same global-mesh scope.
-_set_mesh = getattr(jax, "set_mesh", None) or (lambda mesh: mesh)
-
 
 @pytest.fixture(scope="module")
 def qkv():
@@ -81,7 +77,7 @@ def test_transformer_cp_dispatch_matches_dense(cp_field):
 
     cp_model = Transformer(TransformerConfig(**base, **{cp_field: "tp"}))
     mesh = mesh_mod.build_mesh(mesh_mod.MeshSpec(dp=2, tp=4))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda p, t: cp_model.apply({"params": p}, t))(params, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -97,7 +93,7 @@ def test_transformer_cp_rejects_indivisible_seq():
     model = Transformer(cfg)
     tokens = jnp.zeros((2, 30), jnp.int32)  # 30 % 4 != 0
     mesh = mesh_mod.build_mesh(mesh_mod.MeshSpec(dp=2, tp=4))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with pytest.raises(ValueError, match="divisible by"):
             model.init(jax.random.key(0), tokens)
 
@@ -119,7 +115,7 @@ def test_transformer_cp_dense_impl_matches(cp_field):
     cp_model = Transformer(TransformerConfig(
         **base, attention_impl="dense", **{cp_field: "tp"}))
     mesh = mesh_mod.build_mesh(mesh_mod.MeshSpec(dp=1, tp=8))
-    with _set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda p, t: cp_model.apply({"params": p}, t))(params, tokens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
