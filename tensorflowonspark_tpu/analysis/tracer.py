@@ -79,8 +79,18 @@ def _static_filter(call_kwargs):
 
 
 def _staged_functions(tree):
-    """Yield (FunctionDef, static_argnums, static_argnames, how) for every
-    function staged by jit/pjit/shard_map in this module."""
+    """The (FunctionDef, static_argnums, static_argnames, how) of every
+    function staged by jit/pjit/shard_map in this module.  Kept on the
+    tree: the three tracer rules, jit-recompile and the interprocedural
+    pass all ask for the same module's list (two walks of the tree each
+    time, a fifth of the repo-wide scan before it was kept)."""
+    found = getattr(tree, "_staged_functions", None)
+    if found is None:
+        found = tree._staged_functions = list(_find_staged(tree))
+    return found
+
+
+def _find_staged(tree):
     defs = {}
     for node in ast.walk(tree):
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):

@@ -14,9 +14,14 @@ import threading
 import time
 
 from . import backend as backend_mod
-from . import node, reservation
+from . import node, reservation, trace
 
 logger = logging.getLogger(__name__)
+
+
+# how long `shutdown` waits for the training nodes' BYE (and, before it,
+# their trace report) when no evaluator makes it wait for them anyway
+REPORT_WAIT_SECS = 10.0
 
 
 class InputMode:
@@ -139,12 +144,16 @@ class TPUCluster:
                 parts = [[(node.PROGRESS_HEADER, i)] + list(p)
                          for i, p in enumerate(parts)]
         self._check_driver_error()
-        self._backend.foreach_partition(
-            parts, node.train(self.cluster_info, self.cluster_meta,
-                              feed_timeout=feed_timeout, qname=qname,
-                              skip_offsets=skip_offsets,
-                              track_progress=track_progress,
-                              progress_every=progress_every))
+        count = len(parts) if hasattr(parts, "__len__") else (
+            parts.getNumPartitions() if hasattr(parts, "getNumPartitions")
+            else None)
+        with trace.span("cluster.train", partitions=count):
+            self._backend.foreach_partition(
+                parts, node.train(self.cluster_info, self.cluster_meta,
+                                  feed_timeout=feed_timeout, qname=qname,
+                                  skip_offsets=skip_offsets,
+                                  track_progress=track_progress,
+                                  progress_every=progress_every))
 
     def train_stream(self, stream: Any, feed_timeout: float = 600,
                      qname: str = "input") -> None:
@@ -229,21 +238,27 @@ class TPUCluster:
             # reference's statusTracker poll until only ps/eval tasks
             # remain, TFCluster.py:154-169).  Bounded by `timeout` via the
             # watchdog; node failures surface through the error channel.
+            # Without an evaluator the wait is short and only for the
+            # nodes' trace reports, which they send just before BYE: a
+            # node whose function returns on the end-of-feed sentinel is
+            # there within the bound, and one that is not loses its
+            # report, nothing else.
             has_eval = any(n["job_name"] == "evaluator"
                            for n in self.cluster_info)
-            if has_eval:
-                training = {n["executor_id"] for n in self.cluster_info
-                            if n["job_name"] in ("chief", "worker")}
-                deadline = time.time() + timeout
-                while not training <= self.server.finished_ids():
-                    self._check_driver_error()
-                    if time.time() > deadline:
-                        logger.warning(
-                            "training nodes %s never announced exit; "
-                            "stopping evaluator anyway",
-                            sorted(training - self.server.finished_ids()))
-                        break
-                    time.sleep(0.5)
+            training = {n["executor_id"] for n in self.cluster_info
+                        if n["job_name"] in ("chief", "worker")}
+            deadline = time.time() + (
+                timeout if has_eval else min(timeout, REPORT_WAIT_SECS))
+            while not training <= self.server.finished_ids():
+                self._check_driver_error()
+                if time.time() > deadline:
+                    logger.log(
+                        logging.WARNING if has_eval else logging.INFO,
+                        "training nodes %s have not announced exit; "
+                        "going on with the shutdown",
+                        sorted(training - self.server.finished_ids()))
+                    break
+                time.sleep(0.05)
             # Evaluator nodes run remote-mode managers so the driver can push
             # their stop sentinel directly (maps TFCluster.py:186-194); then
             # mark them 'stopped' so their bootstrap releases the manager.
@@ -260,11 +275,31 @@ class TPUCluster:
         finally:
             watchdog.cancel()
             self.server.stop()
+            try:
+                for line in trace.summary_lines(self.trace_report()):
+                    logger.info(line)
+            except Exception:
+                # a malformed report must not mask what ended the run
+                logger.debug("no trace summary", exc_info=True)
         if isinstance(self._backend, backend_mod.LocalBackend):
             self._backend.join(timeout=60)
             err = self._backend.check_bootstrap_errors()
             if err:
                 raise RuntimeError(f"node failed during run:\n{err}")
+
+    def trace_report(self) -> list:
+        """What the feed plane recorded, as a list of `trace.report()`
+        dicts (``source``, ``anchor``, ``spans``, ``counters``,
+        ``recorded``, ``dropped``): this process's own first (source
+        ``driver``: `cluster.train`), then every report this cluster's
+        bootstrap tasks (``bootstrap:<executor>``: `node.bootstrap` and its
+        steps), feeder tasks (``feeder:<executor>:<pid>``, one a task, sent
+        when the task ends) and nodes (``node:<executor>``, sent when the
+        user function returns or fails) brought to the driver.  Whole after
+        `shutdown`; `trace.wall_ns` puts any span on the wall clock."""
+        mine = self.server.reported_sources()
+        got = trace.collected("driver")
+        return got[:1] + [r for r in got[1:] if r.get("source") in mine]
 
     def tensorboard_url(self) -> Optional[str]:
         """URL of the chief's profiler/TensorBoard endpoint, if enabled

@@ -13,7 +13,7 @@ arrays ready for `jax.device_put`; `iter_batches` wraps the loop.
 import logging
 from typing import Any, Iterable, Iterator, Optional
 
-from . import marker
+from . import marker, trace
 from . import shm as shm_mod
 
 logger = logging.getLogger(__name__)
@@ -43,9 +43,16 @@ def device_prefetch(batch_iter: Iterable, sharding: Any = None,
     from .parallel import mesh as mesh_mod
 
     def _put(batch):
-        if sharding is None:
-            return jax.device_put(batch)
-        return mesh_mod.put_batch(batch, sharding)
+        # the span is the CALL: `device_put` returns once the copy is
+        # enqueued and the bytes move after it (the span says so:
+        # `asynchronous`); what it costs the host here is the dispatch
+        # and whatever the runtime stages before returning
+        with trace.span("feed.h2d", asynchronous=True, bytes=sum(
+                getattr(x, "nbytes", 0)
+                for x in jax.tree_util.tree_leaves(batch))):
+            if sharding is None:
+                return jax.device_put(batch)
+            return mesh_mod.put_batch(batch, sharding)
 
     depth = max(1, int(depth))
     buf = collections.deque()
@@ -128,6 +135,7 @@ class DataFeed:
         # AutoProxy over a fresh socket (several ms of server round trips)
         self._q_in = None
         self._q_out = None
+        self._items_got = 0         # data items got (`feed.queue_get`)
 
     def _queue_in(self):
         if self._q_in is None:
@@ -153,8 +161,12 @@ class DataFeed:
                                "queue-borne chunks", exc_info=True)
         return self._ring
 
-    def _resolve_ref(self, ref):
+    def _resolve_ref(self, ref, cause=None):
         """ShmRef -> list of segments (PackedChunks / ("rows", list))."""
+        with trace.span("feed.resolve", cause=cause, bytes=ref.nbytes):
+            return self._read_ref(ref)
+
+    def _read_ref(self, ref):
         ring = self._ring_handle()
         if ring is None:
             raise RuntimeError(
@@ -179,7 +191,41 @@ class DataFeed:
 
     def _take_blocks(self, batch_size, timeout=None):
         """Collect up to `batch_size` records as blocks (row lists or
-        columnar PackedChunk slices), handling the marker protocol."""
+        columnar PackedChunk slices), handling the marker protocol.
+        One `feed.take` span a call; under it one `feed.queue_get` for
+        every `q.get` (with what came, and the ordinal of data items:
+        the input queue is FIFO, so the k-th data item got is the k-th
+        the feeders put) and one `feed.resolve` for every ring read."""
+        with trace.span("feed.take") as taking:
+            blocks = self._collect(batch_size, timeout, taking)
+            taking.set(rows=sum(len(data) for _, data in blocks))
+        return blocks
+
+    def _get(self, q, timeout, cause, **attrs):
+        """One traced `q.get`; raises `queue.Empty` on a timeout."""
+        import queue as queue_mod
+
+        with trace.span("feed.queue_get", cause=cause, **attrs) as sp:
+            try:
+                item = q.get(timeout=timeout) if timeout is not None \
+                    else q.get()
+            except queue_mod.Empty:
+                sp.set(got="none")
+                raise
+            for kind, cls in (("ring_ref", shm_mod.ShmRef),
+                              ("packed", marker.PackedChunk),
+                              ("chunk", marker.Chunk)):
+                if isinstance(item, cls):     # a data item: it has an ordinal
+                    sp.set(got=kind, item=self._items_got)
+                    self._items_got += 1
+                    break
+            else:
+                sp.set(got="end" if item is None
+                       else "marker" if isinstance(item, marker.Marker)
+                       else "record")
+        return item
+
+    def _collect(self, batch_size, timeout, taking):
         import queue as queue_mod
 
         # staged offsets from the PREVIOUS take are safe now: that batch
@@ -227,7 +273,7 @@ class DataFeed:
             if self.done_feeding or self._partition_break:
                 break
             try:
-                item = q.get(timeout=timeout) if timeout is not None else q.get()
+                item = self._get(q, timeout, taking)
             except queue_mod.Empty:
                 break
             if item is None:
@@ -251,7 +297,7 @@ class DataFeed:
                     break
                 # nothing collected yet: partition boundary is invisible
             elif isinstance(item, shm_mod.ShmRef):
-                self._segments.extend(self._resolve_ref(item))
+                self._segments.extend(self._resolve_ref(item, taking))
                 q.task_done()
             elif isinstance(item, marker.PackedChunk):
                 self._segments.append(item)
@@ -346,6 +392,17 @@ class DataFeed:
         blocks = self._take_blocks(batch_size, timeout)
         if not blocks:
             return None
+        with trace.span("feed.stack") as sp:
+            out = self._stack(blocks, dtype)
+            sp.set(bytes=sum(a.nbytes for a in (
+                out if isinstance(out, tuple) else (out,))))
+        return out
+
+    @staticmethod
+    def _stack(blocks, dtype):
+        """Blocks -> one array, or a tuple of one per field."""
+        import numpy as np
+
         if all(kind == "cols" and data.matrix for kind, data in blocks):
             # wide flat records: concatenate the [N, F] matrices once and
             # expose per-field column views
@@ -459,7 +516,7 @@ class DataFeed:
         done = False
         while not done:
             try:
-                item = q.get(timeout=3)
+                item = self._get(q, 3, None, drained=True)
                 if isinstance(item, shm_mod.ShmRef):
                     # free the ring frames so a feeder blocked on a full
                     # ring unblocks and sees the 'terminating' state

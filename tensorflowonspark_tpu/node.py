@@ -16,6 +16,7 @@ executors through a `Backend`.  Differences from the reference, by design
   loud divergence warning (SURVEY.md §2.3).
 - Data feeding is chunked (`marker.Chunk`) rather than per-record.
 """
+import itertools
 import logging
 from typing import Any, Callable, Dict, Optional
 import multiprocessing as mp
@@ -25,7 +26,7 @@ import traceback
 import uuid
 
 from . import feed as feed_mod
-from . import manager, marker, reservation, shm, tpu_info, util
+from . import manager, marker, reservation, shm, tpu_info, trace, util
 
 logger = logging.getLogger(__name__)
 
@@ -99,11 +100,13 @@ class NodeContext:
             logger.info("single-process cluster; skipping jax.distributed init")
             return False
         import jax
-        jax.distributed.initialize(
-            coordinator_address=self.coordinator_address,
-            num_processes=self.num_processes,
-            process_id=self.process_id,
-        )
+        with trace.span("node.init_distributed",
+                        processes=self.num_processes):
+            jax.distributed.initialize(
+                coordinator_address=self.coordinator_address,
+                num_processes=self.num_processes,
+                process_id=self.process_id,
+            )
         return True
 
 
@@ -186,11 +189,18 @@ def _wrapper_fn_background(map_fun, tf_args, ctx, error_q_addr, authkey,
         ctx.mgr = mgr
         _wrapper_fn(map_fun, tf_args, ctx)
         if hb_client is not None:
+            # the report first: the driver's shutdown waits for BYE, so a
+            # report sent before it cannot lose the race with server.stop()
+            _send_report(server_addr, f"node:{ctx.executor_id}")
             hb_client.bye(ctx.executor_id)
             hb_client.close()
     except BaseException:
         tb = traceback.format_exc()
         logger.error("background node fn failed:\n%s", tb)
+        if server_addr is not None:
+            # a failed run is where `feed.queue_get` and `feed.resolve`
+            # are wanted most: before BYE, as on the way out above
+            _send_report(server_addr, f"node:{ctx.executor_id}")
         reported = False
         if mgr is not None:
             try:
@@ -230,25 +240,33 @@ def run(map_fun, tf_args, cluster_meta, tensorboard=False, log_dir=None,
             executor_id = item
         assert executor_id is not None, "bootstrap task received no executor id"
 
-        # 1. role assignment from the template (maps TFSparkNode.py:231-241)
-        job_name, task_index = None, -1
-        for jname, ids in cluster_meta["cluster_template"].items():
-            if executor_id in ids:
-                job_name = jname
-                task_index = ids.index(executor_id)
-                break
-        assert job_name is not None, f"executor {executor_id} not in cluster template"
-        logger.info("executor %d assigned %s:%d", executor_id, job_name, task_index)
-
         # Connect to the rendezvous server FIRST so that any bootstrap
         # failure below (duplicate-bootstrap, manager start, chip probe) is
         # reported to the driver instead of silently burning the full
         # reservation timeout.
-        client = reservation.Client(cluster_meta["server_addr"])
+        client = job_name = None
         try:
-            _bootstrap(executor_id, job_name, task_index, client, map_fun,
-                       tf_args, cluster_meta, tensorboard, queues, background)
+            # `node.bootstrap`: this task entered to the node dispatched
+            # (the user function about to be entered, here or in the
+            # background process); its children name the steps
+            with trace.span("node.bootstrap", executor=executor_id) as boot:
+                job_name, task_index = _role(executor_id, cluster_meta)
+                client = reservation.Client(cluster_meta["server_addr"])
+                ctx, authkey = _prepare(
+                    executor_id, job_name, task_index, client, cluster_meta,
+                    tensorboard, queues, boot)
+            _dispatch(ctx, authkey, client, map_fun, tf_args, cluster_meta,
+                      background)
         except BaseException as e:
+            if client is None:
+                raise
+            if not isinstance(e, DuplicateBootstrapError):
+                # what this task recorded up to its failure (the
+                # foreground node's feed spans, a bootstrap's steps); a
+                # duplicate has nothing of the original's to report
+                _send_report(cluster_meta["server_addr"],
+                             ("bootstrap:%s" if background else "node:%s")
+                             % executor_id)
             resp = client.report_error(
                 {"executor_id": executor_id, "job_name": job_name}, repr(e))
             if resp is not None and not isinstance(e, DuplicateBootstrapError):
@@ -261,13 +279,24 @@ def run(map_fun, tf_args, cluster_meta, tensorboard=False, log_dir=None,
                 client.bye(executor_id)
             raise
         finally:
-            client.close()
+            if client is not None:
+                client.close()
 
     return _mapfn
 
 
-def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
-               cluster_meta, tensorboard, queues, background):
+def _role(executor_id, cluster_meta):
+    """1. role assignment from the template (maps TFSparkNode.py:231-241)."""
+    for job_name, ids in cluster_meta["cluster_template"].items():
+        if executor_id in ids:
+            logger.info("executor %d assigned %s:%d", executor_id, job_name,
+                        ids.index(executor_id))
+            return job_name, ids.index(executor_id)
+    raise AssertionError(f"executor {executor_id} not in cluster template")
+
+
+def _prepare(executor_id, job_name, task_index, client, cluster_meta,
+             tensorboard, queues, boot):
         # 2. stale-manager detection: a Spark task retry on the same executor
         #    must not double-start a node (maps TFSparkNode.py:249-255).
         state_file = os.path.join(os.getcwd(), ".tfos_cluster_id")
@@ -285,8 +314,9 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
         #    control queue (maps TFSparkNode.py:259-268).
         authkey = uuid.uuid4().bytes
         mode = "remote" if job_name == "evaluator" else "local"
-        mgr = manager.start(authkey, list(queues), mode=mode)
-        mgr.set("state", f"running/{job_name}")
+        with trace.span("node.manager_start", cause=boot, mode=mode):
+            mgr = manager.start(authkey, list(queues), mode=mode)
+            mgr.set("state", f"running/{job_name}")
         util.write_executor_id(executor_id)
 
         # 4b. shared-memory data plane: created BEFORE registration so any
@@ -295,9 +325,11 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
         #     bytes ride /dev/shm; the queue carries ShmRefs + markers).
         if shm.ring_enabled():
             try:
-                ring = shm.ShmChunkRing.create()
-                mgr.set("shm_ring", ring.info())
-                shm.advertise_file(ring.info())
+                with trace.span("node.ring_create", cause=boot) as sp:
+                    ring = shm.ShmChunkRing.create()
+                    mgr.set("shm_ring", ring.info())
+                    shm.advertise_file(ring.info())
+                    sp.set(bytes=ring.capacity_bytes)
                 # Creator-side last-resort unlink.  atexit alone is not
                 # enough: multiprocessing children exit via os._exit after
                 # running only mp.util finalizers, so in an executor
@@ -338,9 +370,12 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
             "tb_port": tb_port,
             "pid": os.getpid(),
         }
-        client.register(node_meta)
-        cluster_info = client.await_reservations(
-            timeout=cluster_meta.get("reservation_timeout", 600))
+        with trace.span("node.register", cause=boot):
+            client.register(node_meta)
+        with trace.span("node.rendezvous", cause=boot) as sp:
+            cluster_info = client.await_reservations(
+                timeout=cluster_meta.get("reservation_timeout", 600))
+            sp.set(nodes=len(cluster_info))
 
         # TPU chip assignment (maps the cluster-aware second GPU pass,
         # TFSparkNode.py:376-378): only meaningful when several executors
@@ -367,16 +402,21 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
             working_dir=os.getcwd(),
             mgr=mgr,
         )
+        return ctx, authkey
 
+
+def _dispatch(ctx, authkey, client, map_fun, tf_args, cluster_meta,
+              background):
         # 8. dispatch (maps TFSparkNode.py:397-443)
+        executor_id, job_name, mgr = ctx.executor_id, ctx.job_name, ctx.mgr
         try:
             if background:
                 # SPARK input mode: node runs in a background process so this
                 # task can return and free the executor slot for feeder tasks.
                 ctx_bg = NodeContext(
                     executor_id=executor_id, job_name=job_name,
-                    task_index=task_index, num_workers=num_workers,
-                    cluster_info=cluster_info,
+                    task_index=ctx.task_index, num_workers=ctx.num_workers,
+                    cluster_info=ctx.cluster_info,
                     default_fs=cluster_meta.get("default_fs", "file://"),
                     working_dir=os.getcwd(), mgr=None)
                 # map_fun crosses as a cloudpickle blob: a fn defined in a
@@ -391,15 +431,22 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
                           mgr._tfos_addr, authkey,
                           cluster_meta.get("server_addr"),
                           _heartbeat_interval(cluster_meta)),
-                    name=f"node-{job_name}-{task_index}")
+                    name=f"node-{job_name}-{ctx.task_index}")
                 p.start()
                 logger.info("started background node process pid=%d", p.pid)
+                # the node process starts with a recorder of its own: what
+                # this task recorded (`node.bootstrap` and its steps) goes
+                # to the driver from here
+                _send_report(cluster_meta["server_addr"],
+                             f"bootstrap:{executor_id}")
             else:
                 # foreground node: this process is the liveness principal
                 hb_interval = _heartbeat_interval(cluster_meta)
                 if hb_interval > 0:
                     client.start_heartbeat(executor_id, interval=hb_interval)
                 _wrapper_fn(map_fun, tf_args, ctx)
+                _send_report(cluster_meta["server_addr"],
+                             f"node:{executor_id}")
                 client.bye(executor_id)
         except BaseException:
             tb = traceback.format_exc()
@@ -411,8 +458,17 @@ def _bootstrap(executor_id, job_name, task_index, client, map_fun, tf_args,
             raise  # _mapfn's outer handler reports to the server, then BYEs
 
 
+def _payload_bytes(packed):
+    """Bytes a packed chunk carries; 0 for object records, whose size is
+    unknowable without pickling them."""
+    if isinstance(packed, marker.PackedChunk):
+        return sum(c.nbytes for c in packed.columns)
+    return 0
+
+
 def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
-                 progress_fn=None, progress_every=512, poll_cb=None):
+                 progress_fn=None, progress_every=512, poll_cb=None,
+                 cause=None):
     """Push records as chunk batches; returns the record count.  Shared by
     the train and inference feeders — inference's 1:1 result accounting
     depends on this count being exact.
@@ -425,22 +481,34 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
     operation costs a manager round trip and per-item overhead (not
     bandwidth) dominates once bytes ride shared memory.  Without a ring,
     uniform numeric chunks go through the queue as columnar PackedChunks
-    (round-1 behavior, still the fallback when rings cannot be created)."""
+    (round-1 behavior, still the fallback when rings cannot be created).
+
+    Traced per chunk, never per record (`trace.span`, children of
+    ``cause``): `feed.source` (the partition's iterator), `feed.pack`,
+    `feed.encode`, `feed.ring_write`, and `feed.queue_put` for every data
+    item with the route its bytes took: ``ring_ref`` (the ring; counted
+    under `feed.bytes.ring`), ``queue`` (no ring, or a fallback) or
+    ``queue_oversize`` (a chunk larger than the ring itself)."""
+    counters = trace.counters()
     ring = None
-    if mgr is not None and shm.ring_enabled():
-        try:
-            info = shm.discover(mgr)
-            if info:
-                ring = shm.attach_cached(info)
-        except Exception:
-            logger.warning("could not attach shm ring; using queue "
-                           "transport", exc_info=True)
+    with trace.span("feed.connect", cause=cause, step="ring") as sp:
+        if mgr is not None and shm.ring_enabled():
+            try:
+                info = shm.discover(mgr)
+                if info:
+                    ring = shm.attach_cached(info)
+            except Exception:
+                counters.inc("feed.ring_fallbacks")
+                logger.warning("could not attach shm ring; using queue "
+                               "transport", exc_info=True)
+        sp.set(ring=ring is not None)
     target_bytes = int(os.environ.get("TFOS_TPU_CHUNK_BYTES", 8 << 20))
     if ring is not None:
         target_bytes = min(target_bytes, ring.capacity_bytes // 4)
 
     pending = []        # packed sub-chunks awaiting one coalesced write
     pending_bytes = 0
+    items_put = 0       # data items put: the k-th put is the k-th got
 
     last_poll = [time.time()]
 
@@ -465,28 +533,46 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
         if tb is not None:
             raise RuntimeError(f"training function failed:\n{tb}")
 
+    def _put(item, route, nbytes):
+        nonlocal items_put
+        with trace.span("feed.queue_put", cause=cause, route=route,
+                        bytes=nbytes, item=items_put):
+            q.put(item)
+        items_put += 1
+        kind = "ring" if route == "ring_ref" else route
+        counters.inc("feed.bytes." + kind, nbytes)
+        counters.inc("feed.items." + kind)
+
     def _flush():
         nonlocal pending, pending_bytes, ring
         if not pending:
             return
         subs, pending, pending_bytes = pending, [], 0
         try:
-            parts, n = (shm.encode_multi(subs) if len(subs) > 1
-                        else shm.encode_chunk(subs[0]))
+            with trace.span("feed.encode", cause=cause) as sp:
+                parts, n = (shm.encode_multi(subs) if len(subs) > 1
+                            else shm.encode_chunk(subs[0]))
+                sp.set(bytes=sum(len(p) for p in parts))
         except Exception:
             # codec surprise: the queue still works (ring untouched)
+            counters.inc("feed.ring_fallbacks")
             logger.warning("chunk encode failed; chunks ride the queue",
                            exc_info=True)
         else:
             try:
-                ref = ring.write(parts, n, timeout=timeout,
-                                 should_abort=_abort_on_error)
+                with trace.span("feed.ring_write", cause=cause) as sp:
+                    blocked = ring.blocked_s
+                    ref = ring.write(parts, n, timeout=timeout,
+                                     should_abort=_abort_on_error)
+                    sp.set(bytes=ref.nbytes, blocked_ms=round(
+                        (ring.blocked_s - blocked) * 1e3, 3))
             except (shm.RingTimeout, RuntimeError):
                 raise
             except Exception:
                 # write() repaired its frame state, but a transport that
                 # failed generically once is not worth retrying — drop to
                 # queue transport for the remainder of this task
+                counters.inc("feed.ring_fallbacks")
                 logger.warning("ring write failed; disabling ring for this "
                                "task", exc_info=True)
                 ring = None
@@ -495,23 +581,25 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
                 # a successful write must fail the task (its frames are
                 # committed; re-sending the subs via the queue would both
                 # duplicate records and orphan the FULL frames)
-                q.put(ref)
+                _put(ref, "ring_ref", ref.nbytes)
                 return
         for sub in subs:
-            q.put(sub)
+            _put(sub, "queue", _payload_bytes(sub))
 
     def _send(records):
         nonlocal pending_bytes
-        packed = marker.pack_records(records)
+        with trace.span("feed.pack", cause=cause) as sp:
+            packed = marker.pack_records(records)
+            nb = _payload_bytes(packed)
+            sp.set(bytes=nb)
         if ring is None:
-            q.put(packed)
+            _put(packed, "queue", nb)
             return
         if isinstance(packed, marker.PackedChunk):
-            nb = sum(c.nbytes for c in packed.columns)
             if nb > ring.capacity_bytes - (1 << 16):
                 # larger than the ring itself: this one rides the queue
                 _flush()
-                q.put(packed)
+                _put(packed, "queue_oversize", nb)
                 return
             # flush BEFORE the payload would cross the target (the 64 KiB
             # margin covers codec metadata), so each ring write stays
@@ -531,33 +619,79 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
 
     count = 0
     last_mark = 0
-    chunk = []
-    for item in iterator:
-        chunk.append(item)
+    records = iter(iterator)
+    while True:
         # a due progress marker cuts the chunk early: markers must land
         # every ~progress_every records even when that is smaller than
         # the transport chunk
-        marker_due = (progress_fn is not None
-                      and count + len(chunk) - last_mark >= progress_every)
-        if len(chunk) >= CHUNK_SIZE or marker_due:
-            _send(chunk)
-            count += len(chunk)
-            chunk = []
-            _maybe_poll()
-            if marker_due:
-                # records must be IN the queue before the marker claims
-                # them (a marker racing ahead of its chunk would confirm
-                # consumption of records still in the pending buffer)
-                _flush()
-                q.put(progress_fn(count))
-                last_mark = count
-    if chunk:
+        limit = CHUNK_SIZE
+        if progress_fn is not None:
+            limit = max(1, min(limit, progress_every - (count - last_mark)))
+        # from the previous chunk's send returning to this chunk being
+        # cut: the time the partition's iterator took
+        with trace.span("feed.source", cause=cause) as sp:
+            chunk = list(itertools.islice(records, limit))
+            sp.set(records=len(chunk))
+        if not chunk:
+            break
         _send(chunk)
         count += len(chunk)
+        if len(chunk) < limit:
+            break               # the iterator ran out inside this chunk
+        _maybe_poll()
+        if progress_fn is not None and count - last_mark >= progress_every:
+            # records must be IN the queue before the marker claims
+            # them (a marker racing ahead of its chunk would confirm
+            # consumption of records still in the pending buffer)
+            _flush()
+            q.put(progress_fn(count))
+            last_mark = count
     _flush()
     if progress_fn is not None and count > last_mark:
         q.put(progress_fn(count))
     return count
+
+
+def _send_report(server_addr, source):
+    """Bring this process's counters, and the spans it recorded since its
+    last report that arrived (a reused Spark worker runs many tasks: each
+    sends its own spans, not the ring again), to the driver (`REPORT` on
+    the reservation channel).  Best effort: never raises, and a server
+    that is gone costs one refused connect."""
+    try:
+        rec = trace.process()
+        report = trace.report(source, since=rec.sent)
+        client = reservation.Client(tuple(server_addr),
+                                    connect=False, retries=1,
+                                    connect_timeout=5.0, rpc_timeout=10.0)
+        try:
+            if client.send_report(report) is not None:
+                rec.sent = report["recorded"]
+        finally:
+            client.close()
+    except Exception:
+        logger.debug("trace report not sent", exc_info=True)
+
+
+def _traced_task(fn, cluster_meta):
+    """`fn(iterator, task)` as a feeder task: the whole of it under a
+    `feed.task` span, and its report sent to the driver at the end."""
+
+    def _task(iterator):
+        try:
+            with trace.span("feed.task") as task:
+                return fn(iterator, task)
+        finally:
+            _send_report(cluster_meta["server_addr"], "feeder:%s:%d" % (
+                util.read_executor_id(), os.getpid()))
+
+    return _task
+
+
+def _connect(cluster_info, task):
+    with trace.span("feed.connect", cause=task, step="manager"):
+        return _get_manager(cluster_info, util.get_ip_address(),
+                            util.read_executor_id())
 
 
 PROGRESS_HEADER = "__tfos_pid__"
@@ -581,10 +715,8 @@ def train(cluster_info: Any, cluster_meta: Any, feed_timeout: float = 600,
     for consumption — so `cluster.run_elastic` can bound duplicate
     delivery on relaunch to ~one progress window.
     """
-    import itertools
-
-    def _train(iterator):
-        mgr = _get_manager(cluster_info, util.get_ip_address(), util.read_executor_id())
+    def _train(iterator, task):
+        mgr = _connect(cluster_info, task)
         state = manager.get_value(mgr, "state") or ""
         if "terminating" in state:
             # Late partitions are skipped fast once training asked to stop
@@ -592,6 +724,7 @@ def train(cluster_info: Any, cluster_meta: Any, feed_timeout: float = 600,
             logger.info("node is terminating; skipping partition")
             count = sum(1 for _ in iterator)
             logger.info("skipped %d records", count)
+            task.set(records=0, skipped=count)
             # Signal the driver that remaining feeding is pointless
             # (maps TFSparkNode.py:499-511).
             try:
@@ -636,10 +769,13 @@ def train(cluster_info: Any, cluster_meta: Any, feed_timeout: float = 600,
 
         count = _push_chunks(q, iterator, mgr=mgr, timeout=feed_timeout,
                              equeue=equeue, progress_fn=progress_fn,
-                             progress_every=progress_every, poll_cb=poll_cb)
+                             progress_every=progress_every, poll_cb=poll_cb,
+                             cause=task)
         logger.info("pushed %d records into %s queue", count, qname)
+        task.set(records=count)
 
-        _join_with_watchdog(q, equeue, feed_timeout, poll_cb=poll_cb)
+        with trace.span("feed.join", cause=task):
+            _join_with_watchdog(q, equeue, feed_timeout, poll_cb=poll_cb)
         if client is not None:
             # join means every item was DEQUEUED, not that every record
             # was handed to the training fn (drained-but-unreturned
@@ -653,7 +789,7 @@ def train(cluster_info: Any, cluster_meta: Any, feed_timeout: float = 600,
                 logger.warning("final progress poll failed", exc_info=True)
             client.close()
 
-    return _train
+    return _traced_task(_train, cluster_meta)
 
 
 def inference(cluster_info: Any, cluster_meta: Any,
@@ -662,17 +798,19 @@ def inference(cluster_info: Any, cluster_meta: Any,
     TFSparkNode.inference, TFSparkNode.py:518-579).  Returns exactly one
     result per input record, per partition."""
 
-    def _inference(iterator):
-        mgr = _get_manager(cluster_info, util.get_ip_address(), util.read_executor_id())
+    def _inference(iterator, task):
+        mgr = _connect(cluster_info, task)
         q = mgr.get_queue(qname)
         equeue = mgr.get_queue("error")
-        count = _push_chunks(q, iterator, mgr=mgr, equeue=equeue)
+        count = _push_chunks(q, iterator, mgr=mgr, equeue=equeue, cause=task)
         q.put(marker.EndPartition())
         logger.info("pushed %d records (+EndPartition) into %s queue", count, qname)
+        task.set(records=count)
         if count == 0:
             return iter([])
 
-        _join_with_watchdog(q, equeue, timeout=600)
+        with trace.span("feed.join", cause=task):
+            _join_with_watchdog(q, equeue, timeout=600)
 
         # Drain exactly `count` results (maps TFSparkNode.py:567-577).
         out = mgr.get_queue("output")
@@ -683,7 +821,7 @@ def inference(cluster_info: Any, cluster_meta: Any,
         logger.info("collected %d inference results", len(results))
         return iter(results)
 
-    return _inference
+    return _traced_task(_inference, cluster_meta)
 
 
 def _peek_error(equeue):

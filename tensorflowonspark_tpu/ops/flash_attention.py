@@ -255,6 +255,7 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             _scratch((block_q, D)),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     out = result[0][:, :, :S].transpose(0, 2, 1, 3)
     return out, (result[1] if need_lse else None)
@@ -297,6 +298,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
         interpret=interpret,
+        name="flash_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # swap grid roles: (b, kv-head, k-block, group-member, q-block) —
@@ -318,6 +320,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
         interpret=interpret,
+        name="flash_dkv",
     )(qt, kt, vt, dot, lse, delta)
 
     tr = lambda x, s: x[:, :, :s].transpose(0, 2, 1, 3)

@@ -210,6 +210,7 @@ def _run_leaf(kernel, scalars, arrays, out_dtypes, block_rows, interpret,
             out_shape=[jax.ShapeDtypeStruct((rows, LANE), d)
                        for d in out_dtypes],
             interpret=interpret,
+            name="adamw_fused",
         )(scalars, *blocks)
         return tuple(_from_blocks(o, shape) for o in outs)
 

@@ -55,6 +55,7 @@ def _ln_impl(x, scale, bias, eps, block_n, interpret):
         out_specs=pl.BlockSpec((block_n, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="layernorm_fwd",
     )(x2, scale, bias)
     return out[:N].reshape(shape)
 

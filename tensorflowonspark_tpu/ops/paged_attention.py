@@ -250,6 +250,7 @@ def paged_attention(q, pages_key, pages_value, page_table, lengths, *,
                                  jnp.float32),
         ],
         interpret=interpret,
+        name="paged_decode",
     )(table, lengths, *inputs)
 
     # LSE combine across splits: out = sum_s e^{m_s - M} acc_s /

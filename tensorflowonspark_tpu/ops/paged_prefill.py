@@ -212,6 +212,7 @@ def _write_pages(k_st, v_st, k_sc, v_sc, pages_key, pages_value,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        name="paged_prefill_write",
     )(table, starts, *inputs, *pool_inputs)
 
 
@@ -352,6 +353,7 @@ def _read_attention(q, ck, cv, pages_key, pages_value, key_scales,
         out_shape=[jax.ShapeDtypeStruct((B, n_kv, ROWS, Dh),
                                         jnp.float32)],
         interpret=interpret,
+        name="paged_prefill_read",
     )(table, starts, *inputs)
     out = out[:, :, :rows].reshape(B, n_kv, S, group, Dh)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, S, H, Dh).astype(q.dtype)

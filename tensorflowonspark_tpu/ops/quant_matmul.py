@@ -143,6 +143,7 @@ def _int8_call(x2, q, scale, block_m, block_n, block_k, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x2.dtype),
         interpret=interpret,
+        name="qmm_int8",
     )(_pad2(x2, Mp, Kp), _pad2(q, Kp, Np), _pad2(scale, 1, Np))
     return out[:M, :N]
 
@@ -190,6 +191,7 @@ def _int4_call(x2, w, block_m, block_n, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x2.dtype),
         interpret=interpret,
+        name="qmm_int4",
     )(xe, xo, _pad2(p, Kp2p, Np), _pad2(scale, Kp2p // gh, Np))
     return out[:M, :N]
 

@@ -29,6 +29,10 @@ Message types (reference: reservation.py:130-146 had REG/QUERY/QINFO/STOP):
                                           partition; cluster.run_elastic reads
                                           them to bound duplicate delivery on
                                           relaunch)
+- ``REPORT`` {report: {...}}           -> ``OK``       (net-new: a process's
+                                          spans and counters, `trace.report()`
+                                          of a feeder task or a node, kept by
+                                          the driver's `trace.process()`)
 - ``STOP``  {}                         -> ``OK``, server shuts down
 """
 import logging
@@ -41,7 +45,7 @@ import time
 
 import msgpack
 
-from . import faults, util
+from . import faults, trace, util
 
 logger = logging.getLogger(__name__)
 
@@ -153,6 +157,7 @@ class Server(MessageSocket):
         self._beats = {}        # executor_id -> last beat monotonic time
         self._finished = set()  # executor_ids that sent BYE (normal exit)
         self._progress = {}     # partition id -> consumed-record high water
+        self._reported = set()  # sources whose trace report arrived here
         self._flagged = set()   # executor_ids already reported dead
         self._beat_lock = threading.Lock()
 
@@ -247,6 +252,12 @@ class Server(MessageSocket):
                     self._progress[pid] = max(self._progress.get(pid, 0),
                                               int(off))
             self.send(sock, {"type": "OK"})
+        elif mtype == "REPORT":
+            report = msg.get("report") or {}
+            trace.process().add_report(report)
+            with self._beat_lock:
+                self._reported.add(str(report.get("source")))
+            self.send(sock, {"type": "OK"})
         elif mtype == "ERROR":
             logger.error("node reported error: %s", msg.get("error"))
             self.reservations.add_error(
@@ -287,6 +298,13 @@ class Server(MessageSocket):
         via PROGRESS (feed-offset resume, cluster.run_elastic)."""
         with self._beat_lock:
             return dict(self._progress)
+
+    def reported_sources(self):
+        """Sources (`feeder:<executor>:<pid>`, `node:<executor>`) whose
+        trace report (REPORT) reached THIS server; the reports themselves
+        are with the process's recorder, `trace.process().reports()`."""
+        with self._beat_lock:
+            return set(self._reported)
 
     def seed_beat(self, executor_id):
         """Grant `executor_id` a fresh liveness window (as if it just
@@ -500,6 +518,14 @@ class Client(MessageSocket):
                                               for p, o in offsets.items()}})
         except (ConnectionError, OSError):
             logger.warning("could not report feed progress")
+
+    def send_report(self, report):
+        """Bring a `trace.report()` to the driver; best-effort (a lost
+        report loses spans and counters, never records)."""
+        try:
+            return self._request({"type": "REPORT", "report": report})
+        except (ConnectionError, OSError):
+            logger.debug("could not send the trace report")
 
     def start_heartbeat(self, executor_id, interval=5.0):
         """Beat on a daemon thread until `stop_heartbeat`/`close`/`bye`.

@@ -194,6 +194,10 @@ class ShmChunkRing:
         self.slot_bytes = slot_bytes
         self._owner = owner
         self._unlinked = False
+        # seconds this handle's writes waited for the consumer to free
+        # frames (the feeder's `feed.ring_write` span reports the part
+        # of it that fell to each write)
+        self.blocked_s = 0.0
 
     # -- lifecycle -----------------------------------------------------
 
@@ -294,19 +298,25 @@ class ShmChunkRing:
         struct.pack_into("<Q", self._buf, 16, seq)
 
     def _wait_free(self, seq, deadline, should_abort=None):
+        if self._state(seq) == _FREE:
+            return
         delay = 0.0
-        next_abort_check = time.time() + 0.25
-        while self._state(seq) != _FREE:
-            now = time.time()
-            if now > deadline:
-                raise RingTimeout(
-                    f"ring slot {seq % self.nslots} still unconsumed — the "
-                    "consumer process is likely dead or stuck")
-            if should_abort is not None and now >= next_abort_check:
-                should_abort()   # raises to abort the blocked write
-                next_abort_check = now + 0.25
-            time.sleep(delay)
-            delay = min(delay + 0.0002, 0.002)
+        began = time.time()
+        next_abort_check = began + 0.25
+        try:
+            while self._state(seq) != _FREE:
+                now = time.time()
+                if now > deadline:
+                    raise RingTimeout(
+                        f"ring slot {seq % self.nslots} still unconsumed — "
+                        "the consumer process is likely dead or stuck")
+                if should_abort is not None and now >= next_abort_check:
+                    should_abort()   # raises to abort the blocked write
+                    next_abort_check = now + 0.25
+                time.sleep(delay)
+                delay = min(delay + 0.0002, 0.002)
+        finally:
+            self.blocked_s += time.time() - began
 
     # -- producer ------------------------------------------------------
 

@@ -1,6 +1,7 @@
-"""Request-scoped distributed tracing for the serving stack.
+"""Tracing: request-scoped spans for the serving stack, process-scoped
+spans and counters for the feed plane.
 
-A trace ID is minted (or accepted via ``X-Trace-Id``) at the fleet
+**Serving.**  A trace ID is minted (or accepted via ``X-Trace-Id``) at the fleet
 gateway, forwarded in the replica-bound body exactly like priority
 classes (``fleet.py``), carried inside the batcher's pending item, and
 — for the exotic hops — inside the wire-snapshot meta (migration,
@@ -22,10 +23,45 @@ Span shape (JSON-ready)::
     {"trace": "4f2a…", "name": "prefill", "t0_ms": 12.3,
      "t1_ms": 14.9, "dur_ms": 2.6, "attrs": {"row": 3, "chunk": 256}}
 
-``t0_ms``/``t1_ms`` are ``time.monotonic()`` milliseconds — comparable
-within one process only; the gateway's ``GET /v1/trace/<id>`` stitches
-per-process timelines side by side (tagged with their source) rather
-than pretending clocks align.
+``t0_ms``/``t1_ms`` are ``time.monotonic()`` milliseconds of the
+recording process.  Every recorder also keeps one ANCHOR, the wall
+clock and the monotonic clock read together at its creation
+(``{"wall_ns": time.time_ns(), "mono_ns": time.monotonic_ns()}``), and
+exports it with its spans: :func:`wall_ns` puts a span of any process
+on the wall clock, so spans of different processes (and hosts, to
+NTP's accuracy) line up on one axis.  The gateway's
+``GET /v1/trace/<id>`` still stitches per-process timelines side by
+side, tagged with their source.
+
+**The feed plane** (feeder task, node, driver) has no request to hang a
+span on, so it records on ONE recorder per process:
+:func:`process` (made on first use; a forked child starts its own,
+empty, with its own anchor), :func:`span` (a context manager: balanced
+by construction), :func:`counters` (a ``metrics.Counters`` beside it)
+and :func:`report` (all of it, JSON-ready).  It always records, bounded
+by the ring: there is no switch.  When jax is loaded in the process a
+span also enters ``jax.profiler.TraceAnnotation``, so under a profiler
+session the node's spans lie on the device trace's clock (plane
+``/host:CPU``, line ``python3``); this module itself never imports jax.
+Reports of other processes that reach this one (``reservation``'s
+``REPORT`` message brings the feeders' and the nodes' to the driver)
+are kept whole beside its own spans, by source: :func:`collected`.
+
+Span and counter names of the feed plane (``node.py``, ``feed.py``,
+``cluster.py``; PERF.md names the metric that reads each)::
+
+    cluster.train                                           (driver)
+    feed.task  feed.connect  feed.source  feed.pack  feed.encode
+    feed.ring_write  feed.queue_put  feed.join              (feeder)
+    feed.take  feed.queue_get  feed.resolve  feed.stack  feed.h2d
+    node.bootstrap  node.manager_start  node.ring_create
+    node.register  node.rendezvous  node.init_distributed   (node)
+    jaxpr_trace_duration  jaxpr_to_mlir_module_duration
+    backend_compile_duration  ... (util.enable_compile_cache: what
+    jax.monitoring reports under /jax/core/compile/ and
+    /jax/compilation_cache/)
+    counters: feed.bytes.<route>  feed.items.<route>  with <route> one
+    of ring, queue, queue_oversize;  feed.ring_fallbacks
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`
@@ -36,11 +72,14 @@ open ever escapes.
 """
 import collections
 import contextlib
+import itertools
+import os
+import sys
 import threading
 import time
 import uuid
 
-from . import faults
+from . import faults, metrics
 
 # Hex digits plus dashes: accepts both uuid4().hex and W3C-style
 # dashed trace ids from external callers.  Anything else is rejected
@@ -56,6 +95,13 @@ MAX_ID_LEN = 64
 #   job.submit  job.partition  job.record  job.cancel  job.done
 DEFAULT_RING = 4096
 DEFAULT_DECODE_SAMPLE = 16
+# the process recorder's ring: a node of a long job keeps its newest
+# spans, some ten a batch; reports of other processes, the newest few
+# hundred sources (a feeder sends one a task) and, over all of them,
+# the newest MAX_REPORT_SPANS spans
+PROCESS_RING = 16384
+MAX_REPORTS = 256
+MAX_REPORT_SPANS = 8 * PROCESS_RING
 
 
 def new_id():
@@ -92,6 +138,10 @@ class Recorder:
         self._ring = collections.deque(maxlen=self.capacity)
         self.recorded = 0       # spans accepted into the ring
         self.dropped = 0        # spans dropped by the export fault site
+        # both clocks read together: what puts this recorder's spans on
+        # the wall clock, beside those of other processes
+        self.anchor = {"wall_ns": time.time_ns(),
+                       "mono_ns": time.monotonic_ns()}
 
     # -- recording ----------------------------------------------------
 
@@ -171,7 +221,7 @@ class Recorder:
         """All retained spans for a trace id, oldest first."""
         with self._lock:
             return [dict(s) for s in self._ring
-                    if s["trace"] == trace_id]
+                    if s.get("trace") == trace_id]
 
     def summary(self, trace_id):
         """Compact per-request digest for the final stream event:
@@ -186,9 +236,221 @@ class Recorder:
             st["ms"] = round(st["ms"] + s["dur_ms"], 3)
         return {"id": trace_id, "spans": len(found), "stages": stages}
 
+    def export(self, since=0):
+        """What is retained of the spans recorded after the first
+        `since`, oldest first, with the anchor that puts it on the wall
+        clock.  ``recorded`` counts from the recorder's creation: larger
+        than ``since + len(spans)`` means the ring's oldest fell off."""
+        with self._lock:
+            skip = max(0, len(self._ring) - max(0, self.recorded - since))
+            return {"anchor": dict(self.anchor),
+                    "spans": [dict(s) for s in
+                              itertools.islice(self._ring, skip, None)],
+                    "recorded": self.recorded, "dropped": self.dropped}
+
     def stats(self):
         with self._lock:
             return {"trace_spans_recorded": self.recorded,
                     "trace_spans_dropped": self.dropped,
                     "trace_ring_len": len(self._ring),
                     "trace_ring_capacity": self.capacity}
+
+
+def wall_ns(anchor, t_ms):
+    """A span's ``t0_ms``/``t1_ms`` on the wall clock (nanoseconds since
+    the epoch), by the anchor its recorder exported with it."""
+    return anchor["wall_ns"] + int(t_ms * 1e6) - anchor["mono_ns"]
+
+
+# ------------------------------------------------ the process recorder ----
+
+class _Process(Recorder):
+    """This process's recorder: its own spans and counters, and the
+    reports other processes sent here, whole and by source."""
+
+    def __init__(self):
+        super().__init__(capacity=PROCESS_RING)
+        self.counters = metrics.Counters()
+        self._ids = itertools.count(1)
+        self._reports = collections.OrderedDict()
+        # `recorded` at the last report that reached the driver: the next
+        # one carries what came after (`report(since=...)`)
+        self.sent = 0
+
+    def record(self, name, t0_ms, t1_ms, cause, attrs, span_id=None):
+        """Push one process-scoped span (`span`, `span_ended`)."""
+        self._push({"id": next(self._ids) if span_id is None else span_id,
+                    "cause": getattr(cause, "id", cause), "name": name,
+                    "t0_ms": t0_ms, "t1_ms": t1_ms, "attrs": attrs})
+
+    def add_report(self, report):
+        """Keep a report of another process.  A later report of the same
+        source and recorder (a reused feeder process reports after every
+        task, each time what it recorded since the last) continues the
+        earlier one: its spans follow, less any the earlier already
+        holds, and its counters, which count from the process's start,
+        take the earlier one's place.  Bounded: the newest
+        `PROCESS_RING` spans a source, `MAX_REPORTS` sources and
+        `MAX_REPORT_SPANS` spans over all (the oldest source goes
+        first)."""
+        source = str(report.get("source"))
+        spans = list(report.get("spans") or ())
+        with self._lock:
+            old = self._reports.pop(source, None)
+            if old is not None and old.get("anchor") == report.get("anchor") \
+                    and report.get("recorded", 0) >= old.get("recorded", 0):
+                first = report.get("recorded", 0) - len(spans)
+                spans = old["spans"] + \
+                    spans[max(0, old.get("recorded", 0) - first):]
+            self._reports[source] = dict(report,
+                                         spans=spans[-PROCESS_RING:])
+            held = sum(len(r["spans"]) for r in self._reports.values())
+            while len(self._reports) > 1 and (
+                    len(self._reports) > MAX_REPORTS
+                    or held > MAX_REPORT_SPANS):
+                held -= len(self._reports.popitem(last=False)[1]["spans"])
+
+    def reports(self):
+        with self._lock:
+            return list(self._reports.values())
+
+
+_process = None
+_process_lock = threading.Lock()
+
+
+def _forget_process():
+    # after fork, in the child: the parent's spans, counters and held
+    # reports are not the child's to report, and its anchor is its own
+    global _process, _process_lock
+    _process = None
+    _process_lock = threading.Lock()
+
+
+os.register_at_fork(after_in_child=_forget_process)
+
+
+def process():
+    """This process's recorder, made on first use."""
+    global _process
+    rec = _process
+    if rec is None:
+        with _process_lock:
+            if _process is None:
+                _process = _Process()
+            rec = _process
+    return rec
+
+
+def counters():
+    """The ``metrics.Counters`` beside the process recorder."""
+    return process().counters
+
+
+class span:
+    """``with trace.span(name, cause=parent, **attrs) as s:`` records one
+    span on the process recorder: name, start, end, ``id``, the id of
+    the span that caused it, ``attrs``.  ``s.id`` (or ``s`` itself) is
+    what a child passes as ``cause``; ``s.set(**attrs)`` adds what is
+    only known inside.  A block that raises is recorded with
+    ``abandoned`` set."""
+
+    __slots__ = ("name", "cause", "attrs", "id", "_t0", "_annotation")
+
+    def __init__(self, name, cause=None, **attrs):
+        self.name = name
+        self.cause = getattr(cause, "id", cause)
+        self.attrs = attrs
+        self.id = None
+
+    def set(self, **attrs):
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        self.id = next(process()._ids)
+        self._annotation = None
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            try:
+                self._annotation = jax.profiler.TraceAnnotation(self.name)
+                self._annotation.__enter__()
+            except Exception:      # jax half imported, or no profiler
+                self._annotation = None
+        self._t0 = _now_ms()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = _now_ms()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            self.attrs["abandoned"] = True
+        process().record(self.name, self._t0, t1, self.cause, self.attrs,
+                         span_id=self.id)
+        return False
+
+
+def span_ended(name, seconds, cause=None, **attrs):
+    """Record, on the process recorder, a span that ends now and took
+    `seconds`: for a duration something else measured and only tells
+    afterwards (`jax.monitoring`'s compile events)."""
+    t1 = _now_ms()
+    process().record(name, t1 - seconds * 1e3, t1, cause, attrs)
+
+
+def report(source=None, since=0):
+    """This process's spans and counters, JSON-ready:
+    ``{"source", "anchor", "spans", "counters", "recorded", "dropped"}``;
+    with `since`, only the spans recorded after the first `since` (the
+    counters always count from the process's start)."""
+    rec = process()
+    out = rec.export(since)
+    out["source"] = source or f"pid:{os.getpid()}"
+    out["counters"] = rec.counters.snapshot()
+    return out
+
+
+def collected(source="driver"):
+    """This process's own report (under ``source``) and, after it, every
+    report that other processes sent here."""
+    return [report(source)] + process().reports()
+
+
+def by_span(reports):
+    """``{name: [count, seconds]}`` over the spans of some reports."""
+    out = {}
+    for r in reports:
+        for s in r.get("spans", ()):
+            row = out.setdefault(s["name"], [0, 0.0])
+            row[0] += 1
+            row[1] += s["dur_ms"] / 1e3
+    return out
+
+
+def summary_lines(reports):
+    """Five lines for an operator's log: what was collected, the feed's
+    bytes by route, and the feeders', the nodes' and the driver's time by
+    span (count and seconds, longest first)."""
+    groups = {"feeder": [], "node": [], "driver": []}
+    for r in reports:
+        kind = str(r.get("source", "")).split(":")[0]
+        groups.setdefault(kind, []).append(r)
+    counts = {}
+    for r in reports:
+        for k, v in (r.get("counters") or {}).items():
+            counts[k] = counts.get(k, 0) + v
+
+    def spans_of(kind):
+        rows = sorted(by_span(groups[kind]).items(), key=lambda kv: -kv[1][1])
+        return " ".join(f"{n}={c}x{t:.3f}s" for n, (c, t) in rows) or "-"
+
+    lines = ["trace: " + " ".join(
+        f"{k}={len(v)}" for k, v in groups.items()) + " reports, "
+        f"{sum(r.get('recorded', 0) for r in reports)} spans recorded, "
+        f"{sum(r.get('dropped', 0) for r in reports)} dropped",
+        "trace: feed " + (" ".join(
+            f"{k[5:]}={v}" for k, v in sorted(counts.items())
+            if k.startswith("feed.")) or "-")]
+    lines += [f"trace: {kind} {spans_of(kind)}"
+              for kind in ("feeder", "node", "driver")]
+    return lines

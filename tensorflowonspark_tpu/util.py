@@ -235,7 +235,15 @@ def enable_compile_cache():
     absolute — never under a tempdir, a pid or the clock — and never
     relative: executors chdir into per-run scratch directories.  Call
     before the first compile of the process.
+
+    It also makes the process's set-up visible from inside: every
+    duration of a millisecond or more that JAX reports under
+    ``/jax/core/compile/`` (tracing to a jaxpr, lowering to MLIR, the
+    backend's compile) and ``/jax/compilation_cache/`` (a cache entry's
+    retrieval) becomes a `trace` span named after the event's last path
+    component.
     """
+    _trace_compile_events()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -246,6 +254,40 @@ def enable_compile_cache():
 
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+_compile_events_traced = False
+_MIN_COMPILE_EVENT_SECS = 1e-3
+
+
+def _trace_compile_events():
+    """One `jax.monitoring` duration listener a process, however often
+    `enable_compile_cache` is called."""
+    global _compile_events_traced
+    if _compile_events_traced:
+        return
+    _compile_events_traced = True
+    import jax
+
+    from . import trace
+
+    def on_duration(event, seconds, **kw):
+        # `compile_time_saved_sec` is an estimate of time NOT spent: no
+        # interval to put on a timeline
+        if not event.startswith(("/jax/core/compile/",
+                                 "/jax/compilation_cache/")) \
+                or event.endswith("_saved_sec"):
+            return
+        if seconds < _MIN_COMPILE_EVENT_SECS:
+            # JAX reports a trace for every inner `jit` it passes through
+            # (thousands in one step's tracing, microseconds each, all
+            # inside the outer one's span, which covers their time): not
+            # recorded, or they would push the feed's spans out of the ring
+            return
+        trace.span_ended(event.rsplit("/", 1)[1], seconds,
+                         **{k: str(v) for k, v in kw.items()})
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def absolutize_args(args, keys=("data_dir", "model_dir", "export_dir",

@@ -38,87 +38,6 @@ def trace(log_dir):
     logger.info("profiler trace written to %s", log_dir)
 
 
-def parse_perfetto_trace(path_or_events, device_only=True, group=True):
-    """Aggregate a perfetto trace (`jax.profiler` with
-    ``create_perfetto_trace=True``) into per-op device time.
-
-    Returns [(name, total_dur_us, count)] sorted by time desc.  `group`
-    collapses versioned XLA op names ("fusion.123" -> "fusion"); set
-    False for the per-instance view.  Accepts a path to
-    ``perfetto_trace.json.gz``/.json, a trace dict, or an event list.
-    """
-    import collections
-    import gzip
-    import json
-
-    if isinstance(path_or_events, str):
-        opener = (gzip.open if path_or_events.endswith(".gz") else open)
-        with opener(path_or_events, "rt") as f:
-            trace = json.load(f)
-        events = trace.get("traceEvents", [])
-    elif isinstance(path_or_events, dict):
-        events = path_or_events.get("traceEvents", [])
-    else:
-        events = path_or_events
-
-    pids = {ev.get("pid"): ev.get("args", {}).get("name", "")
-            for ev in events
-            if ev.get("ph") == "M" and ev.get("name") == "process_name"}
-    dur = collections.Counter()
-    cnt = collections.Counter()
-    for ev in events:
-        if ev.get("ph") != "X" or "dur" not in ev:
-            continue
-        track = pids.get(ev.get("pid"), "")
-        if device_only and not ("TPU" in track or "GPU" in track
-                                or "/device:" in track):
-            continue
-        name = ev.get("name", "?")
-        if group:
-            name = name.split(".")[0]
-        dur[name] += ev["dur"]
-        cnt[name] += 1
-    return [(name, d, cnt[name]) for name, d in dur.most_common()]
-
-
-def op_breakdown(fn, *args, steps=3, log_dir=None, top=20):
-    """Run `fn(*args)` under the profiler and return the per-op device-time
-    breakdown — the 'where does my step go' question in one call.
-
-    `fn` should be the jitted step (warmed up by this helper); the
-    result's scale is `steps` executions.  Returns
-    [(op_name, total_us, count)]; also logs the top entries.
-    """
-    import glob
-    import tempfile
-
-    import jax
-    import numpy as np
-
-    def _sync(out):
-        # host readback of every leaf: a barrier that holds on any runtime
-        for leaf in jax.tree_util.tree_leaves(out):
-            np.asarray(leaf)
-
-    _sync(fn(*args))                      # warmup/compile
-    log_dir = log_dir or tempfile.mkdtemp(prefix="tfos_profile_")
-    jax.profiler.start_trace(log_dir, create_perfetto_trace=True)
-    out = None
-    for _ in range(steps):
-        out = fn(*args)
-    _sync(out)
-    jax.profiler.stop_trace()
-    traces = glob.glob(os.path.join(log_dir, "**", "perfetto_trace.json.gz"),
-                       recursive=True)
-    if not traces:
-        raise RuntimeError(f"no perfetto trace produced under {log_dir}")
-    rows = parse_perfetto_trace(sorted(traces)[-1])
-    for name, us, n in rows[:top]:
-        logger.info("%10.3f ms/step x%-5d %s", us / 1e3 / steps, n // steps,
-                    name)
-    return rows
-
-
 def start_tensorboard(log_dir, port=None):
     """Launch a TensorBoard subprocess if the binary is available.
 

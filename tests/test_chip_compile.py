@@ -19,6 +19,7 @@ worker collects the same tests and only the one handed this file loads
 the library), and every compile runs in the test's own process.
 """
 import functools
+import re
 
 import pytest
 
@@ -71,6 +72,23 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "adamw_fused")
+
+
+def _kernels(text):
+    """Which of `KERNELS` the `tpu_custom_call` instructions of a compiled
+    text are named after.  An instruction takes its name from the
+    `pallas_call`'s `name=` with the transformations it went through
+    around it (`%transpose_jvp_flash_dq__.3`), and a device trace's event
+    is called by the whole instruction; one named after none of them is
+    returned as it is called."""
+    found = set()
+    for name in re.findall(
+            r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"', text):
+        found.add(next((k for k in KERNELS if k in name), name))
+    return found
+
+
 def _qkv(chip):
     return (chip((B, S, H, DH), jnp.bfloat16),
             chip((B, S, N_KV, DH), jnp.bfloat16),
@@ -79,7 +97,9 @@ def _qkv(chip):
 
 def test_flash_forward_lowers(chip):
     fn = functools.partial(flash_attention, causal=True, interpret=False)
-    assert "tpu_custom_call" in _compile(fn, *_qkv(chip))
+    text = _compile(fn, *_qkv(chip))
+    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"flash_fwd"}
 
 
 def test_flash_backward_lowers(chip):
@@ -90,6 +110,7 @@ def test_flash_backward_lowers(chip):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip))
     # forward + dq + dk/dv kernels
     assert text.count("tpu_custom_call") >= 3
+    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
 
 
 def test_adamw_fused_apply_lowers(chip):
@@ -107,6 +128,7 @@ def test_adamw_fused_apply_lowers(chip):
         state)
     text = _compile(opt.apply, params, state, params)
     assert text.count("tpu_custom_call") >= len(shapes)
+    assert _kernels(text) == {"adamw_fused"}
 
 
 def test_adamw_fused_apply_lowers_under_a_mesh(topo, chip):
