@@ -466,6 +466,20 @@ def _payload_bytes(packed):
     return 0
 
 
+def _row_slices(packed, limit):
+    """``packed`` as chunks of at most ``limit`` bytes each, in order:
+    itself when it fits, else row slices of it, none under one record.
+    A PackedChunk is columnar and uniform, so a slice is the same rows
+    of every column (views: nothing is copied)."""
+    n = len(packed)
+    rows = max(1, limit * n // max(_payload_bytes(packed), 1))
+    if rows >= n:
+        return [packed]
+    return [marker.PackedChunk(tuple(c[a:a + rows] for c in packed.columns),
+                               packed.row_type, packed.matrix)
+            for a in range(0, n, rows)]
+
+
 def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
                  progress_fn=None, progress_every=512, poll_cb=None,
                  cause=None):
@@ -476,11 +490,17 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
     Transport: when the node advertises a shared-memory ring
     (`shm.discover`), chunk payloads are copied into the ring and the
     queue carries tiny `shm.ShmRef` handles — the SURVEY.md §7
-    "process-boundary feed throughput" fix.  Packed sub-chunks coalesce
-    into ~TFOS_TPU_CHUNK_BYTES payloads first, because each queue
-    operation costs a manager round trip and per-item overhead (not
-    bandwidth) dominates once bytes ride shared memory.  Without a ring,
-    uniform numeric chunks go through the queue as columnar PackedChunks
+    "process-boundary feed throughput" fix.  One size rule, set by the
+    ring the feeder attached to (`TFOS_TPU_RING_MB` is the one knob): a
+    payload holds an eighth of the ring at most, 8 MiB of the default
+    64 MiB.  Packed sub-chunks coalesce up to that first, because each
+    queue operation costs a manager round trip and per-item overhead
+    (not bandwidth) dominates once bytes ride shared memory; a packed
+    chunk over it (wide records: 512 images are 77 MB) is cut into row
+    slices that each fit, counted under `feed.chunk_splits`.
+    `CHUNK_SIZE` is the number of records packed at once and the grain
+    of progress markers, not a transport size.  Without a ring, uniform
+    numeric chunks go through the queue as columnar PackedChunks
     (round-1 behavior, still the fallback when rings cannot be created).
 
     Traced per chunk, never per record (`trace.span`, children of
@@ -488,7 +508,8 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
     `feed.encode`, `feed.ring_write`, and `feed.queue_put` for every data
     item with the route its bytes took: ``ring_ref`` (the ring; counted
     under `feed.bytes.ring`), ``queue`` (no ring, or a fallback) or
-    ``queue_oversize`` (a chunk larger than the ring itself)."""
+    ``queue_oversize`` (a single record larger than the ring itself:
+    the one thing that cannot be cut)."""
     counters = trace.counters()
     ring = None
     with trace.span("feed.connect", cause=cause, step="ring") as sp:
@@ -502,9 +523,14 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
                 logger.warning("could not attach shm ring; using queue "
                                "transport", exc_info=True)
         sp.set(ring=ring is not None)
-    target_bytes = int(os.environ.get("TFOS_TPU_CHUNK_BYTES", 8 << 20))
     if ring is not None:
-        target_bytes = min(target_bytes, ring.capacity_bytes // 4)
+        # what one record may weigh and still ride the ring, and an
+        # eighth of it, what one payload may (8 MiB less 64 KiB at the
+        # default 64 MiB): the 128th left over covers codec metadata, so
+        # a ring write stays within its frames instead of spilling into
+        # an extra mostly-empty slot
+        ring_room = ring.capacity_bytes - ring.capacity_bytes // 128
+        payload_room = ring_room // 8
 
     pending = []        # packed sub-chunks awaiting one coalesced write
     pending_bytes = 0
@@ -594,23 +620,28 @@ def _push_chunks(q, iterator, mgr=None, timeout=600.0, equeue=None,
             sp.set(bytes=nb)
         if ring is None:
             _put(packed, "queue", nb)
-            return
-        if isinstance(packed, marker.PackedChunk):
-            if nb > ring.capacity_bytes - (1 << 16):
-                # larger than the ring itself: this one rides the queue
-                _flush()
-                _put(packed, "queue_oversize", nb)
-                return
-            # flush BEFORE the payload would cross the target (the 64 KiB
-            # margin covers codec metadata), so each ring write stays
-            # within its intended frame budget instead of spilling into
-            # an extra mostly-empty slot
-            if pending and pending_bytes + nb > target_bytes - (1 << 16):
-                _flush()
-            pending.append(packed)
-            pending_bytes += nb
-            if len(pending) >= 64:
-                _flush()
+        elif isinstance(packed, marker.PackedChunk):
+            slices = _row_slices(packed, payload_room)
+            if len(slices) > 1:
+                counters.inc("feed.chunk_splits")
+            for piece in slices:
+                piece_nb = _payload_bytes(piece)
+                if ring is None:
+                    # a ring write failed under an earlier slice
+                    _put(piece, "queue", piece_nb)
+                elif piece_nb > ring_room:
+                    # one record larger than the ring itself: it cannot
+                    # be cut, this one rides the queue
+                    _flush()
+                    _put(piece, "queue_oversize", piece_nb)
+                else:
+                    # flush BEFORE the payload would cross the budget
+                    if pending and pending_bytes + piece_nb > payload_room:
+                        _flush()
+                    pending.append(piece)
+                    pending_bytes += piece_nb
+                    if len(pending) >= 64:
+                        _flush()
         else:
             # object records: size unknowable without pickling; ship the
             # coalesced buffer right away

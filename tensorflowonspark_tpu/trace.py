@@ -61,7 +61,9 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     jax.monitoring reports under /jax/core/compile/ and
     /jax/compilation_cache/)
     counters: feed.bytes.<route>  feed.items.<route>  with <route> one
-    of ring, queue, queue_oversize;  feed.ring_fallbacks
+    of ring, queue, queue_oversize (a record larger than the ring);
+    feed.ring_fallbacks  feed.chunk_splits (packed chunks cut by bytes
+    to fit ring payloads)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

@@ -5,7 +5,9 @@ DataFeed semantics) at the transport layer below them: payload bytes ride
 /dev/shm, refs ride the queue.
 """
 import multiprocessing as mp
+import queue
 import time
+import types
 
 import numpy as np
 import pytest
@@ -266,3 +268,203 @@ class TestFeedIntegration:
             ring.close()
             ring.unlink()
             mgr.shutdown()
+
+
+# ---- ring payloads are cut by bytes (node._push_chunks, no manager process)
+
+class _FakeQueue(queue.Queue):
+    """The input queue without a manager process; keeps what was put."""
+
+    def __init__(self):
+        super().__init__()
+        self.puts = []
+
+    def put(self, item, *args, **kwargs):
+        self.puts.append(item)
+        super().put(item, *args, **kwargs)
+
+
+class _FakeManager:
+    """What `_push_chunks` and `DataFeed` ask of the queue manager."""
+
+    def __init__(self, ring):
+        self.kv = {"shm_ring": ring.info()}
+        self.q = _FakeQueue()
+
+    def get_queue(self, name):
+        assert name == "input"
+        return self.q
+
+    def get(self, key):
+        value = self.kv.get(key)
+        return None if value is None else types.SimpleNamespace(
+            _getvalue=lambda: value)
+
+    def set(self, key, value):
+        self.kv[key] = value
+
+
+def _push(ring, records, batch=None, **kwargs):
+    """`node._push_chunks(records)` in a thread (a ring smaller than the
+    records blocks it until the consumer frees frames), then the end of
+    the feed.  Returns what it returned, the items it put, its
+    `feed.queue_put` spans' (route, bytes), what its counters counted
+    and, with ``batch``, the numpy batches a `DataFeed` took meanwhile."""
+    import threading
+
+    from tensorflowonspark_tpu import feed as feed_mod
+    from tensorflowonspark_tpu import node as node_mod
+    from tensorflowonspark_tpu import trace
+
+    mgr = _FakeManager(ring)
+    before = dict(trace.counters().snapshot())
+    out = {}
+
+    def feeder():
+        with trace.span("test.push") as task:
+            out["task"] = task.id
+            try:
+                out["count"] = node_mod._push_chunks(
+                    mgr.q, iter(records), mgr=mgr, timeout=60, cause=task,
+                    **kwargs)
+            finally:
+                mgr.q.put(None)
+
+    t = threading.Thread(target=feeder, daemon=True)
+    t.start()
+    batches = []
+    if batch:
+        df = feed_mod.DataFeed(mgr)
+        while not df.should_stop():
+            got = df.next_numpy_batch(batch, timeout=30)
+            if got is None:
+                break
+            batches.append(got)
+    t.join(60)
+    assert not t.is_alive() and "count" in out
+    after = trace.counters().snapshot()
+    counted = {k: v - before.get(k, 0) for k, v in after.items()
+               if k.startswith("feed.") and v != before.get(k, 0)}
+    puts = [(s["attrs"]["route"], s["attrs"]["bytes"])
+            for s in trace.report()["spans"]
+            if s["name"] == "feed.queue_put" and s["cause"] == out["task"]]
+    return out["count"], mgr.q.puts[:-1], puts, counted, batches
+
+
+@pytest.fixture
+def small_ring():
+    """Eight slots of 64 KiB: a payload may hold 65024 bytes, a record
+    that rides the ring 520192."""
+    r = shm.ShmChunkRing.create(slot_bytes=1 << 16, nslots=8)
+    yield r
+    r.close()
+    r.unlink()
+
+
+class TestPayloadsCutByBytes:
+    def test_image_records_cross_slice_and_batch_boundaries(self, small_ring):
+        """(wide uint8 array, int32 label) records, as the ResNet cell's:
+        512 of them are 1.5 MB against a payload of 65024 bytes, so each
+        packed chunk goes as slices of 21 records; batches of 100 line up
+        with neither.  Field by field what was put in, in order."""
+        rng = np.random.default_rng(7)
+        records = [(rng.integers(0, 256, 3000, dtype=np.uint8), np.int32(i))
+                   for i in range(700)]
+        count, items, puts, counted, batches = _push(
+            small_ring, records, batch=100)
+        assert count == 700 == sum(len(ref) for ref in items)
+        assert [len(b[1]) for b in batches] == [100] * 7
+        xs = np.concatenate([b[0] for b in batches])
+        ys = np.concatenate([b[1] for b in batches])
+        assert xs.dtype == np.uint8 and ys.dtype == np.int32
+        np.testing.assert_array_equal(xs, np.stack([r[0] for r in records]))
+        np.testing.assert_array_equal(ys, np.arange(700, dtype=np.int32))
+        # every item a ref, no payload over an eighth of the ring
+        assert all(isinstance(ref, shm.ShmRef) for ref in items)
+        assert max(ref.nbytes for ref in items) <= \
+            small_ring.capacity_bytes // 8
+        assert {route for route, _ in puts} == {"ring_ref"}
+        assert [len(ref) for ref in items[:25]] == [21] * 24 + [8]
+        assert counted["feed.chunk_splits"] == 2
+        assert counted["feed.items.ring"] == len(items) == len(puts)
+        assert set(counted) == {"feed.chunk_splits", "feed.items.ring",
+                                "feed.bytes.ring"}
+
+    @pytest.mark.parametrize("values,route", [
+        (160 * 1024, "queue_oversize"),     # 640 KiB: the ring holds 512
+        (25 * 1024, "ring_ref"),            # 100 KiB: over a payload's room
+    ], ids=["larger_than_the_ring", "larger_than_a_payload"])
+    def test_a_record_that_cannot_be_cut_goes_alone(self, small_ring,
+                                                    values, route):
+        """A slice is never under one record.  One larger than a payload's
+        room still rides the ring, a payload of its own; only one larger
+        than the ring itself rides the queue, whole."""
+        records = [np.full(values, i, np.float32) for i in range(3)]
+        count, items, puts, counted, batches = _push(
+            small_ring, records, batch=2)
+        assert count == 3
+        nbytes = values * 4 if route == "queue_oversize" else items[0].nbytes
+        assert puts == [(route, nbytes)] * 3
+        assert values * 4 <= nbytes < values * 4 + 512
+        kind = marker.PackedChunk if route == "queue_oversize" else shm.ShmRef
+        assert [type(i) for i in items] == [kind] * 3
+        assert all(len(i) == 1 for i in items)
+        assert counted["feed.chunk_splits"] == 1
+        assert [len(b) for b in batches] == [2, 1]
+        np.testing.assert_array_equal(np.concatenate(batches),
+                                      np.stack(records))
+
+    def test_a_progress_marker_follows_every_slice_it_claims(self):
+        """With `progress_fn` a chunk is 100 records here, 1.6 MB, cut in
+        slices of 31: a marker is put only when the records before it,
+        down to the last slice, are in the queue."""
+        ring = shm.ShmChunkRing.create(slot_bytes=1 << 16, nslots=64)
+        try:
+            records = [np.full(4096, i, np.float32) for i in range(230)]
+            count, items, puts, counted, _ = _push(
+                ring, records, progress_every=100,
+                progress_fn=lambda n: marker.Progress(7, n))
+        finally:
+            ring.close()
+            ring.unlink()
+        assert count == 230
+        marks, seen = [], 0
+        for item in items:
+            if isinstance(item, marker.Progress):
+                assert item.offset == seen
+                marks.append(item.offset)
+            else:
+                seen += len(item)
+        assert marks == [100, 200, 230] and seen == 230
+        assert [len(i) for i in items if isinstance(i, shm.ShmRef)] == \
+            [31, 31, 31, 7] * 2 + [30]
+        assert counted["feed.chunk_splits"] == 2    # the 30 fit one payload
+
+    @pytest.mark.parametrize("records,expected", [
+        # the GPT-2 cell's rows: three 2.1 MB chunks a payload
+        ([np.full(1025, i, np.int32) for i in range(2200)],
+         [("ring_ref", 6297861, 1536), ("ring_ref", 2722586, 664)]),
+        # 5.1 MB chunks: two of them would cross 8 MiB less 64 KiB, the
+        # second and the short third do not
+        ([np.full(2500, i, np.float32) for i in range(1100)],
+         [("ring_ref", 5120069, 512), ("ring_ref", 5880186, 588)]),
+        # python scalars: 64 sub-chunks a payload, however small
+        (list(range(40000)),
+         [("ring_ref", 266599, 32768), ("ring_ref", 58929, 7232)]),
+    ], ids=["lm_rows", "wide_rows", "scalars"])
+    def test_chunks_under_the_budget_are_put_as_before(self, records,
+                                                       expected):
+        """At the default ring a chunk under 8 MiB is never cut: the same
+        puts, route, bytes and records each, as before payloads were
+        bounded by the ring (the numbers are the parent commit's)."""
+        ring = shm.ShmChunkRing.create()
+        try:
+            assert ring.capacity_bytes == 64 << 20
+            count, items, puts, counted, _ = _push(ring, records)
+        finally:
+            ring.close()
+            ring.unlink()
+        assert count == len(records)
+        assert [(route, nbytes, len(item)) for (route, nbytes), item
+                in zip(puts, items)] == expected
+        assert "feed.chunk_splits" not in counted

@@ -4,8 +4,9 @@ reservation channel (REPORT) — on the CPU, LocalBackend, toy records.
 
 One small cluster runs once for the module (`fed`); the two other
 clusters pin what only another set-up shows: a ring smaller than one
-chunk (ROADMAP S1's reading of `_push_chunks`, at toy size), and the
-`trace.export` fault site armed in every process.
+chunk (the ResNet cell's case at toy size: `_push_chunks` cuts the chunk
+by bytes) or than one record, and the `trace.export` fault site armed in
+every process.
 """
 import json
 import logging
@@ -28,13 +29,19 @@ ROW = 1024              # float32 values a record: 4 KiB, 2 MiB a chunk
 
 def fn_consume(args, ctx):
     """Node function: numpy batches to the end of the feed; the count of
-    records consumed is left in the executor's directory."""
+    records consumed is left in the executor's directory.  Record i of a
+    partition is a row of i's: one out of its place fails the node."""
     df = ctx.get_data_feed()
     n = 0
+    last = -1
     while not df.should_stop():
         batch = df.next_numpy_batch(64, timeout=60)
         if batch is not None:
             n += len(batch)
+            ids = np.concatenate([[last], batch[:, 0]])
+            if not ((ids[1:] == ids[:-1] + 1) | (ids[1:] == 0)).all():
+                raise RuntimeError(f"records out of order: {ids}")
+            last = ids[-1]
     with open(os.path.join(ctx.working_dir, "consumed"), "w") as f:
         f.write(str(n))
 
@@ -527,13 +534,13 @@ def test_a_failed_node_still_reports_and_a_bad_summary_masks_nothing(
 
 # --------------------------------- a ring smaller than one chunk (S1) ----
 
-def test_a_chunk_larger_than_the_ring_rides_the_queue_oversize(
+def test_a_chunk_larger_than_the_ring_is_cut_and_rides_the_ring(
         tmp_path, monkeypatch):
     """512 records of 16 KiB are an 8 MiB chunk; the least ring there is
     (`TFOS_TPU_RING_MB=1`: 64 slots of 64 KiB, 4 MiB) cannot hold it, so
-    `_push_chunks._send` takes the `q.put(packed)` branch and the bytes
-    cross the manager's socket.  This is ROADMAP S1's reading of the
-    ResNet cell (77 MB chunks, 64 MiB ring) at toy size."""
+    `_push_chunks` cuts it into row slices of 31 records that each fit a
+    payload (an eighth of the ring) and every byte rides the ring.  This
+    is the ResNet cell (77 MB chunks, 64 MiB ring) at toy size."""
     monkeypatch.setenv("TFOS_TPU_SERVER_HOST", "127.0.0.1")
     monkeypatch.setenv("TFOS_TPU_RING_MB", "1")
     wide = 4 * ROW
@@ -541,13 +548,45 @@ def test_a_chunk_larger_than_the_ring_rides_the_queue_oversize(
     assert consumed == 1024
     kinds = _by_kind(report)
     c = _counters(kinds["feeder"])
-    assert c == {"feed.bytes.queue_oversize": 1024 * wide * 4,
-                 "feed.items.queue_oversize": 2}
     puts = _spans(kinds["feeder"], "feed.queue_put")
-    assert [s["attrs"]["route"] for s in puts] == ["queue_oversize"] * 2
+    assert set(c) == {"feed.bytes.ring", "feed.items.ring",
+                      "feed.chunk_splits"}
+    assert c["feed.chunk_splits"] == 2
+    assert c["feed.items.ring"] == len(puts) == 2 * 17
+    # the records' bytes and the codec's few hundred a payload
+    data = 1024 * wide * 4
+    assert data < c["feed.bytes.ring"] < data + 512 * len(puts)
+    assert max(s["attrs"]["bytes"] for s in puts) <= (4 << 20) // 8
+    assert [s["attrs"]["route"] for s in puts] == ["ring_ref"] * len(puts)
     gets = [s for s in _spans(kinds["node"], "feed.queue_get")
             if "item" in s["attrs"]]
-    assert [s["attrs"]["got"] for s in gets] == ["packed"] * 2
+    assert [s["attrs"]["got"] for s in gets] == ["ring_ref"] * len(puts)
+    resolved = _spans(kinds["node"], "feed.resolve")
+    assert sum(s["attrs"]["bytes"] for s in resolved) == c["feed.bytes.ring"]
+    # (in order, or `fn_consume` had failed the run)
+    assert [s["attrs"]["rows"] for s in _spans(kinds["node"], "feed.take")
+            if s["attrs"]["rows"]] == [64] * 16
+
+
+def test_a_record_larger_than_the_ring_rides_the_queue_oversize(
+        tmp_path, monkeypatch):
+    """What cannot be cut: one record of 5 MiB against a ring of 4.  It
+    crosses the manager's socket whole, the only thing that still may."""
+    monkeypatch.setenv("TFOS_TPU_SERVER_HOST", "127.0.0.1")
+    monkeypatch.setenv("TFOS_TPU_RING_MB", "1")
+    wide = 1280 * ROW
+    report, consumed = _run(tmp_path, [_records(3, wide)])
+    assert consumed == 3
+    kinds = _by_kind(report)
+    assert _counters(kinds["feeder"]) == {
+        "feed.bytes.queue_oversize": 3 * wide * 4,
+        "feed.items.queue_oversize": 3, "feed.chunk_splits": 1}
+    puts = _spans(kinds["feeder"], "feed.queue_put")
+    assert [(s["attrs"]["route"], s["attrs"]["bytes"]) for s in puts] == \
+        [("queue_oversize", wide * 4)] * 3
+    gets = [s for s in _spans(kinds["node"], "feed.queue_get")
+            if "item" in s["attrs"]]
+    assert [s["attrs"]["got"] for s in gets] == ["packed"] * 3
     assert not _spans(kinds["node"], "feed.resolve")
 
 
