@@ -42,12 +42,35 @@ class TransformerConfig:
     rope: bool = False            # rotary position embeddings instead of
     # a learned absolute pos_embed table
     rope_theta: float = 10000.0
+    head_dim: Optional[int] = None  # None = d_model // n_heads; set where
+    # n_heads * head_dim is not d_model (out is [n_heads*head_dim, d_model])
+    layer_types: Optional[tuple] = None  # one kind a layer, "full_attention"
+    # or "sliding_attention" (None = all full); a sliding layer sees the
+    # last `sliding_window` keys only and rotates by `rope_local_theta`
+    sliding_window: Optional[int] = None
+    rope_local_theta: Optional[float] = None  # sliding layers' base (plain
+    # rotary; None = rope_theta)
+    rope_yarn_factor: float = 1.0  # full layers: YaRN scaling of rope_theta's
+    # frequencies (1.0 = plain rotary); the four fields below are its
+    # published parameters, `rope_attention_factor` multiplies cos and sin
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
     num_experts: int = 0          # 0 = dense MLP; >0 = MoE with EP sharding
     moe_every: int = 2            # every k-th layer is MoE (when enabled)
     moe_router: str = "dense"     # dense (every token through every expert,
-    # exact, test-friendly) | topk (GShard-style capacity dispatch)
+    # exact, test-friendly) | topk (GShard-style capacity dispatch) |
+    # dropless (top-k, rows sorted by expert through ops.grouped_matmul:
+    # no capacity, no token dropped; one chip, raises under a mesh)
     moe_top_k: int = 1            # experts per token under the topk router
     moe_capacity_factor: float = 1.25  # per-expert slots = factor*k*T/E
+    moe_d_ff: Optional[int] = None  # an expert's width (None = d_ff)
+    moe_experts_held: Optional[int] = None  # dropless: this chip's share of
+    # an expert-parallel layer: experts [offset, offset + held) live here,
+    # the router scores all `num_experts`, and what the absent experts
+    # would have added is left out of the layer's output (None = all)
+    moe_expert_offset: int = 0
     remat: bool = False
     ring_attention_axis: Optional[str] = None  # e.g. "tp" to enable CP
     ulysses_axis: Optional[str] = None  # all-to-all sequence parallelism
@@ -122,23 +145,80 @@ class TransformerConfig:
     # full-gather read (O(pool) write / O(max_seq) read per chunk, kept
     # for parity tests and as the mesh fallback like paged_attn_impl)
 
+    def __post_init__(self):
+        if isinstance(self.layer_types, list):   # from a JSON file
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        kinds = self.layer_types or ()
+        if kinds and len(kinds) != self.n_layers:
+            raise ValueError(f"layer_types names {len(kinds)} layers, "
+                             f"n_layers={self.n_layers}")
+        bad = set(kinds) - set(LAYER_KINDS)
+        if bad:
+            raise ValueError(f"layer_types {sorted(bad)} not in {LAYER_KINDS}")
+        if SLIDING in kinds and not self.sliding_window:
+            raise ValueError("sliding_attention layers need sliding_window")
+        missing = [name for name, on in (
+            ("layer_types", bool(kinds)),
+            ("sliding_window", bool(self.sliding_window)),
+            ("moe_experts_held", self.moe_experts_held is not None),
+            ("moe_router='dropless'", self.moe_router == "dropless"
+             and self.num_experts > 0)) if on]
+        if self.decode and missing:
+            raise NotImplementedError(
+                f"decode=True with {', '.join(missing)}: the kv cache keeps "
+                "no window, its incremental attention no layer kinds, and "
+                "routing has no incremental form here (ROADMAP R1/R5)")
 
-def apply_rope(x, positions, theta=10000.0):
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+LAYER_KINDS = (FULL, SLIDING)
+
+
+def rope_inv_freq(head_dim, theta, yarn_factor=1.0, original_max=0,
+                  beta_fast=32.0, beta_slow=1.0):
+    """`theta ** (-2m / head_dim)` for m in [0, head_dim / 2); with
+    `yarn_factor` > 1 the YaRN blend: pairs that turn more than `beta_fast`
+    times over `original_max` positions keep their frequency, pairs that
+    turn less than `beta_slow` times have it divided by the factor, a
+    linear ramp between."""
+    import math
+
+    half = head_dim // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn_factor == 1.0:
+        return freqs
+
+    def pair_of(turns):
+        return head_dim * math.log(original_max / (2 * math.pi * turns)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 - ramp) * freqs + ramp * freqs / yarn_factor
+
+
+def apply_rope(x, positions, theta=10000.0, inv_freq=None, factor=1.0):
     """Rotary position embedding over [..., S, H, D] (split-half pairing).
 
     `positions`: [S] (or [B, S]) absolute token positions; q·k after
     rotation depends only on relative position, so RoPE composes with
     sequence-parallel attention (rotation happens before the CP dispatch,
-    on globally-indexed activations).
+    on globally-indexed activations).  `inv_freq` [D/2] replaces
+    `theta`'s frequencies (`rope_inv_freq`); `factor` multiplies cos and
+    sin (YaRN's attention factor: the logits carry its square).
     """
     D = x.shape[-1]
     if D % 2:
         raise ValueError(f"head_dim={D} must be even for RoPE")
     half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = rope_inv_freq(D, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, half]
     cos = jnp.cos(angles)[..., None, :]                        # [..., S, 1, half]
     sin = jnp.sin(angles)[..., None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin,
@@ -216,6 +296,7 @@ class QuantDense(nn.Module):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    layer_type: str = FULL        # this layer's kind (cfg.layer_types[i])
 
     def _proj(self, name, features, x, dtype):
         """One attention projection, with an optional PER-ROW LoRA delta.
@@ -250,7 +331,9 @@ class Attention(nn.Module):
     def __call__(self, x, mask=None):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        head_dim = cfg.d_model // cfg.n_heads
+        head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
+        sliding = self.layer_type == SLIDING
+        window = cfg.sliding_window if sliding else None
         n_kv = cfg.n_heads if cfg.n_kv_heads is None else cfg.n_kv_heads
         if n_kv < 1:
             raise ValueError(f"n_kv_heads={n_kv} must be >= 1 (or None)")
@@ -258,7 +341,7 @@ class Attention(nn.Module):
             raise ValueError(
                 f"n_heads={cfg.n_heads} must be divisible by "
                 f"n_kv_heads={n_kv}")
-        q = self._proj("query", cfg.d_model, x, dtype)
+        q = self._proj("query", cfg.n_heads * head_dim, x, dtype)
         k = self._proj("key", n_kv * head_dim, x, dtype)
         v = self._proj("value", n_kv * head_dim, x, dtype)
         B, S = x.shape[0], x.shape[1]
@@ -286,8 +369,20 @@ class Attention(nn.Module):
                 # sequence shard; rotate with global token positions
                 if cp_axis in _bound_axes(_ambient_mesh()):
                     pos = pos + jax.lax.axis_index(cp_axis) * S
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+            # rotary parameters by layer kind: plain on the window layers,
+            # YaRN with its attention factor on the full ones (at its
+            # defaults the plain `rope_theta` frequencies, factor 1)
+            if sliding:
+                rope = dict(inv_freq=rope_inv_freq(
+                    head_dim, cfg.rope_local_theta or cfg.rope_theta))
+            else:
+                rope = dict(factor=cfg.rope_attention_factor,
+                            inv_freq=rope_inv_freq(
+                    head_dim, cfg.rope_theta, cfg.rope_yarn_factor,
+                    cfg.rope_yarn_original_max, cfg.rope_yarn_beta_fast,
+                    cfg.rope_yarn_beta_slow))
+            q = apply_rope(q, pos, **rope)
+            k = apply_rope(k, pos, **rope)
 
         if cfg.attention_impl not in ("auto", "flash", "dense"):
             raise ValueError(
@@ -308,6 +403,10 @@ class Attention(nn.Module):
                     "causal=False has no incremental form")
             out = self._decode_attention(q, k, v, mask)
         elif cfg.ring_attention_axis or cfg.ulysses_axis:
+            if window:
+                raise NotImplementedError(
+                    "sliding_attention layers are not supported with "
+                    "sequence-parallel attention")
             if mask is not None:
                 raise NotImplementedError(
                     "key-padding masks are not supported with "
@@ -324,7 +423,7 @@ class Attention(nn.Module):
                     and jax.default_backend() == "tpu")):
                 # GQA-native kernel: narrow k/v go straight in (no
                 # repeated kv in HBM, dk/dv come back narrow)
-                out = _flash_dispatch(q, k, v, cfg)
+                out = _flash_dispatch(q, k, v, cfg, window)
             else:
                 # dense path: broadcast back to full heads for the
                 # attention cores (the narrow projection already saved
@@ -338,8 +437,8 @@ class Attention(nn.Module):
                         "attention_impl='flash' with a key-padding mask "
                         "falls back to dense O(S^2) attention")
                 out = dot_product_attention(q, k, v, causal=cfg.causal,
-                                            mask=mask)
-        out = out.reshape(B, S, cfg.d_model)
+                                            mask=mask, window=window)
+        out = out.reshape(B, S, cfg.n_heads * head_dim)
         return self._proj("out", cfg.d_model, out, dtype)
 
     def _decode_attention(self, q, k, v, mask):
@@ -718,8 +817,8 @@ def _seqpar_dispatch(q, k, v, cfg):
               batch_axes=batch_axes or None, **impl_kwargs)
 
 
-def _flash_dispatch(q, k, v, cfg):
-    """Route to the pallas flash kernel.
+def _flash_dispatch(q, k, v, cfg, window=None):
+    """Route to the pallas flash kernel (`window`: a sliding layer's).
 
     `pallas_call` is a custom call GSPMD cannot partition, so under an
     active mesh the kernel must be wrapped in shard_map — batch over dp,
@@ -731,7 +830,7 @@ def _flash_dispatch(q, k, v, cfg):
     from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
     mesh = _ambient_mesh()
     if mesh is None:
-        return flash_attention(q, k, v, causal=cfg.causal)
+        return flash_attention(q, k, v, causal=cfg.causal, window=window)
     axes = mesh.axis_names
 
     def _divides(axis, dim):
@@ -754,23 +853,26 @@ def _flash_dispatch(q, k, v, cfg):
     for name, got in (("dp", dp), ("tp", tp)):
         if got is None and name in axes and mesh.shape[name] > 1:
             kf, vf = _kv_repeat(q, k, v)   # dense core needs full heads
-            return dot_product_attention(q, kf, vf, causal=cfg.causal)
+            return dot_product_attention(q, kf, vf, causal=cfg.causal,
+                                         window=window)
     import functools
     from jax.sharding import PartitionSpec as P
 
     spec = P(dp, None, tp, None)
-    local = functools.partial(flash_attention, causal=cfg.causal)
+    local = functools.partial(flash_attention, causal=cfg.causal,
+                              window=window)
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def dot_product_attention(q, k, v, causal=True, mask=None):
+def dot_product_attention(q, k, v, causal=True, mask=None, window=None):
     """Standard attention with f32 softmax accumulation.
 
     [B, S, H, D] inputs; einsum layouts chosen so the two matmuls land on
     the MXU as [S, D] x [D, S] and [S, S] x [S, D] per (batch, head).
     `mask` is an optional [B, S_k] key-validity mask (True = attend),
-    BERT-style padding.
+    BERT-style padding.  `window`: query i sees key j only if i - j <
+    window (with `causal`, the last `window` keys up to itself).
     """
     head_dim = q.shape[-1]
     scale = 1.0 / jnp.sqrt(head_dim).astype(jnp.float32)
@@ -779,6 +881,10 @@ def dot_product_attention(q, k, v, causal=True, mask=None):
         S_q, S_k = q.shape[1], k.shape[1]
         cmask = jnp.tril(jnp.ones((S_q, S_k), dtype=bool))
         logits = jnp.where(cmask[None, None], logits, -1e30)
+    if window is not None:
+        near = (jnp.arange(q.shape[1])[:, None]
+                - jnp.arange(k.shape[1])[None, :]) < window
+        logits = jnp.where(near[None, None], logits, -1e30)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -829,15 +935,130 @@ class DenseMLP(nn.Module):
                           name="wo", dtype=dtype, impl=impl)(h)
 
 
+_ROW_CHUNK = 8192     # rows a pass of `_take_first`'s loop moves
+
+
+def _take_first(src, idx, n):
+    """`src[idx]` [M, D], of which only the first `n` rows are wanted.
+    Where M is whole chunks the rows are moved a chunk at a time in a loop
+    of `ceil(n / chunk)` passes and the chunks after them stay zero, so a
+    row buffer sized for the worst routing costs what the routing fills
+    (not differentiable: used inside custom forward and backward rules)."""
+    m = idx.shape[0]
+    if m % _ROW_CHUNK:
+        return jnp.take(src, idx, axis=0)
+
+    def move(i, buf):
+        ids = jax.lax.dynamic_slice(idx, (i * _ROW_CHUNK,), (_ROW_CHUNK,))
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.take(src, ids, axis=0), (i * _ROW_CHUNK, 0))
+
+    return jax.lax.fori_loop(
+        0, (n + _ROW_CHUNK - 1) // _ROW_CHUNK, move,
+        jnp.zeros((m, src.shape[1]), src.dtype))
+
+
+@jax.custom_vjp
+def _moe_dispatch(xt, order, pos, local):
+    """`xt[order // k]` for the held picks: the token of every (token,
+    pick) pair, rows sorted by expert.  Its backward is a gather too (each
+    token adds up the rows of its own held picks), where XLA's would be a
+    scatter-add."""
+    return _take_first(xt, order // pos.shape[1], jnp.sum(local))
+
+
+def _moe_dispatch_fwd(xt, order, pos, local):
+    return _moe_dispatch(xt, order, pos, local), (pos, local)
+
+
+def _moe_dispatch_bwd(res, g):
+    pos, local = res
+    # rows past the last group were never computed: select, not multiply
+    rows = jnp.where(local[..., None], jnp.take(g, pos, axis=0), 0)
+    return (jnp.sum(rows.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None, None)
+
+
+_moe_dispatch.defvjp(_moe_dispatch_fwd, _moe_dispatch_bwd)
+
+
+@jax.custom_vjp
+def _moe_combine(out, w, order, pos, local):
+    """`sum_j w[t, j] * out[pos[t, j]]` over a token's held picks, summed
+    in float32: [T, D] in `out`'s type.  Backward by gathers, as
+    `_moe_dispatch`."""
+    rows = jnp.where(local[..., None], jnp.take(out, pos, axis=0), 0)
+    return jnp.einsum("tkd,tk->td", rows.astype(jnp.float32),
+                      w).astype(out.dtype)
+
+
+def _moe_combine_fwd(out, w, order, pos, local):
+    return _moe_combine(out, w, order, pos, local), (out, w, order, pos,
+                                                     local)
+
+
+def _moe_combine_bwd(res, g):
+    out, w, order, pos, local = res
+    # in row order: the token's cotangent beside each of its held rows
+    g_row = _take_first(g, order // pos.shape[1], jnp.sum(local))
+    dw_row = jnp.einsum("rd,rd->r", out.astype(jnp.float32),
+                        g_row.astype(jnp.float32))
+    dw = jnp.where(local, jnp.take(dw_row, pos), 0)
+    w_row = jnp.take(jnp.where(local, w, 0).reshape(-1), order)    # [T*k]
+    return (g_row * w_row[:, None]).astype(out.dtype), dw, None, None, None
+
+
+_moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
+
+
+MOE_COUNTERS = ("moe.pairs.local", "moe.pairs.absent", "moe.load.max",
+                "moe.load.mean")
+
+
+def moe_stats(intermediates):
+    """`{counter: scalar}` of one step, from what its dropless MoE layers
+    sowed (`moe_stats`, under `mutable=["intermediates"]`): `moe.pairs.local`
+    and `moe.pairs.absent` (picks that fell on held and on absent experts),
+    `moe.load.max` and `moe.load.mean` (tokens of the fullest held expert
+    and of the mean one), each summed over the layers.  A loss function
+    returns them as its aux metrics and names them in its `counters`
+    (`MOE_COUNTERS`): `parallel.train.make_train_step` then adds each step's
+    to `trace.counters()` with no sync, and once a step (what the
+    rematerialised blocks sow a second time is never read).  Nothing sowed
+    (no such layer): `{}`."""
+    stats = [leaf for path, leaf in
+             jax.tree_util.tree_leaves_with_path(intermediates)
+             if any(getattr(p, "key", None) == "moe_stats" for p in path)]
+    if not stats:
+        return {}
+    return dict(zip(MOE_COUNTERS, jnp.sum(jnp.stack(stats), axis=0)))
+
+
+class _ExpertKernel(nn.Module):
+    """One stacked expert weight as a module of its own, so that its leaf
+    is `<name>/kernel` in the tree and not a name with a slash in it (the
+    dense and topk routers keep their older flat names, which `convert.py`
+    and exported checkpoints carry); both spell the same path for the
+    sharding rules."""
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape)
+
+
 class MoEMLP(nn.Module):
     """Mixture-of-experts MLP (Switch/GShard-style).
 
     Expert weights carry a leading [num_experts] dim that the sharding rules
-    place on the ep axis.  Two routers, both static-shape and sort-free:
-    `dense` sends every token through every expert slot and masks (exact,
-    the numerics reference); `topk` is the production path — GShard
-    capacity dispatch where each expert computes a fixed C slots and
-    overflow tokens fall back to the residual stream.
+    place on the ep axis.  Three routers, all static-shape: `dense` sends
+    every token through every expert slot and masks (exact, the numerics
+    reference); `topk` is GShard capacity dispatch where each expert
+    computes a fixed C slots and overflow tokens fall back to the residual
+    stream; `dropless` sorts the (token, pick) pairs by expert and runs
+    them through `ops.grouped_matmul`: no capacity, no token dropped, and
+    with `moe_experts_held` the chip's share of an expert-parallel layer.
     """
     cfg: TransformerConfig
 
@@ -847,24 +1068,39 @@ class MoEMLP(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         B, S, D = x.shape
         E = cfg.num_experts
-        if cfg.moe_router not in ("dense", "topk"):
-            raise ValueError(
-                f"moe_router={cfg.moe_router!r} not in ('dense', 'topk')")
+        F = cfg.moe_d_ff or cfg.d_ff
+        if cfg.moe_router not in ("dense", "topk", "dropless"):
+            raise ValueError(f"moe_router={cfg.moe_router!r} not in "
+                             "('dense', 'topk', 'dropless')")
+        dropless = cfg.moe_router == "dropless"
+        if cfg.moe_experts_held is not None and not dropless:
+            raise ValueError("moe_experts_held (a share of the experts) "
+                             "needs moe_router='dropless'")
         gate_logits = QuantDense(E, use_bias=False, name="router",
                                  impl=cfg.quant_matmul_impl)(
             x.astype(jnp.float32))
         probs = jax.nn.softmax(gate_logits, axis=-1)
 
-        wi = self.param("experts_wi/kernel", nn.initializers.lecun_normal(),
-                        (E, D, cfg.d_ff)).astype(dtype)
-        wo = self.param("experts_wo/kernel", nn.initializers.lecun_normal(),
-                        (E, cfg.d_ff, D)).astype(dtype)
+        def experts(name, shape):
+            if dropless:
+                return _ExpertKernel(shape, name=name)().astype(dtype)
+            return self.param(f"{name}/kernel",
+                              nn.initializers.lecun_normal(),
+                              shape).astype(dtype)
+
+        held = E if cfg.moe_experts_held is None else cfg.moe_experts_held
+        wi = experts("experts_wi", (held, D, F))
+        wo = experts("experts_wo", (held, F, D))
         # gated experts (Mixtral-shape): wi routes through the activation,
         # experts_up is the linear branch; both shard like experts_wi
         # (the sharding rule matches the experts_(wi|up) prefix)
-        up = (self.param("experts_up/kernel", nn.initializers.lecun_normal(),
-                         (E, D, cfg.d_ff)).astype(dtype)
+        up = (experts("experts_up", (held, D, F))
               if cfg.mlp_style == "gated" else None)
+
+        if dropless:
+            # no balancing loss is sown here: the counters say how the
+            # load fell (`moe_stats`)
+            return self._dropless_route(x, probs, wi, up, wo)
 
         def expert_mlp(xe):
             """xe: [E, ..., D] -> [E, ..., D], batched over the expert dim."""
@@ -889,6 +1125,56 @@ class MoEMLP(nn.Module):
         aux = E * jnp.sum(frac_tokens * frac_probs)
         self.sow("intermediates", "moe_aux_loss", aux)
         return y
+
+    def _dropless_route(self, x, probs, wi, up, wo):
+        """Top-k routing over all `num_experts`, computed for the experts
+        held here.  Of a token's k picks those that fall on held experts
+        are sorted by expert, run through the grouped matmul, weighted
+        and summed back per token; the static row buffer is sized for the
+        worst case (every pick held), and the kernel does no work for the
+        rows past the last group, so no routing drops a token.  What the
+        absent experts would have added is left out: with all experts
+        held this is the whole layer."""
+        from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
+
+        cfg = self.cfg
+        B, S, D = x.shape
+        E, k = cfg.num_experts, cfg.moe_top_k
+        held, off = wi.shape[0], cfg.moe_expert_offset
+        if not 1 <= k <= E:
+            raise ValueError(f"moe_top_k={k} must be in [1, {E}]")
+        if not 0 <= off <= E - held:
+            raise ValueError(f"experts [{off}, {off + held}) are not among "
+                             f"the {E} the router scores")
+        T = B * S
+        xt = x.reshape(T, D).astype(jnp.dtype(cfg.dtype))
+        topk_p, topk_idx = jax.lax.top_k(probs.reshape(T, E), k)   # f32
+        if k > 1:      # weights renormalised over the picks, as `topk` does
+            topk_p = topk_p / jnp.maximum(
+                jnp.sum(topk_p, axis=-1, keepdims=True), 1e-9)
+        local = (topk_idx >= off) & (topk_idx < off + held)        # [T, k]
+        # rows sorted by held expert, the absent picks behind them all
+        key = jnp.where(local, topk_idx - off, held).reshape(T * k)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row->pair
+        pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32),
+            unique_indices=True).reshape(T, k)                    # pair->row
+        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)                           # [held]
+        xs = _moe_dispatch(xt, order, pos, local)                  # [T*k, D]
+        h = _activation(grouped_matmul(xs, wi, sizes), cfg.activation)
+        if up is not None:
+            h = h * grouped_matmul(xs, up, sizes)
+        out = grouped_matmul(h, wo, sizes)                         # [T*k, D]
+        y = _moe_combine(out, topk_p, order, pos, local)
+        n_local = jnp.sum(sizes)
+        self.sow("intermediates", "moe_stats", jnp.stack([
+            n_local, T * k - n_local, jnp.max(sizes),
+            n_local / held]).astype(jnp.float32))      # as MOE_COUNTERS
+        # the choices themselves, for a look at how rounding moves them
+        # (`benchmark/tests/moe_routes.py`); unread, they cost nothing
+        self.sow("intermediates", "moe_picks", topk_idx)
+        return y.reshape(B, S, D)
 
     def _topk_route(self, x, probs, expert_mlp):
         """GShard-style capacity dispatch: each token picks its top-k
@@ -1037,6 +1323,7 @@ class Block(nn.Module):
     BERT checkpoints — see convert.from_hf_bert)."""
     cfg: TransformerConfig
     use_moe: bool = False
+    layer_type: str = FULL
 
     @nn.compact
     def __call__(self, x, mask=None):
@@ -1046,7 +1333,7 @@ class Block(nn.Module):
                 f"norm_style={cfg.norm_style!r} not in ('pre', 'post')")
         ln1 = _make_ln(cfg, "ln1")
         ln2 = _make_ln(cfg, "ln2")
-        attn = Attention(cfg, name="attn")
+        attn = Attention(cfg, self.layer_type, name="attn")
         mlp = (MoEMLP(cfg, name="moe") if self.use_moe
                else DenseMLP(cfg, name="mlp"))
         x = _sp_constrain(x, cfg)
@@ -1102,7 +1389,9 @@ class Transformer(nn.Module):
             # every layer (k=2 keeps the old odd-layer placement)
             use_moe = cfg.num_experts > 0 and (
                 i % cfg.moe_every == cfg.moe_every - 1)
-            x = block_cls(cfg, use_moe=use_moe, name=f"layer_{i}")(x)
+            kind = cfg.layer_types[i] if cfg.layer_types else FULL
+            x = block_cls(cfg, use_moe=use_moe, layer_type=kind,
+                          name=f"layer_{i}")(x)
         x = _make_ln(cfg, "ln_f")(x)
         if return_hidden and not self.is_initializing():
             return x.astype(dtype)

@@ -14,6 +14,11 @@ interpret-mode path so the same kernels are testable on the CPU mesh.
 - adamw_fused / lion_fused : single-pass optimizer updates — read
   grad/param/moments once, write param/moments once, clip scale inlined
   (see ops/fused_optim.py; surfaced via optim.make_optimizer)
+- grouped_matmul : rows sorted by expert times the experts a chip holds
+  (`[M, K] x [G, K, N]`, group sizes from the routing) — forward and both
+  gradients, no work for rows past the last group (see
+  ops/grouped_matmul.py; the dropless `MoEMLP` path,
+  TransformerConfig.moe_router)
 - paged_attention : flash-decode over the paged serving kv pool — page
   table scalar-prefetched, only occupied pages read (in place, no
   logical-view gather), online softmax + split-K LSE combine, int8
@@ -33,6 +38,7 @@ interpret-mode path so the same kernels are testable on the CPU mesh.
 """
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 from tensorflowonspark_tpu.ops.fused_optim import adamw_fused, lion_fused
+from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
 from tensorflowonspark_tpu.ops.layernorm import fused_layernorm
 from tensorflowonspark_tpu.ops.paged_attention import paged_attention
 from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
@@ -40,7 +46,7 @@ from tensorflowonspark_tpu.ops.quant_matmul import quant_matmul
 from tensorflowonspark_tpu.ops.xent import fused_unembed_xent
 
 __all__ = ["flash_attention", "fused_layernorm", "fused_unembed_xent",
-           "adamw_fused", "lion_fused", "paged_attention",
+           "adamw_fused", "lion_fused", "grouped_matmul", "paged_attention",
            "paged_prefill", "quant_matmul"]
 
 

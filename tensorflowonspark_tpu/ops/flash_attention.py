@@ -10,7 +10,10 @@ lse) — flash-style recompute, residuals O(B·S·H·D) — in two kernels: one
 accumulating dq over streamed K/V blocks, one accumulating dk/dv over
 streamed Q/dO blocks.  All matmuls run on the MXU with f32 accumulation.
 Causal q/k block pairs with no overlap are skipped entirely (`pl.when`),
-halving the work for causal LMs.
+halving the work for causal LMs.  With a `window` (query i sees key j only
+if i - j < window) the blocks wholly left of it are skipped too and never
+fetched: the block index maps clamp to the window's own blocks, and a block
+index that does not change starts no copy.
 
 Composes with ring attention (parallel/ring_attention.py): ring handles the
 cross-device sequence axis, this kernel the on-device blocks.
@@ -32,9 +35,9 @@ def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
 
-def _block_mask(qi, ki, block_q, block_k, seq_len, causal):
+def _block_mask(qi, ki, block_q, block_k, seq_len, causal, window=None):
     """[bq, bk] validity mask for one (q-block, k-block) tile: real rows,
-    real keys, and the causal triangle."""
+    real keys, the causal triangle and the window."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -42,11 +45,49 @@ def _block_mask(qi, ki, block_q, block_k, seq_len, causal):
     mask = jnp.logical_and(q_pos < seq_len, k_pos < seq_len)
     if causal:
         mask = jnp.logical_and(mask, q_pos >= k_pos)
+    if window is not None:
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
     return mask
 
 
+def _when_visible(qi, ki, block_q, block_k, causal, window, block):
+    """Run `block` unless the (q-block, k-block) tile is empty: strictly
+    above the diagonal, or wholly left of the window."""
+    conds = []
+    if causal:
+        conds.append(qi * block_q + block_q - 1 >= ki * block_k)
+    if window is not None:
+        conds.append(qi * block_q - (ki * block_k + block_k - 1) < window)
+    if not conds:
+        return block()
+    pl.when(functools.reduce(jnp.logical_and, conds))(block)
+
+
+def _k_blocks_of(i, block_q, block_k, causal, window, nk):
+    """Clamp a key-block index to the blocks query block `i` can see."""
+    def clamp(j):
+        if window is None:
+            return j
+        lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
+        hi = (i * block_q + block_q - 1) // block_k if causal else nk - 1
+        return jnp.clip(j, lo, jnp.minimum(hi, nk - 1))
+    return clamp
+
+
+def _q_blocks_of(j, block_q, block_k, causal, window, nq):
+    """Clamp a query-block index to the blocks that can see key block `j`."""
+    def clamp(i):
+        if window is None:
+            return i
+        lo = (j * block_k) // block_q if causal else 0
+        hi = (j * block_k + block_k - 1 + window - 1) // block_q
+        return jnp.clip(i, lo, jnp.minimum(hi, nq - 1))
+    return clamp
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                sm_scale, causal, block_q, block_k, seq_len, need_lse):
+                sm_scale, causal, block_q, block_k, seq_len, need_lse,
+                window=None):
     if need_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -69,8 +110,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal),
-                      s, NEG_INF)
+        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
+                                  window), s, NEG_INF)
 
         m_prev = m_scr[:, :1]                         # [bq, 1]
         l_prev = l_scr[:, :1]
@@ -85,13 +126,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
-        # skip blocks strictly above the diagonal
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _block()
-    else:
-        _block()
+    # skip blocks strictly above the diagonal or left of the window
+    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -106,7 +142,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k, seq_len):
+                   dq_scr, *, sm_scale, causal, block_q, block_k, seq_len,
+                   window=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -124,8 +161,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
         dlt = dlt_ref[0, 0][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal),
-                      s, NEG_INF)
+        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
+                                  window), s, NEG_INF)
         p = jnp.exp(s - lse)                          # [bq, bk]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -134,12 +171,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _block()
-    else:
-        _block()
+    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -148,7 +180,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    sm_scale, causal, block_q, block_k, seq_len):
+                    sm_scale, causal, block_q, block_k, seq_len, window=None):
     # grid (B, H_kv, nk, group, nq): dk/dv accumulate across the GQA
     # group's q heads AND the q blocks before one narrow write — the
     # output block index is constant over both inner dims, so pallas
@@ -173,8 +205,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
         dlt = dlt_ref[0, 0][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal),
-                      s, NEG_INF)
+        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
+                                  window), s, NEG_INF)
         p = jnp.exp(s - lse)                          # [bq, bk]
         # dv += p^T @ dO
         dv_scr[:] += jax.lax.dot_general(
@@ -188,12 +220,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _block()
-    else:
-        _block()
+    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
 
     @pl.when(jnp.logical_and(g == ng - 1, qi == nq - 1))
     def _finish():
@@ -213,7 +240,7 @@ _LANES = 128  # lse/delta carry a lane-replicated trailing dim for layout
 
 
 def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                    need_lse):
+                    need_lse, window=None):
     """Returns (out [B,S,H,D], lse [B,H,Sq_padded,LANES] or None).
 
     `need_lse=False` (the primal/serving path) omits the lse output
@@ -229,14 +256,18 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_len=S, need_lse=need_lse)
+        block_q=block_q, block_k=block_k, seq_len=S, need_lse=need_lse,
+        **({} if window is None else {"window": window}))
     o_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     lse_spec = pl.BlockSpec((1, 1, block_q, _LANES),
                             lambda b, h, i, j: (b, h, i, 0))
     # narrow kv blocks are indexed by the q head's GROUP — no repeated
     # kv ever materializes in HBM (the GQA bandwidth win, kept here)
-    kv_spec = pl.BlockSpec((1, 1, block_k, D),
-                           lambda b, h, i, j: (b, h // group, j, 0))
+    def kv_index(b, h, i, j):
+        seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
+        return (b, h // group, seen(j), 0)
+
+    kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_index)
     result = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
@@ -262,7 +293,7 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
 
 
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
-                    interpret, g_lse=None):
+                    interpret, g_lse=None, window=None):
     B, S, H, D = q.shape
     group = H // k.shape[2]   # GQA: q heads per (narrow) kv head
     H_kv = k.shape[2]
@@ -283,15 +314,21 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, Sq - S)))
     delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
 
+    windowed = {} if window is None else {"window": window}
+
+    def k_index(b, h, i, j):
+        seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
+        return (b, h // group, seen(j), 0)
+
     q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, D),
-                          lambda b, h, i, j: (b, h // group, j, 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, D), k_index)
     r_spec = pl.BlockSpec((1, 1, block_q, _LANES),
                           lambda b, h, i, j: (b, h, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S),
+                          block_q=block_q, block_k=block_k, seq_len=S,
+                          **windowed),
         grid=(B, H, nq, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
@@ -304,15 +341,18 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     # swap grid roles: (b, kv-head, k-block, group-member, q-block) —
     # q innermost; dk/dv come out NARROW, accumulated across the group
     # (the narrow output replaces the former repeat-then-sum cotangent)
-    qk_spec = pl.BlockSpec((1, 1, block_q, D),
-                           lambda b, kh, j, g, i: (b, kh * group + g, i, 0))
+    def q_index(b, kh, j, g, i):
+        seeing = _q_blocks_of(j, block_q, block_k, causal, window, nq)
+        return (b, kh * group + g, seeing(i), 0)
+
+    qk_spec = pl.BlockSpec((1, 1, block_q, D), q_index)
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
                            lambda b, kh, j, g, i: (b, kh, j, 0))
-    rk_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                           lambda b, kh, j, g, i: (b, kh * group + g, i, 0))
+    rk_spec = pl.BlockSpec((1, 1, block_q, _LANES), q_index)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S),
+                          block_q=block_q, block_k=block_k, seq_len=S,
+                          **windowed),
         grid=(B, H_kv, nk, group, nq),
         in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
         out_specs=[kk_spec, kk_spec],
@@ -327,7 +367,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     return tr(dq, S), tr(dk, S), tr(dv, S)
 
 
-def attention_reference(q, k, v, causal=True, sm_scale=None):
+def attention_reference(q, k, v, causal=True, sm_scale=None, window=None):
     """Dense reference with semantics identical to the kernel (f32 softmax,
     large-finite mask).  Used for tests and as the dense fallback.
     Accepts narrow (GQA) k/v like the kernel does — repeated here."""
@@ -343,27 +383,33 @@ def attention_reference(q, k, v, causal=True, sm_scale=None):
         Sq, Sk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((Sq, Sk), dtype=bool))
         s = jnp.where(mask[None, None], s, NEG_INF)
+    if window is not None:
+        near = (jnp.arange(q.shape[1])[:, None]
+                - jnp.arange(k.shape[1])[None, :]) < window
+        s = jnp.where(near[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     out, _ = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                             interpret, need_lse=False)
+                             interpret, need_lse=False, window=window)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window):
     out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                               interpret, need_lse=True)
+                               interpret, need_lse=True, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+                   res, g):
     q, k, v, out, lse = res
     return _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale,
-                           block_q, block_k, interpret)
+                           block_q, block_k, interpret, window=window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -445,8 +491,12 @@ def flash_attention_with_lse(q, k, v, causal=True, sm_scale=None,
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None,
-                    block_q=1024, block_k=1024, interpret=None):
+                    block_q=1024, block_k=1024, interpret=None, window=None):
     """Flash attention over [B, S, H, D] q and [B, S, H_kv, D] k/v.
+
+    `window` (static): query i sees key j only if i - j < window; key
+    blocks wholly left of it are neither fetched nor computed, so a window
+    layer's work grows with S x window and not with S x S.
 
     GQA-native: ``H_kv`` may be any divisor of ``H`` — narrow k/v blocks
     are indexed per q-head group inside the kernel, so the repeated k/v
@@ -458,4 +508,7 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
     """
     sm_scale, block_q, block_k, interpret = _resolve_call_args(
         q, k, sm_scale, block_q, block_k, interpret)
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    if window is not None and window < 1:
+        raise ValueError(f"window={window} must be at least 1")
+    return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                  None if window is None else int(window))

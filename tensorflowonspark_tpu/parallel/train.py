@@ -66,6 +66,13 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
     Explicit on purpose: the aligned payload's shape coincides with the
     row-major one in the common case, so it cannot be detected.
 
+    `loss_fn` may also return `(loss, aux)`, `aux` a dict of scalars that
+    add up over microbatches (counts): they join `metrics` under their own
+    names.  Where `loss_fn.counters` names some of them, the step object
+    hands each step's values to `trace.count_when_ready`, which adds them
+    to the process's counters once the device has them: no host callback
+    inside the step, no sync in the loop (the `moe.*` routing counters).
+
     Returns `train_step(state, batch, rng) -> (state, metrics)`.
     """
     import jax
@@ -77,7 +84,12 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
                 lambda x: x.astype(compute_dtype)
                 if hasattr(x, "astype") and jnp.issubdtype(x.dtype, jnp.floating)
                 else x, params)
-        return loss_fn(params, batch, rng)
+        out = loss_fn(params, batch, rng)
+        return out if isinstance(out, tuple) else (out, {})
+
+    def _counted(step):
+        names = tuple(getattr(loss_fn, "counters", ()))
+        return _CountedStep(step, names) if names else step
 
     def _step(state, batch, rng):
         if grad_accum > 1:
@@ -86,17 +98,20 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
                                     + x.shape[1:]), batch)
 
             def body(carry, mb):
-                loss, g = jax.value_and_grad(_loss)(state.params, mb, rng)
+                (loss, aux), g = jax.value_and_grad(_loss, has_aux=True)(
+                    state.params, mb, rng)
                 acc_loss, acc_g = carry
                 return (acc_loss + loss,
-                        jax.tree_util.tree_map(jnp.add, acc_g, g)), None
+                        jax.tree_util.tree_map(jnp.add, acc_g, g)), aux
 
             zeros = jax.tree_util.tree_map(jnp.zeros_like, state.params)
-            (loss, grads), _ = jax.lax.scan(body, (0.0, zeros), micro)
+            (loss, grads), aux = jax.lax.scan(body, (0.0, zeros), micro)
             loss = loss / grad_accum
             grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads)
+            aux = jax.tree_util.tree_map(lambda x: x.sum(0), aux)
         else:
-            loss, grads = jax.value_and_grad(_loss)(state.params, batch, rng)
+            (loss, aux), grads = jax.value_and_grad(_loss, has_aux=True)(
+                state.params, batch, rng)
 
         import optax
         fused_apply = getattr(optimizer, "apply", None)
@@ -117,7 +132,7 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
         # the fused path computes this same reduction for its clip scale;
         # XLA CSEs the two, so the metric stays free there
         metrics = {"loss": loss,
-                   "grad_norm": optax.global_norm(grads)}
+                   "grad_norm": optax.global_norm(grads), **aux}
         return new_state, metrics
 
     fused_param_sh = None   # NamedSharding per param leaf, over a mesh
@@ -127,7 +142,7 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
         leaves = jax.tree_util.tree_leaves(param_shardings)
         mesh = next((s.mesh for s in leaves if hasattr(s, "mesh")), None)
     if mesh is None:
-        return jax.jit(_step, donate_argnums=(0,) if donate else ())
+        return _counted(jax.jit(_step, donate_argnums=(0,) if donate else ()))
 
     from jax.sharding import NamedSharding, PartitionSpec
     repl = NamedSharding(mesh, PartitionSpec())
@@ -164,7 +179,7 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
             # AOT like the plain-jit returns of this function
             step.lower = lambda state, batch, rng: _jitted(state).lower(
                 state, batch, rng)
-            return step
+            return _counted(step)
         state_shardings = None  # let jit infer from input placement
         in_shardings = (None, batch_shard, repl)
         out_shardings = (None, repl)
@@ -177,9 +192,36 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
         in_shardings = (state_shardings, batch_shard, repl)
         out_shardings = (state_shardings, repl)
 
-    return jax.jit(_step, in_shardings=in_shardings,
-                   out_shardings=out_shardings,
-                   donate_argnums=(0,) if donate else ())
+    return _counted(jax.jit(_step, in_shardings=in_shardings,
+                            out_shardings=out_shardings,
+                            donate_argnums=(0,) if donate else ()))
+
+
+class _CountedStep:
+    """A step (jitted, lowered or compiled) of a loss function that names
+    `counters` among its aux metrics: calls and everything else go through
+    to the step itself; after a call the named metrics of that step are
+    handed to `trace.count_when_ready`; `lower(...)` and `compile()` give
+    the same again, so an ahead-of-time compiled step counts too."""
+
+    def __init__(self, step, names):
+        self._step, self._names = step, names
+
+    def __call__(self, *args, **kwargs):
+        from tensorflowonspark_tpu import trace
+
+        out = self._step(*args, **kwargs)
+        trace.count_when_ready({k: out[1][k] for k in self._names})
+        return out
+
+    def lower(self, *args, **kwargs):
+        return _CountedStep(self._step.lower(*args, **kwargs), self._names)
+
+    def compile(self, *args, **kwargs):
+        return _CountedStep(self._step.compile(*args, **kwargs), self._names)
+
+    def __getattr__(self, name):
+        return getattr(self._step, name)
 
 
 def _opt_state_shardings(optimizer, param_shardings, repl,

@@ -64,6 +64,13 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     of ring, queue, queue_oversize (a record larger than the ring);
     feed.ring_fallbacks  feed.chunk_splits (packed chunks cut by bytes
     to fit ring payloads)
+    counters of a node that trains dropless expert layers
+    (``models.transformer.moe_stats``, scalars in the step's metrics that
+    ``parallel.train`` hands to :func:`count_when_ready`: no host callback
+    in the step, no sync in the loop): moe.pairs.local  moe.pairs.absent
+    (a token's picks that fell on experts held here, and on absent ones)
+    moe.load.max  moe.load.mean (tokens of the fullest held expert and of
+    the mean one; all four summed over layers and steps)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`
@@ -273,6 +280,8 @@ class _Process(Recorder):
     def __init__(self):
         super().__init__(capacity=PROCESS_RING)
         self.counters = metrics.Counters()
+        # `count_when_ready`: values the device has not finished yet
+        self._pending = collections.deque()
         self._ids = itertools.count(1)
         self._reports = collections.OrderedDict()
         # `recorded` at the last report that reached the driver: the next
@@ -284,6 +293,16 @@ class _Process(Recorder):
         self._push({"id": next(self._ids) if span_id is None else span_id,
                     "cause": getattr(cause, "id", cause), "name": name,
                     "t0_ms": t0_ms, "t1_ms": t1_ms, "attrs": attrs})
+
+    def drain(self, block):
+        """Count what `count_when_ready` holds, oldest first: everything
+        (`block`, waiting for the device) or as far as it is ready."""
+        with self._lock:
+            while self._pending and (block or all(
+                    v.is_ready() for v in self._pending[0].values())):
+                for name, v in self._pending.popleft().items():
+                    n = float(v)
+                    self.counters.inc(name, int(n) if n == int(n) else n)
 
     def add_report(self, report):
         """Keep a report of another process.  A later report of the same
@@ -392,6 +411,18 @@ class span:
         return False
 
 
+def count_when_ready(values):
+    """Add device scalars (``{counter: a jax.Array of one element}``, the
+    outputs of a step that was just dispatched) to :func:`counters` once
+    the device has them, without waiting: they are kept, and counted in
+    order by the next call that finds them ready, or by :func:`report`
+    (which waits: a report holds every step dispatched before it)."""
+    rec = process()
+    with rec._lock:
+        rec._pending.append(values)
+    rec.drain(block=False)
+
+
 def span_ended(name, seconds, cause=None, **attrs):
     """Record, on the process recorder, a span that ends now and took
     `seconds`: for a duration something else measured and only tells
@@ -406,6 +437,7 @@ def report(source=None, since=0):
     with `since`, only the spans recorded after the first `since` (the
     counters always count from the process's start)."""
     rec = process()
+    rec.drain(block=True)
     out = rec.export(since)
     out["source"] = source or f"pid:{os.getpid()}"
     out["counters"] = rec.counters.snapshot()

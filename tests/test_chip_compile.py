@@ -72,7 +72,8 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "adamw_fused")
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "adamw_fused", "moe_tgmm",
+           "moe_gmm")
 
 
 def _kernels(text):
@@ -111,6 +112,44 @@ def test_flash_backward_lowers(chip):
     # forward + dq + dk/dv kernels
     assert text.count("tpu_custom_call") >= 3
     assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
+def test_flash_with_a_window_lowers(chip):
+    """A sliding layer of the sparse-expert cell: 8192 tokens, 32 query
+    heads on 4 key/value heads of 128, a window of 1024 (the clamped block
+    index maps are what the interpreter cannot judge)."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=1024,
+                              interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = (chip((1, 8192, 32, 128), jnp.bfloat16),
+           chip((1, 8192, 4, 128), jnp.bfloat16),
+           chip((1, 8192, 4, 128), jnp.bfloat16))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
+def test_grouped_matmul_lowers(chip):
+    """The expert products of the same cell, forward and both gradients:
+    rows for the worst routing (2 x 8192 tokens x 8 picks), 16 held experts
+    of 2304 x 896 and back."""
+    from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
+
+    def loss(rows, w_in, w_out, sizes):
+        h = grouped_matmul(rows, w_in, sizes, interpret=False)
+        out = grouped_matmul(h, w_out, sizes, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    chip((131072, 2304), jnp.bfloat16),
+                    chip((16, 2304, 896), jnp.bfloat16),
+                    chip((16, 896, 2304), jnp.bfloat16),
+                    chip((16,), jnp.int32))
+    # the first product, the rows' gradient twice, the weights' twice (the
+    # sum's own forward is dead code)
+    assert text.count("tpu_custom_call") >= 5
+    assert _kernels(text) == {"moe_gmm", "moe_tgmm"}
 
 
 def test_adamw_fused_apply_lowers(chip):

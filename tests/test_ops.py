@@ -278,3 +278,58 @@ def test_flash_block_pick_avoids_padding():
     assert _pick_block(1024, 3000) == 1024   # no divisor: keep (2.4% pad)
     assert _pick_block(512, 64) == 64        # small sequences clamp
     assert _pick_block(16, 1536) == 16       # explicit small block honored
+
+
+# ---- a window (sliding-attention layers) -----------------------------------
+
+@pytest.mark.parametrize("S,window,blocks,group", [
+    (200, 50, (64, 64), 1),      # S and the window both off the block
+    (200, 64, (64, 32), 2),      # unequal blocks, GQA
+    (96, 1, (32, 32), 1),        # a window of the token itself
+    (96, 300, (32, 32), 4),      # a window wider than the row masks nothing
+])
+def test_flash_window_forward_and_backward_match_dense(S, window, blocks,
+                                                       group):
+    """Against the dense windowed path the model's CPU branch runs
+    (`dot_product_attention(window=)`): key blocks left of the window are
+    skipped and their index maps clamped, in all three kernels."""
+    from tensorflowonspark_tpu.models.transformer import (
+        dot_product_attention)
+    from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
+
+    q, k, v = _qkv(B=1, S=S)
+    k, v = k[:, :, ::group], v[:, :, ::group]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=blocks[0], block_k=blocks[1],
+                               interpret=True)
+
+    def dense(q, k, v):
+        kf, vf = _kv_repeat(q, k, v)
+        return dot_product_attention(q, kf, vf, causal=True, window=window)
+
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+    w = jax.random.normal(jax.random.key(9), q.shape)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def test_flash_window_skips_the_blocks_left_of_it():
+    """The clamps of the block index maps, by hand: query block 5 of 128
+    rows under a window of 200 sees keys 441..767, blocks 3..5; key block
+    2 is seen by queries 256..582, blocks 2..4."""
+    from tensorflowonspark_tpu.ops.flash_attention import (
+        _k_blocks_of, _q_blocks_of)
+
+    seen = _k_blocks_of(5, 128, 128, True, 200, 8)
+    assert [int(seen(j)) for j in range(8)] == [3, 3, 3, 3, 4, 5, 5, 5]
+    seeing = _q_blocks_of(2, 128, 128, True, 200, 8)
+    assert [int(seeing(i)) for i in range(8)] == [2, 2, 2, 3, 4, 4, 4, 4]
+    same = _k_blocks_of(5, 128, 128, True, None, 8)
+    assert [same(j) for j in range(8)] == list(range(8))
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(*_qkv(S=16), window=0)
