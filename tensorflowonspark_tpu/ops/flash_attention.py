@@ -9,11 +9,27 @@ logsumexp.  The backward recomputes probabilities blockwise from (q, k,
 lse) — flash-style recompute, residuals O(B·S·H·D) — in two kernels: one
 accumulating dq over streamed K/V blocks, one accumulating dk/dv over
 streamed Q/dO blocks.  All matmuls run on the MXU with f32 accumulation.
-Causal q/k block pairs with no overlap are skipped entirely (`pl.when`),
-halving the work for causal LMs.  With a `window` (query i sees key j only
-if i - j < window) the blocks wholly left of it are skipped too and never
-fetched: the block index maps clamp to the window's own blocks, and a block
-index that does not change starts no copy.
+
+A grid step keeps a large resident block (1024 rows by default: a grid
+step costs the same whatever it holds) and computes it in strips of
+sub-tiles of 128, a sub-tile only if the causal triangle, the `window`
+(query i sees key j only if i - j < window) and the padding leave a pair
+in it, with the mask arithmetic only on the sub-tiles a mask edge crosses.
+So the causal skip also fires where S is one block (S=1024: 36 of 64
+sub-tiles, 8 of them masked), and a window layer computes the sub-tiles
+of its band, not the two blocks that hold it.  The schedule follows from
+what a call can see (S, the blocks, causal, window) and is worked out when
+the kernel is traced: every strip has static bounds, blocks of the grid
+with the same schedule share one body, and a small table in scalar memory
+tells a grid step which body is its own.  `subtile_counts` is the same
+arithmetic as a count, and every traced call adds it to the process
+counters `flash.subtiles.{computed,masked,square}` (`trace.py`).  Where a
+row's keys all sit in one block the forward keeps no running state; the
+dk/dv kernel holds its scores keys-by-queries, so neither product that
+accumulates into dk or dv transposes a score tile.  Key blocks wholly
+outside the window are never fetched: the block index maps clamp to the
+window's own blocks, and a block index that does not change starts no
+copy.
 
 Composes with ring attention (parallel/ring_attention.py): ring handles the
 cross-device sequence axis, this kernel the on-device blocks.
@@ -25,23 +41,189 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tensorflowonspark_tpu import trace
+
 NEG_INF = -1e30  # large-finite: exp(NEG_INF - m) == 0 without inf-inf NaNs
+_LANES = 128  # lse/delta carry a lane-replicated trailing dim for layout
 
 
 def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
 
-def _block_mask(qi, ki, block_q, block_k, seq_len, causal, window=None):
-    """[bq, bk] validity mask for one (q-block, k-block) tile: real rows,
+# ---- the sub-tile schedule -------------------------------------------------
+#
+# A grid step keeps a large resident block (the step count does not grow)
+# and computes it in STRIPS of sub-tiles: a run of query sub-tiles against
+# the key sub-tiles they can see (dq and the forward; dk/dv take a run of
+# key sub-tiles against their queries).  Which sub-tiles hold a visible
+# (query, key) pair, and which of those a mask edge crosses, is interval
+# arithmetic on what the call can see, done in Python when the kernel is
+# traced: every strip has static bounds.  Blocks of the grid that share a
+# schedule share one unrolled body; a table in scalar memory says which
+# body a grid step takes.
+
+def _ceil_div(x, t, n):
+    """ceil(x / t) clipped into [0, n]."""
+    return min(max(-(-x // t), 0), n)
+
+
+def _floor_div1(x, t, n):
+    """floor(x / t) + 1 clipped into [0, n]."""
+    return min(max(x // t + 1, 0), n)
+
+
+def _span(a0, ta, a_len, b_base, tb, nb, b_len, lo_d, hi_d):
+    """Sub-tiles of a resident block along axis `b` that the sub-tile
+    `[a0, a0 + ta)` of axis `a` meets: `(lo, lo_m, hi_m, hi)`.
+
+    Sub-tile `i` covers `[b_base + i*tb, b_base + (i+1)*tb)`, `nb` of them;
+    positions at or past `a_len` / `b_len` are padding.  A pair is visible
+    if `lo_d <= a - b <= hi_d` (either bound may be None).  Sub-tiles
+    `lo..hi-1` hold a visible pair and are computed; of those,
+    `lo_m..hi_m-1` hold nothing else and need no mask."""
+    a_end = min(a0 + ta, a_len) - 1             # last real position of a
+    rel = b_len - b_base
+    hi, hi_m = _ceil_div(rel, tb, nb), _floor_div1(rel - tb, tb, nb)
+    lo = lo_m = 0
+    some, whole = a0 < a_len, a0 + ta <= a_len
+    if lo_d is not None:      # a - b >= lo_d: an upper end for b
+        hi = min(hi, _floor_div1(a_end - lo_d - b_base, tb, nb))
+        hi_m = min(hi_m, _floor_div1(a0 - lo_d - tb + 1 - b_base, tb, nb))
+    if hi_d is not None:      # a - b <= hi_d: a lower end for b
+        lo = _ceil_div(a0 - hi_d - tb + 1 - b_base, tb, nb)
+        lo_m = _ceil_div(a0 + ta - 1 - hi_d - b_base, tb, nb)
+        some = some and a0 - hi_d <= b_len - 1
+    hi = max(hi, lo) if some else lo
+    lo_m = min(max(lo_m, lo), hi)
+    hi_m = min(max(hi_m, lo_m), hi) if whole else lo_m
+    return lo, lo_m, hi_m, hi
+
+
+def _k_span(q0, k_base, sub, nc, seq_q, seq_k, causal, window):
+    """Key sub-tiles of a resident key block for query rows `[q0, q0+tq)`."""
+    return _span(q0, sub[0], seq_q, k_base, sub[1], nc, seq_k,
+                 0 if causal else None,
+                 None if window is None else window - 1)
+
+
+def _q_span(k0, q_base, sub, nr, seq_q, seq_k, causal, window):
+    """Query sub-tiles of a resident query block for keys `[k0, k0+tk)`:
+    the same condition read as a bound on `k - q`."""
+    return _span(k0, sub[1], seq_k, q_base, sub[0], nr, seq_q,
+                 None if window is None else 1 - window,
+                 0 if causal else None)
+
+
+def subtile_counts(seq_q, seq_k, block_q, block_k, sub, causal=True,
+                   window=None):
+    """`(computed, masked, square)`: how many sub-tiles one kernel call
+    computes for one head, how many of those carry the mask arithmetic,
+    and how many the padded square holds.  `sub` is `(tq, tk)`.  The
+    kernels' strips come from the same `_span`."""
+    nr, nc = block_q // sub[0], block_k // sub[1]
+    nq, nk = -(-seq_q // block_q), -(-seq_k // block_k)
+    computed = masked = 0
+    for row in range(nq * nr):
+        for kj in range(nk):
+            lo, lo_m, hi_m, hi = _k_span(row * sub[0], kj * block_k, sub, nc,
+                                         seq_q, seq_k, causal, window)
+            computed += hi - lo
+            masked += (hi - lo) - (hi_m - lo_m)
+    return computed, masked, nq * nr * nk * nc
+
+
+# Rows, and keys, a sub-tile.  Measured on the v5e at S=1024 (D=64, one
+# block a head) and S=8192 (D=128, GQA 8:1, with and without a window),
+# 128 against 256 and 512 in each kernel: 128 is quickest in all three at
+# both shapes (PERF.md section 6, PR 29).
+_SUBTILE = 128
+
+
+def _pick_subtile(block_q, block_k):
+    """`(tq, tk)` for a resident block: `_SUBTILE` where it divides the
+    block, the block itself where it does not (the small blocks of
+    tests, S under 128)."""
+    def one(block):
+        return _SUBTILE if block % _SUBTILE == 0 else block
+    return one(block_q), one(block_k)
+
+
+def _count_subtiles(seq_len, block_q, block_k, sub, causal, window):
+    computed, masked, square = subtile_counts(
+        seq_len, seq_len, block_q, block_k, sub, causal, window)
+    counters = trace.counters()
+    counters.inc("flash.subtiles.computed", computed)
+    counters.inc("flash.subtiles.masked", masked)
+    counters.inc("flash.subtiles.square", square)
+
+
+def _schedule(grid, blocks, sub, seq_len, causal, window, by_keys):
+    """`(kinds, patterns)` for a grid of `(nq, nk)` resident blocks:
+    `patterns[kinds[qi * nk + ki]]` is the tuple of strips block (qi, ki)
+    computes, `()` where it holds no visible pair.
+
+    A strip `(i0, i1, span)` is sub-tiles `i0..i1-1` of one axis (queries
+    if `by_keys`: the forward and dq walk a query strip's keys; else keys:
+    dk/dv walk a key strip's queries) against the sub-tiles `span` =
+    `(lo, lo_m, hi_m, hi)` of the other, as `_span` gives them.
+    Neighbours with the same span make one strip: an interior block is
+    one strip, the whole block."""
+    (nq, nk), (block_q, block_k) = grid, blocks
+    nr, nc = block_q // sub[0], block_k // sub[1]
+    kinds, patterns = [], []
+    for qi in range(nq):
+        for ki in range(nk):
+            if by_keys:
+                spans = [_k_span(qi * block_q + r * sub[0], ki * block_k,
+                                 sub, nc, seq_len, seq_len, causal, window)
+                         for r in range(nr)]
+            else:
+                spans = [_q_span(ki * block_k + c * sub[1], qi * block_q,
+                                 sub, nr, seq_len, seq_len, causal, window)
+                         for c in range(nc)]
+            strips = []
+            for i, span in enumerate(spans):
+                if span[3] == span[0]:
+                    continue
+                if strips and strips[-1][1:] == (i, span):
+                    strips[-1] = (strips[-1][0], i + 1, span)
+                else:
+                    strips.append((i, i + 1, span))
+            pattern = tuple(strips)
+            if pattern not in patterns:
+                patterns.append(pattern)
+            kinds.append(patterns.index(pattern))
+    return np.asarray(kinds, np.int32), patterns
+
+
+def _for_each_strip(kinds_ref, patterns, qi, ki, nk, strip):
+    """Run `strip(*s)` for the strips of block (qi, ki)'s pattern: under a
+    scalar branch a pattern where the grid holds several, plainly where
+    every block has the same."""
+    if len(patterns) == 1:
+        for s in patterns[0]:
+            strip(*s)
+        return
+    kind = kinds_ref[qi * nk + ki]
+    for pid, pattern in enumerate(patterns):
+        if pattern:
+            @pl.when(kind == pid)
+            def _(pattern=pattern):
+                for s in pattern:
+                    strip(*s)
+
+
+def _tile_mask(q0, k0, shape, q_axis, seq_len, causal, window):
+    """Validity mask of a tile of scores whose axis `q_axis` runs over
+    queries from `q0` and whose other axis over keys from `k0`: real rows,
     real keys, the causal triangle and the window."""
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
     mask = jnp.logical_and(q_pos < seq_len, k_pos < seq_len)
     if causal:
         mask = jnp.logical_and(mask, q_pos >= k_pos)
@@ -50,17 +232,47 @@ def _block_mask(qi, ki, block_q, block_k, seq_len, causal, window=None):
     return mask
 
 
-def _when_visible(qi, ki, block_q, block_k, causal, window, block):
-    """Run `block` unless the (q-block, k-block) tile is empty: strictly
-    above the diagonal, or wholly left of the window."""
-    conds = []
-    if causal:
-        conds.append(qi * block_q + block_q - 1 >= ki * block_k)
-    if window is not None:
-        conds.append(qi * block_q - (ki * block_k + block_k - 1) < window)
-    if not conds:
-        return block()
-    pl.when(functools.reduce(jnp.logical_and, conds))(block)
+def _strip_scores(a, b, sm_scale, q0, k0, q_axis, t, span, seq_len, causal,
+                  window):
+    """Scaled scores `[rows of a, rows of b]` of one strip on the MXU, f32
+    accumulation, with the mask arithmetic only on the runs of the strip a
+    mask edge crosses.  `a` holds the strip's own rows (queries if
+    `q_axis` is 0, keys if 1), `b` the other side's sub-tiles `lo..hi-1` of
+    `span` = `(lo, lo_m, hi_m, hi)`, `t` rows each: masked, plain, masked
+    runs along axis 1.  `q0`, `k0`: the strip's first query and key."""
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    lo, lo_m, hi_m, hi = span
+    if lo_m == lo and hi_m == hi:
+        return s
+    parts = []
+    for first, last, masked in ((lo, lo_m, True), (lo_m, hi_m, False),
+                                (hi_m, hi, True)):
+        if first == last:
+            continue
+        off = (first - lo) * t
+        part = s[:, off:(last - lo) * t]
+        if masked:
+            mask = _tile_mask(q0 + (off if q_axis else 0),
+                              k0 + (0 if q_axis else off), part.shape,
+                              q_axis, seq_len, causal, window)
+            part = jnp.where(mask, part, NEG_INF)
+        parts.append(part)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _grid_pos(axis, n):
+    """A block's index along a grid axis: 0, statically, where the axis
+    holds one block."""
+    return 0 if n == 1 else pl.program_id(axis)
+
+
+def _when(cond, body):
+    if isinstance(cond, bool):
+        if cond:
+            body()
+    else:
+        pl.when(cond)(body)
 
 
 def _k_blocks_of(i, block_q, block_k, causal, window, nk):
@@ -85,160 +297,203 @@ def _q_blocks_of(j, block_q, block_k, causal, window, nq):
     return clamp
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                sm_scale, causal, block_q, block_k, seq_len, need_lse,
+def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
+                sm_scale, causal, sub, grid, patterns, seq_len, need_lse,
                 window=None):
-    if need_lse:
-        lse_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        m_scr, l_scr, acc_scr = rest
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+    (nq, nk), (tq, tk) = grid, sub
+    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
+    lse_ref = rest[0] if need_lse else None
+    # where a row's keys all sit in one resident block the running state
+    # is never revisited, and there is none: a strip writes its rows' output
+    direct = nk == 1
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, m_scr.dtype)
-        l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
-        acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
-
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32)          # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)          # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        # [bq, bk] scores on the MXU, f32 accumulation
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
-                                  window), s, NEG_INF)
-
-        m_prev = m_scr[:, :1]                         # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                        # [bq, bk]
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    # skip blocks strictly above the diagonal or left of the window
-    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+    def _write(rows, acc, m, l):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
         if need_lse:
             # lse rows that saw no valid key (padding) get a finite sentinel
             # so the backward's exp(NEG_INF - lse) underflows to exactly 0
-            m = m_scr[:, :1]
             lse = jnp.where(m <= NEG_INF / 2, 0.0, m + jnp.log(l))
-            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref[0, 0].shape)
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(
+                lse, (acc.shape[0], _LANES))
+
+    if direct:
+        if seq_len < nq * block_q:
+            # padding rows no strip reaches still need defined values
+            _write(slice(None), jnp.zeros((block_q, q_ref.shape[3])),
+                   jnp.full((block_q, 1), NEG_INF), jnp.zeros((block_q, 1)))
+    else:
+        m_scr, l_scr, acc_scr = rest[-3:]
+
+        @functools.partial(_when, ki == 0)
+        def _init():
+            m_scr[:] = jnp.full(m_scr.shape, NEG_INF, m_scr.dtype)
+            l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
+            acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+
+    def _strip(r0, r1, span):
+        rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
+                                                    span[3] * tk)
+        q = q_ref[0, 0, rows, :].astype(jnp.float32)          # [Tq, D]
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)          # [Tk, D]
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        s = _strip_scores(q, k, sm_scale, qi * block_q + r0 * tq,
+                          ki * block_k + span[0] * tk, 0, tk, span, seq_len,
+                          causal, window)
+        m_new = jnp.max(s, axis=-1, keepdims=True)    # [Tq, 1]
+        if not direct:
+            m_prev = m_scr[rows, :1]
+            m_new = jnp.maximum(m_prev, m_new)
+        p = jnp.exp(s - m_new)                        # [Tq, Tk]
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        if direct:
+            return _write(rows, pv, m_new, l_new)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+        lanes = (q.shape[0], _LANES)
+        m_scr[rows, :] = jnp.broadcast_to(m_new, lanes)
+        l_scr[rows, :] = jnp.broadcast_to(l_scr[rows, :1] * alpha + l_new,
+                                          lanes)
+
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
+
+    if not direct:
+        _when(ki == nk - 1, lambda: _write(
+            slice(None), acc_scr[:], m_scr[:, :1], l_scr[:, :1]))
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k, seq_len,
-                   window=None):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+def _bwd_dq_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                   dq_ref, dq_scr, *, sm_scale, causal, sub, grid, patterns,
+                   seq_len, window=None):
+    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+    (nq, nk), (tq, tk) = grid, sub
+    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
 
-    @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
 
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32)           # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)           # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)         # [bq, D]
-        lse = lse_ref[0, 0][:, :1]                    # [bq, 1]
-        dlt = dlt_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
-                                  window), s, NEG_INF)
-        p = jnp.exp(s - lse)                          # [bq, bk]
+    _when(ki == 0, _init)
+
+    def _strip(r0, r1, span):
+        rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
+                                                    span[3] * tk)
+        q = q_ref[0, 0, rows, :].astype(jnp.float32)           # [Tq, D]
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)           # [Tk, D]
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)         # [Tq, D]
+        lse = lse_ref[0, 0, rows, :1]                          # [Tq, 1]
+        dlt = dlt_ref[0, 0, rows, :1]
+        s = _strip_scores(q, k, sm_scale, qi * block_q + r0 * tq,
+                          ki * block_k + span[0] * tk, 0, tk, span, seq_len,
+                          causal, window)
+        p = jnp.exp(s - lse)                          # [Tq, Tk]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dlt)                           # [bq, bk]
-        dq_scr[:] += sm_scale * jax.lax.dot_general(
+        ds = p * (dp - dlt)                           # [Tq, Tk]
+        dq_scr[rows, :] += sm_scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
 
-    @pl.when(ki == nk - 1)
     def _finish():
         dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
 
+    _when(ki == nk - 1, _finish)
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+
+def _bwd_dkv_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    sm_scale, causal, block_q, block_k, seq_len, window=None):
+                    sm_scale, causal, sub, grid, patterns, seq_len,
+                    window=None):
     # grid (B, H_kv, nk, group, nq): dk/dv accumulate across the GQA
     # group's q heads AND the q blocks before one narrow write — the
     # output block index is constant over both inner dims, so pallas
     # keeps it resident until the last (g, qi) visit
-    ki = pl.program_id(2)
+    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+    (nq, nk), (tq, tk) = grid, sub
+    ki = _grid_pos(2, nk)
     g = pl.program_id(3)
-    qi = pl.program_id(4)                             # q innermost here
+    qi = _grid_pos(4, nq)                             # q innermost here
     ng = pl.num_programs(3)
-    nq = pl.num_programs(4)
 
-    @pl.when(jnp.logical_and(g == 0, qi == 0))
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[:] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
 
-    def _block():
-        q = q_ref[0, 0].astype(jnp.float32)           # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)           # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, :1]
-        dlt = dlt_ref[0, 0][:, :1]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(_block_mask(qi, ki, block_q, block_k, seq_len, causal,
-                                  window), s, NEG_INF)
-        p = jnp.exp(s - lse)                          # [bq, bk]
-        # dv += p^T @ dO
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+    pl.when(jnp.logical_and(g == 0, qi == 0))(_init)
+
+    def _strip(c0, c1, span):
+        rows, cols = slice(span[0] * tq, span[3] * tq), slice(c0 * tk,
+                                                              c1 * tk)
+        q = q_ref[0, 0, rows, :].astype(jnp.float32)           # [Tq, D]
+        k = k_ref[0, 0, cols, :].astype(jnp.float32)           # [Tk, D]
+        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        do = do_ref[0, 0, rows, :].astype(jnp.float32)
+        # keys down the rows, queries along the lanes: the two products
+        # that accumulate dk and dv contract the lanes as they lie, and no
+        # [Tq, Tk] tile is transposed
+        lse = lse_ref[0, 0, :, rows]                           # [1, Tq]
+        dlt = dlt_ref[0, 0, :, rows]
+        s = _strip_scores(k, q, sm_scale, qi * block_q + span[0] * tq,
+                          ki * block_k + c0 * tk, 1, tq, span, seq_len,
+                          causal, window)
+        p = jnp.exp(s - lse)                          # [Tk, Tq]
+        dv_scr[cols, :] += jax.lax.dot_general(
+            p, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dlt)                           # [bq, bk]
-        # dk += ds^T @ q
-        dk_scr[:] += sm_scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+        ds = p * (dp - dlt)                           # [Tk, Tq]
+        dk_scr[cols, :] += sm_scale * jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _when_visible(qi, ki, block_q, block_k, causal, window, _block)
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
 
-    @pl.when(jnp.logical_and(g == ng - 1, qi == nq - 1))
     def _finish():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
+    pl.when(jnp.logical_and(g == ng - 1, qi == nq - 1))(_finish)
+
 
 def _pad_seq(x, block):
-    s = x.shape[2]
-    pad = (-s) % block
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    return x
+    """Pad the sequence axis of [B, H, S, D] up to a multiple of `block`
+    (a pad of zero rows is no operation)."""
+    return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block), (0, 0)))
 
 
-_LANES = 128  # lse/delta carry a lane-replicated trailing dim for layout
+def _scheduled_call(kernel, name, n_blocks, blocks, seq_len, causal, window,
+                    interpret, out_shape, **grid_spec):
+    """`pl.pallas_call` of one of the three kernels with its schedule: the
+    table of block kinds rides in as a scalar-prefetch operand (the index
+    maps take it as a last argument and ignore it)."""
+    sub = _pick_subtile(*blocks)
+    _count_subtiles(seq_len, *blocks, sub, causal, window)
+    kinds, patterns = _schedule(n_blocks, blocks, sub, seq_len, causal,
+                                window, by_keys=name != "flash_dkv")
+    body = functools.partial(
+        kernel, causal=causal, sub=sub, grid=n_blocks, patterns=patterns,
+        seq_len=seq_len, **({} if window is None else {"window": window}))
+    call = pl.pallas_call(
+        body, out_shape=out_shape, interpret=interpret, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                               **grid_spec))
+    return functools.partial(call, kinds)
 
 
+# Both implementations are jitted on their own: a model calls them once a
+# layer with the same shapes, and an inner `jit` is traced and lowered ONCE
+# for all of them (the kernels' unrolled strips are most of a step's
+# tracing and lowering time otherwise); XLA inlines the calls again.
+_STATIC = ("causal", "sm_scale", "block_q", "block_k", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("need_lse",))
 def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                     need_lse, window=None):
     """Returns (out [B,S,H,D], lse [B,H,Sq_padded,LANES] or None).
@@ -254,44 +509,39 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     Sq, Sk = qt.shape[2], kt.shape[2]
     nq, nk = Sq // block_q, Sk // block_k
 
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_len=S, need_lse=need_lse,
-        **({} if window is None else {"window": window}))
-    o_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    o_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, i, j, _: (b, h, i, 0))
     lse_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                            lambda b, h, i, j: (b, h, i, 0))
+                            lambda b, h, i, j, _: (b, h, i, 0))
     # narrow kv blocks are indexed by the q head's GROUP — no repeated
     # kv ever materializes in HBM (the GQA bandwidth win, kept here)
-    def kv_index(b, h, i, j):
+    def kv_index(b, h, i, j, _):
         seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
         return (b, h // group, seen(j), 0)
 
     kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_index)
-    result = pl.pallas_call(
-        kernel,
+    result = _scheduled_call(
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, need_lse=need_lse),
+        "flash_fwd", (nq, nk), (block_q, block_k), S, causal, window,
+        interpret,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            kv_spec,
-            kv_spec,
-        ],
+        in_specs=[o_spec, kv_spec, kv_spec],
         out_specs=[o_spec] + ([lse_spec] if need_lse else []),
         out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype)] + (
             [jax.ShapeDtypeStruct((B, H, Sq, _LANES), jnp.float32)]
             if need_lse else []),
-        scratch_shapes=[
+        # running max, denominator, accumulator: none where nk is 1
+        scratch_shapes=[] if nk == 1 else [
             _scratch((block_q, _LANES)),
             _scratch((block_q, _LANES)),
             _scratch((block_q, D)),
         ],
-        interpret=interpret,
-        name="flash_fwd",
     )(qt, kt, vt)
     out = result[0][:, :, :S].transpose(0, 2, 1, 3)
     return out, (result[1] if need_lse else None)
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                     interpret, g_lse=None, window=None):
     B, S, H, D = q.shape
@@ -312,56 +562,58 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     delta = jnp.pad(delta, ((0, 0), (0, 0), (0, Sq - S)))
+    # dq reads both lane-replicated, a query a row; dk/dv a query a lane
+    lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
     delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
 
-    windowed = {} if window is None else {"window": window}
-
-    def k_index(b, h, i, j):
+    def k_index(b, h, i, j, _):
         seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
         return (b, h // group, seen(j), 0)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    q_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, i, j, _: (b, h, i, 0))
     k_spec = pl.BlockSpec((1, 1, block_k, D), k_index)
     r_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                          lambda b, h, i, j: (b, h, i, 0))
+                          lambda b, h, i, j, _: (b, h, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S,
-                          **windowed),
+    dq = _scheduled_call(
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale),
+        "flash_dq", (nq, nk), (block_q, block_k), S, causal, window,
+        interpret,
         grid=(B, H, nq, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[_scratch((block_q, D))],
-        interpret=interpret,
-        name="flash_dq",
     )(qt, kt, vt, dot, lse, delta)
 
     # swap grid roles: (b, kv-head, k-block, group-member, q-block) —
     # q innermost; dk/dv come out NARROW, accumulated across the group
     # (the narrow output replaces the former repeat-then-sum cotangent)
-    def q_index(b, kh, j, g, i):
+    def q_index(b, kh, j, g, i, _):
         seeing = _q_blocks_of(j, block_q, block_k, causal, window, nq)
         return (b, kh * group + g, seeing(i), 0)
 
     qk_spec = pl.BlockSpec((1, 1, block_q, D), q_index)
     kk_spec = pl.BlockSpec((1, 1, block_k, D),
-                           lambda b, kh, j, g, i: (b, kh, j, 0))
-    rk_spec = pl.BlockSpec((1, 1, block_q, _LANES), q_index)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S,
-                          **windowed),
+                           lambda b, kh, j, g, i, _: (b, kh, j, 0))
+
+    def q_lane_index(*args):
+        b, h, i, _ = q_index(*args)
+        return (b, h, 0, i)
+
+    rk_spec = pl.BlockSpec((1, 1, 1, block_q), q_lane_index)
+    dk, dv = _scheduled_call(
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale),
+        "flash_dkv", (nq, nk), (block_q, block_k), S, causal, window,
+        interpret,
         grid=(B, H_kv, nk, group, nq),
         in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
         out_specs=[kk_spec, kk_spec],
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)],
         scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
-        interpret=interpret,
-        name="flash_dkv",
-    )(qt, kt, vt, dot, lse, delta)
+    )(qt, kt, vt, dot, lse_t, delta_t)
 
     tr = lambda x, s: x[:, :, :s].transpose(0, 2, 1, 3)
     return tr(dq, S), tr(dk, S), tr(dv, S)
@@ -460,10 +712,14 @@ def _resolve_call_args(q, k, sm_scale, block_q, block_k, interpret):
     auto-select (native Mosaic on TPU, interpreter elsewhere), and block
     sizes clamped into the padded sequence range.
 
-    Default blocks are 1024x1024 — measured 28-46% faster than 512x512 on
-    v5e at S in [4096, 8192] (f32 score tiles stay well inside v5e-class
-    ~128MB VMEM; pre-v4 generations with small VMEM may need block sizes
-    passed explicitly)."""
+    Default blocks are 1024x1024: the number of grid steps is what a
+    larger block saves (S=1024 is one step a head), and what a block
+    computes is cut to its visible sub-tiles of `_SUBTILE` inside it.  The
+    1024 itself dates from an earlier runtime (S in [4096, 8192]) and was
+    not measured again; the sub-tile was, on the v5e (PERF.md section 6, PR
+    29).  A 1024x1024 strip of float32 scores is 4 MB of v5e-class ~128MB
+    VMEM; pre-v4 generations with small VMEM may need block sizes passed
+    explicitly."""
     if q.shape[2] % k.shape[2]:
         raise ValueError(
             f"q heads {q.shape[2]} must be a multiple of kv heads "

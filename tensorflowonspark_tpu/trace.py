@@ -71,6 +71,12 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     (a token's picks that fell on experts held here, and on absent ones)
     moe.load.max  moe.load.mean (tokens of the fullest held expert and of
     the mean one; all four summed over layers and steps)
+    counters of a process that traces flash attention
+    (``ops.flash_attention``, added on the host each time a kernel call is
+    traced, so per compiled program and not per step):
+    flash.subtiles.computed  flash.subtiles.masked  flash.subtiles.square
+    (sub-tiles a head computes, those of them that carry the mask
+    arithmetic, and those the padded square holds)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

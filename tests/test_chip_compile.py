@@ -90,25 +90,35 @@ def _kernels(text):
     return found
 
 
-def _qkv(chip):
-    return (chip((B, S, H, DH), jnp.bfloat16),
-            chip((B, S, N_KV, DH), jnp.bfloat16),
-            chip((B, S, N_KV, DH), jnp.bfloat16))
+# (batch, sequence, query heads, key/value heads, head size): the private
+# flagship's head of 128, and GPT-2 large as `gpt2-large.fed_b8` runs it,
+# one resident block of 1024 a head walked in sub-tiles at a head of 64
+FLASH_SHAPES = {"flagship": (B, S, H, N_KV, DH),
+                "gpt2-large": (8, 1024, 20, 20, 64)}
 
 
-def test_flash_forward_lowers(chip):
+def _qkv(chip, shape="flagship"):
+    b, s, h, n_kv, dh = FLASH_SHAPES[shape]
+    return (chip((b, s, h, dh), jnp.bfloat16),
+            chip((b, s, n_kv, dh), jnp.bfloat16),
+            chip((b, s, n_kv, dh), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_forward_lowers(chip, shape):
     fn = functools.partial(flash_attention, causal=True, interpret=False)
-    text = _compile(fn, *_qkv(chip))
+    text = _compile(fn, *_qkv(chip, shape))
     assert "tpu_custom_call" in text
     assert _kernels(text) == {"flash_fwd"}
 
 
-def test_flash_backward_lowers(chip):
+@pytest.mark.parametrize("shape", list(FLASH_SHAPES))
+def test_flash_backward_lowers(chip, shape):
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, interpret=False)
         return jnp.sum(out.astype(jnp.float32))
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip, shape))
     # forward + dq + dk/dv kernels
     assert text.count("tpu_custom_call") >= 3
     assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}

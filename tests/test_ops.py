@@ -55,25 +55,30 @@ def test_flash_attention_grad_matches_reference():
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("group", [2, 4])
-def test_flash_attention_gqa_narrow_kv(group):
+@pytest.mark.parametrize("group,B,S,D,block", [
+    (2, 2, 64, 32, 16),
+    (4, 2, 64, 32, 16),
+    (4, 1, 1024, 64, 1024),     # one resident block walked in sub-tiles
+    (4, 1, 2048, 128, 1024),    # two blocks: loop bounds from program_id
+])
+def test_flash_attention_gqa_narrow_kv(group, B, S, D, block):
     # GQA-native: narrow k/v feed the kernel directly; outputs match the
     # repeated-kv reference, forward and backward (dk/dv come back
     # NARROW — the repeat's summed cotangent, computed in-kernel)
-    B, S, H, D = 2, 64, 4, 32
+    H = 4
     ks = jax.random.split(jax.random.key(3), 3)
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, H // group, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, H // group, D), jnp.float32)
 
-    out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+    out = flash_attention(q, k, v, causal=True, block_q=block, block_k=block,
                           interpret=True)
     ref = attention_reference(q, k, v, causal=True)   # repeats internally
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=16,
-                                       block_k=16, interpret=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=True, block_q=block,
+                                       block_k=block, interpret=True) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(attention_reference(q, k, v, causal=True) ** 2)
@@ -233,14 +238,21 @@ def test_transformer_attention_impl_validated():
         Transformer(cfg).init(jax.random.key(0), tokens)
 
 
-@pytest.mark.parametrize("causal,S", [(True, 48), (False, 40)])
-def test_flash_attention_grad_ragged(causal, S):
+@pytest.mark.parametrize("causal,S,block,D", [
+    (True, 48, 32, 32),
+    (False, 40, 32, 32),
+    (True, 1536, 1024, 64),     # three blocks of 512, sub-tiles of 256
+    (False, 1536, 1024, 128),   # no mask at all: every sub-tile plain
+    (True, 1100, 1024, 64),     # padded to 2048: sub-tiles the padding
+    (False, 1100, 1024, 64),    # crosses are masked, those past it skipped
+])
+def test_flash_attention_grad_ragged(causal, S, block, D):
     # multi-block accumulation with padded rows/keys in BOTH bwd kernels
-    q, k, v = _qkv(S=S)
+    q, k, v = _qkv(B=2 if S < 1024 else 1, S=S, H=4 if S < 1024 else 2, D=D)
 
     def f_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=32,
-                                       block_k=32, interpret=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=block,
+                                       block_k=block, interpret=True) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(attention_reference(q, k, v, causal=causal) ** 2)
@@ -282,14 +294,21 @@ def test_flash_block_pick_avoids_padding():
 
 # ---- a window (sliding-attention layers) -----------------------------------
 
-@pytest.mark.parametrize("S,window,blocks,group", [
-    (200, 50, (64, 64), 1),      # S and the window both off the block
-    (200, 64, (64, 32), 2),      # unequal blocks, GQA
-    (96, 1, (32, 32), 1),        # a window of the token itself
-    (96, 300, (32, 32), 4),      # a window wider than the row masks nothing
+@pytest.mark.parametrize("S,window,blocks,group,D", [
+    (200, 50, (64, 64), 1, 32),      # S and the window both off the block
+    (200, 64, (64, 32), 2, 32),      # unequal blocks, GQA
+    (96, 1, (32, 32), 1, 32),        # a window of the token itself
+    (96, 300, (32, 32), 4, 32),      # wider than the row: masks nothing
+    # the sub-tile walk inside resident blocks of 1024 (512 at S=1536)
+    (1024, 256, (1024, 1024), 1, 64),    # one block, a sub-tile's window
+    (1024, 1024, (1024, 1024), 4, 128),  # the window the row never reaches
+    (1024, 300, (1024, 1024), 1, 128),   # no multiple of the sub-tile
+    (1536, 256, (1024, 1024), 1, 64),    # ragged for 1024: blocks of 512
+    (2048, 1024, (1024, 1024), 4, 64),   # two blocks, edge in the first
+    (2048, 1000, (1024, 1024), 1, 128),  # two blocks, off the sub-tile
 ])
 def test_flash_window_forward_and_backward_match_dense(S, window, blocks,
-                                                       group):
+                                                       group, D):
     """Against the dense windowed path the model's CPU branch runs
     (`dot_product_attention(window=)`): key blocks left of the window are
     skipped and their index maps clamped, in all three kernels."""
@@ -297,7 +316,7 @@ def test_flash_window_forward_and_backward_match_dense(S, window, blocks,
         dot_product_attention)
     from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
 
-    q, k, v = _qkv(B=1, S=S)
+    q, k, v = _qkv(B=1, S=S, D=D)
     k, v = k[:, :, ::group], v[:, :, ::group]
 
     def flash(q, k, v):
@@ -333,3 +352,40 @@ def test_flash_window_skips_the_blocks_left_of_it():
     assert [same(j) for j in range(8)] == list(range(8))
     with pytest.raises(ValueError, match="window"):
         flash_attention(*_qkv(S=16), window=0)
+
+
+@pytest.mark.parametrize("causal,S,D", [(True, 1024, 64), (False, 1024, 128),
+                                        (True, 2048, 64)])
+def test_flash_with_lse_and_a_cotangent_on_lse(causal, S, D):
+    """`flash_attention_with_lse` (ring attention's local step): both
+    outputs and the gradients through BOTH, against the dense softmax."""
+    from tensorflowonspark_tpu.ops.flash_attention import (
+        flash_attention_with_lse)
+
+    q, k, v = _qkv(B=1, S=S, H=2, D=D)
+    w = jax.random.normal(jax.random.key(5), q.shape)
+    u = jax.random.normal(jax.random.key(6), (1, 2, S))
+
+    def dense(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / D ** 0.5
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, axis=-1))
+
+    def flash(q, k, v):
+        return flash_attention_with_lse(q, k, v, causal=causal,
+                                        interpret=True)
+
+    def scalar(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * w) + jnp.sum(lse * u)
+        return f
+
+    for a, b in zip(flash(q, k, v), dense(q, k, v)):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+    got = jax.grad(scalar(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(scalar(dense), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
