@@ -5,9 +5,9 @@ The quickest proof that the system still starts on the chip.  A JAX-free
 driver (this process) launches a one-executor cluster through the normal
 entry points — `cluster.run(LocalBackend(1), map_fun, ...,
 InputMode.SPARK)`, `c.train(partitions)`, `c.shutdown(timeout=...)`; the
-background node process owns the chip, builds the flagship LM the way
-`benchmarks.make_flagship_step` does (FLAGSHIP_LM_V2: 0.87B params, d2048,
-16 layers, GQA 16/8, d_ff 8192, S=1024, batch 8, bf16, adamw_fused), pulls
+background node process owns the chip, builds the flagship LM
+(FLAGSHIP_LM_V2: 0.87B params, d2048, 16 layers, GQA 16/8, d_ff 8192,
+S=1024, batch 8, bf16, rmsnorm, adamw_fused), pulls
 `[8, 1025]` int32 token batches off the shm ring with
 `DataFeed.next_numpy_batch`, keeps transfers in flight with
 `feed.device_prefetch`, and takes a few donated steps.
@@ -41,29 +41,37 @@ COMPARE_STEPS = 3     # --chips 4: steps compared against one device
 LOSS_RTOL = 1e-2
 BUDGET_S = 1100       # whole script, compile included (driver limit 1200)
 
+# What the smoke trains: a private 0.87B LLaMA-shaped config at the width
+# the earlier rounds' records were taken at.  Plain dicts and scalars:
+# the driver process imports no JAX.
+FLAGSHIP_LM_V2 = dict(
+    vocab_size=32000, d_model=2048, n_heads=16, n_kv_heads=8,
+    n_layers=16, d_ff=8192, max_seq_len=1024, dtype="bfloat16",
+    rope=True, attention_impl="auto", norm_type="rmsnorm")
+FLAGSHIP_BATCH = 8
+FLAGSHIP_OPTIMIZER = "adamw_fused"    # ops/fused_optim.py, one HBM pass
+FLAGSHIP_MU_DTYPE = "bfloat16"
+
 
 def smoke_args(chips=1, seed=0, platform="tpu", model=None, batch=None):
     """The run's description, shipped to the node as `tf_args`.  The
     defaults ARE the smoke (flagship width on a TPU); the CPU rehearsal in
     tests/test_chip_smoke.py passes a toy `model` and `platform="cpu"`."""
-    from tensorflowonspark_tpu import benchmarks
-
     return argparse.Namespace(
         chips=chips, seed=seed, platform=platform,
-        model=dict(model or benchmarks.FLAGSHIP_LM_V2),
-        batch=batch or benchmarks.FLAGSHIP_BATCH,
+        model=dict(model or FLAGSHIP_LM_V2),
+        batch=batch or FLAGSHIP_BATCH,
         steps=WARM_STEPS + 2 * TIMED_STEPS)
 
 
 # --------------------------------------------------------------- node ----
 
 def _build(args, mesh):
-    """Model, state and the donated train step, as
-    `benchmarks.make_flagship_step` builds them (plus the mesh)."""
+    """Model, state and the donated train step over `mesh` (None: one
+    device)."""
     import jax
     import jax.numpy as jnp
 
-    from tensorflowonspark_tpu import benchmarks
     from tensorflowonspark_tpu.models.transformer import (
         Transformer, TransformerConfig, lm_loss)
     from tensorflowonspark_tpu.optim import make_optimizer
@@ -77,9 +85,8 @@ def _build(args, mesh):
         return lm_loss(model.apply({"params": p}, batch[:, :-1]),
                        batch[:, 1:])
 
-    opt, _ = make_optimizer(benchmarks.FLAGSHIP_OPTIMIZER,
-                            learning_rate=3e-4,
-                            mu_dtype=benchmarks.FLAGSHIP_MU_DTYPE)
+    opt, _ = make_optimizer(FLAGSHIP_OPTIMIZER, learning_rate=3e-4,
+                            mu_dtype=FLAGSHIP_MU_DTYPE)
     init = jax.jit(lambda key: model.init(key, tokens)["params"])
 
     def make_state(params):

@@ -37,7 +37,7 @@ import time
 # deliberately-broken fixtures that would drown the signal.
 DEFAULT_PATHS = [
     "tensorflowonspark_tpu", "tests", "examples", "scripts",
-    "bench.py", "chip_smoke.py", "__graft_entry__.py",
+    "chip_smoke.py", "__graft_entry__.py",
 ]
 DEFAULT_BASELINE = os.path.join("scripts", "graftcheck_baseline.json")
 
@@ -89,7 +89,7 @@ class Rule:
             return True
         parts = _posix(ctx.path).split("/")
         return PACKAGE_DIR in parts or ctx.path in (
-            "bench.py", "chip_smoke.py", "__graft_entry__.py")
+            "chip_smoke.py", "__graft_entry__.py")
 
     def check(self, ctx):  # pragma: no cover - abstract
         raise NotImplementedError

@@ -290,6 +290,7 @@ class Job:
         self.state = spec.get("state") or "running"
         self.error = spec.get("error")
         self.halt = threading.Event()      # cancel/failure -> workers out
+        self.merging = False               # one worker claimed the merge
         # progress (JobManager._lock guards every access)
         self.pending = collections.deque()
         self.leased = set()
@@ -391,13 +392,15 @@ class JobManager:
             return {"next_offset": job.splits[p][0], "out_bytes": 0,
                     "done_n": 0, "failed_n": 0, "done": False}
 
-    def _persist_state(self, job):
-        """Best-effort durable state transition (job.json rewrite).  A
+    def _persist_state(self, job, state=None):
+        """Best-effort durable state transition (job.json rewrite; to
+        ``state`` where the in-memory one is published after it).  A
         persistent spool fault leaves the durable state behind the
         in-memory one; a later rescan then re-drives from checkpoints,
         which is idempotent by construction."""
         with self._lock:
-            spec = dict(job.spec, state=job.state, error=job.error)
+            spec = dict(job.spec, state=state or job.state,
+                        error=job.error)
             job.spec = spec
         try:
             self._spool_write(os.path.join(job.dir, "job.json"), spec)
@@ -569,7 +572,8 @@ class JobManager:
         returns the terminal status."""
         job = self._get(job_id)
         with self._lock:
-            terminal = job.state in TERMINAL_STATES
+            # a job whose output is being put in place finishes
+            terminal = job.state in TERMINAL_STATES or job.merging
             if not terminal:
                 job.state = "cancelled"
         if not terminal:
@@ -667,6 +671,9 @@ class JobManager:
                     job.state = "failed"
                     job.error = (f"partition {p} failed "
                                  f"{n} attempts: {err}")
+                    # counted with the state: whoever sees "failed"
+                    # sees the count
+                    self.counters.inc("jobs_failed")
                     failed = True
         if self.trace is not None:
             self.trace.span_at(job.trace_id, "job.partition",
@@ -680,7 +687,6 @@ class JobManager:
         if failed:
             job.halt.set()
             self._persist_state(job)
-            self.counters.inc("jobs_failed")
 
     def _run_partition(self, job, lease):
         p = lease["p"]
@@ -849,12 +855,14 @@ class JobManager:
 
     def _maybe_finish(self, job):
         """Last worker out merges the partition spools into the final
-        output (atomic rename) and flips the durable state."""
+        output (atomic rename) and flips the durable state.  The state
+        is published last: whoever sees ``completed`` finds the output
+        in place, the durable record written and the count moved."""
         with self._lock:
-            if (job.state != "running" or job.leased
+            if (job.state != "running" or job.leased or job.merging
                     or len(job.done) != len(job.splits)):
                 return
-            job.state = "completed"   # claimed under the lock: exactly
+            job.merging = True        # claimed under the lock: exactly
             n_parts = len(job.splits)  # one worker runs the merge
         try:
             tmp = job.output + ".tmp"
@@ -876,12 +884,14 @@ class JobManager:
             with self._lock:
                 job.state = "failed"
                 job.error = f"output merge failed: {e}"
+                self.counters.inc("jobs_failed")
             self._persist_state(job)
-            self.counters.inc("jobs_failed")
             return
-        self._persist_state(job)
+        self._persist_state(job, state="completed")
         self.counters.inc("jobs_completed")
         if self.trace is not None:
             self.trace.event(job.trace_id, "job.done", job=job.id,
                              output=job.output)
+        with self._lock:
+            job.state = "completed"
         logger.info("job %s: completed -> %s", job.id, job.output)

@@ -84,9 +84,6 @@ class TransformerConfig:
     # scale-only, no mean subtraction — one statistics reduce per norm
     # instead of two, which is exactly the flagship profile's non-matmul
     # tail; convergence-equivalent for pre-LN decoders)
-    fused_ln: bool = False        # Pallas fused layernorm fwd (single
-    # VMEM pass; falls back to the XLA reference under an active mesh —
-    # pallas_call is a custom call GSPMD cannot partition)
     norm_style: str = "pre"       # pre-LN (GPT/LLaMA) | post-LN (BERT)
     activation: str = "gelu_tanh"  # gelu_tanh | gelu_exact | relu | silu
     mlp_style: str = "plain"      # plain (wo(act(wi x))) | gated (LLaMA
@@ -623,7 +620,7 @@ def _paged_attention_body(attn_self, q, k, v):
     [B, L, n_kv, Dh] view and runs a full-length masked softmax —
     O(max_seq)/token, kept as the parity reference and as the fallback
     under an active mesh (pallas is a custom call GSPMD cannot
-    partition — the _flash_dispatch/_single_device discipline).
+    partition — the _flash_dispatch discipline).
 
     CONTRACT: a row's table must name valid pool pages for every
     position it will touch before those positions are written (admission
@@ -1269,50 +1266,12 @@ def _sp_constrain(x, cfg):
     return _constrain_bsd(x, cfg, cfg.sp_axis, None)
 
 
-def _single_device():
-    # The ONLY configuration where an unpartitionable pallas custom call
-    # is always safe: one visible device means every jit — mesh context,
-    # in_shardings, or plain — is trivially single-shard.  An abstract-
-    # mesh check is NOT sufficient: make_train_step shards via
-    # in_shardings without jax.set_mesh, which traces with an EMPTY
-    # abstract mesh while still GSPMD-partitioning the program.
-    return len(jax.devices()) == 1
-
-
-class FusedLayerNorm(nn.Module):
-    """flax LayerNorm drop-in over the Pallas fused kernel (f32 stats,
-    one VMEM pass).  Param names match nn.LayerNorm ("scale"/"bias") so
-    checkpoints interchange.  The kernel runs only on a single-device
-    host (the serving/AOT and single-chip bench case); with multiple
-    devices visible the XLA reference runs instead — pallas_call is a
-    custom call GSPMD cannot partition, and sharded jits cannot be
-    detected reliably from inside a traced module (see _single_device).
-    Output dtype follows x."""
-    epsilon: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        from tensorflowonspark_tpu.ops.layernorm import (
-            fused_layernorm, layernorm_reference)
-        D = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (D,), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (D,), jnp.float32)
-        if not _single_device():
-            return layernorm_reference(x, scale, bias, self.epsilon)
-        return fused_layernorm(x, scale, bias, eps=self.epsilon)
-
-
 def _make_ln(cfg, name):
     if cfg.norm_type not in ("layernorm", "rmsnorm"):
         raise ValueError(
             f"norm_type={cfg.norm_type!r} not in ('layernorm', 'rmsnorm')")
     if cfg.norm_type == "rmsnorm":
-        if cfg.fused_ln:
-            raise ValueError("fused_ln applies to norm_type='layernorm' "
-                             "(the Pallas kernel computes mean+variance)")
         return nn.RMSNorm(name=name, dtype=jnp.float32, epsilon=cfg.ln_eps)
-    if cfg.fused_ln:
-        return FusedLayerNorm(epsilon=cfg.ln_eps, name=name)
     return nn.LayerNorm(name=name, dtype=jnp.float32, epsilon=cfg.ln_eps)
 
 

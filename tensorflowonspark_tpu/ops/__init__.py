@@ -7,7 +7,6 @@ Pallas kernels that stream blocks HBM→VMEM and keep the MXU busy, with an
 interpret-mode path so the same kernels are testable on the CPU mesh.
 
 - flash_attention : blocked online-softmax attention, O(S) memory per core
-- fused_layernorm : single-pass layernorm, f32 accumulation in VMEM
 - fused_unembed_xent : chunked lm_head matmul + cross entropy, no
   materialized logits (XLA scan, not Pallas — the MXU matmul is already
   optimal; the win is memory, see ops/xent.py)
@@ -39,14 +38,13 @@ interpret-mode path so the same kernels are testable on the CPU mesh.
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 from tensorflowonspark_tpu.ops.fused_optim import adamw_fused, lion_fused
 from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
-from tensorflowonspark_tpu.ops.layernorm import fused_layernorm
 from tensorflowonspark_tpu.ops.paged_attention import paged_attention
 from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
 from tensorflowonspark_tpu.ops.quant_matmul import quant_matmul
 from tensorflowonspark_tpu.ops.xent import fused_unembed_xent
 
-__all__ = ["flash_attention", "fused_layernorm", "fused_unembed_xent",
-           "adamw_fused", "lion_fused", "grouped_matmul", "paged_attention",
+__all__ = ["flash_attention", "fused_unembed_xent", "adamw_fused",
+           "lion_fused", "grouped_matmul", "paged_attention",
            "paged_prefill", "quant_matmul"]
 
 

@@ -7,7 +7,7 @@ reads attention context by gathering each row's FULL logical
 ``[max_seq, n_kv, Dh]`` view out of the pool — per chunk that is
 O(pool) write traffic and O(max_seq) read traffic no matter how short
 the chunk is.  Prefill-role replicas and the host-tier warm-miss path
-live in this loop, so it sets ttft_ms directly.
+live in this loop, so it sets time to first token directly.
 
 This module is the prefill twin of ops/paged_attention.py (the PR-4
 flash-decode read) and closes ROADMAP open item 1 with two kernels:

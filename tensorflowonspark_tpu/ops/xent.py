@@ -29,7 +29,7 @@ assumes the vocab dimension is unsharded in this function's frame.  Under
 a vocab-sharded (tp) lm_head keep using `models.transformer.lm_loss`
 (gather-free one-hot einsum, partitions cleanly); this op is the
 single-device / data-parallel fast path — exactly the layouts the
-driver bench and the examples train in.
+benchmark's cells and the examples train in.
 """
 import functools
 

@@ -952,8 +952,7 @@ class ContinuousBatcher:
         # through a bounded chunk queue — up to `pipeline_depth` flushed
         # chunks stay in flight, so the device keeps stepping while the
         # host works.  "serial" is the single-thread reference engine
-        # (byte-identical tokens; the parity baseline and the
-        # engine_tps bench's comparison arm).
+        # (byte-identical tokens; the parity baseline).
         if engine not in ("async", "serial"):
             raise ValueError(f"engine={engine!r} not in "
                              "('async', 'serial')")
@@ -1953,10 +1952,9 @@ class ContinuousBatcher:
         full-prefix page demotes to the host tier first when it is
         armed); pages still shared with live rows stay.  Thread-safe:
         from any host thread this posts a device-loop op and blocks on
-        the ack.  Ops/bench hook — the warm_ttft_ms segment calls this
-        between its cold and warm passes so the warm pass can only be
-        served by host->device promotion, and an operator can use it to
-        return a quiesced replica's pool to 100% free.  Returns the
+        the ack.  Ops hook: after it a returning conversation can only
+        be served by host->device promotion, and an operator can use it
+        to return a quiesced replica's pool to 100% free.  Returns the
         number of pages evicted."""
         if not self.kv_page_size:
             return 0
@@ -2095,6 +2093,22 @@ class ContinuousBatcher:
             pages + [self._sink] * (self._table_width - len(pages)),
             jnp.int32)
 
+    def _map_row(self, row, pages, spare=0):
+        """Point `row`'s table at `pages`, widening every table first
+        where `pages` and `spare` sink entries past them outrun it.
+        A lane row passes ``spare=1`` until its last chunk: it sits
+        through other rows' decode rounds, each writing at ITS
+        cache_index too, just past its pages, and the write path clamps
+        an overshoot to the table's LAST entry — which must then be the
+        sink, not the row's own last page."""
+        import jax.numpy as jnp
+
+        if len(pages) + spare > self._table_width:
+            self._grow_table(len(pages) + spare)
+        self._cache = self._set_table(self._cache,
+                                      jnp.asarray(row, jnp.int32),
+                                      self._row_entries(pages))
+
     def _grow_table(self, need):
         """Widen every row's page table to cover `need` entries: pow2
         geometric steps (at least doubling) clamped at the full-
@@ -2150,13 +2164,12 @@ class ContinuousBatcher:
         cannot cover the need even after the overflow valve — a
         definitive failure that fails the request with a typed
         KVOverflowError instead of wedging the lane forever."""
-        import jax.numpy as jnp
-
         if adm["di"] < len(adm["d_sizes"]):
             return True     # draft catch-up: dense draft cache, no pages
         item, row = adm["item"], adm["row"]
         upto = adm["offset"] + adm["sizes"][adm["i"]]
-        if upto >= len(adm["src"]):
+        final = upto >= len(adm["src"])
+        if final:
             need = self._pages_needed(len(item["prompt"]),
                                       item["max_new"],
                                       rep=item["rep"])
@@ -2187,11 +2200,7 @@ class ContinuousBatcher:
         try:
             pages = self._assert_no_sink(
                 (self._row_pages[row] or []) + fresh)
-            if len(pages) > self._table_width:
-                self._grow_table(len(pages))
-            self._cache = self._set_table(self._cache,
-                                          jnp.asarray(row, jnp.int32),
-                                          self._row_entries(pages))
+            self._map_row(row, pages, spare=0 if final else 1)
         except BaseException:
             # conservation: a grow kill / device OOM between the pops
             # and the table write must not strand the fresh pages
@@ -2211,8 +2220,6 @@ class ContinuousBatcher:
         pages are allocated chunk-by-chunk as the lane's prefill
         advances (`_ensure_long_pages`), so admitting a 100k-token
         prompt does not reserve its whole footprint up front."""
-        import jax.numpy as jnp
-
         if faults.deny("serve.alloc"):
             return False
 
@@ -2244,11 +2251,7 @@ class ContinuousBatcher:
         promo = fresh[:len(host_run)]
         try:
             pages = self._assert_no_sink(shared + fresh)
-            if len(pages) > self._table_width:
-                self._grow_table(len(pages))
-            self._cache = self._set_table(self._cache,
-                                          jnp.asarray(row, jnp.int32),
-                                          self._row_entries(pages))
+            self._map_row(row, pages, spare=1 if lazy else 0)
             if host_run:
                 self._promote_scatter(promo, host_run)
         except BaseException:
@@ -3599,11 +3602,7 @@ class ContinuousBatcher:
             pages = [self._free_pages.pop() for _ in range(need)]
             try:
                 self._assert_no_sink(pages)
-                if len(pages) > self._table_width:
-                    self._grow_table(len(pages))
-                self._cache = self._set_table(
-                    self._cache, jnp.asarray(row, jnp.int32),
-                    self._row_entries(pages))
+                self._map_row(row, pages)
                 # kv blocks were normalized and pow2-padded in
                 # submit_resume (host thread); pad rows land in the sink
                 width = _pow2_width(n_have)
@@ -3986,8 +3985,7 @@ class ContinuousBatcher:
         """The single-thread reference engine: dispatch, flush, process
         the PREVIOUS chunk inline (double-buffered readback — the copy
         rides under the next chunk's compute).  Byte-identical tokens to
-        the async engine; kept as the parity baseline and the
-        engine_tps bench's comparison arm."""
+        the async engine; kept as the parity baseline."""
         try:
             reads = []       # dispatched this chunk: [(toks, counts,
             inflight = None  # done, gens)]; previous chunk in host copy
@@ -4085,9 +4083,9 @@ class ContinuousBatcher:
         """Terminal failure of either engine thread: record the cause,
         stop the other thread, fail every queued / in-flight /
         mid-admission request, and release retire-ack waiters."""
+        self._dead = e      # before the log line: a caller whose handle
+        self._stop.set()    # already failed must find the engine dead
         logger.exception(msg)
-        self._dead = e
-        self._stop.set()
         adms, self._admissions = self._admissions, []
         for adm in adms:
             adm["item"]["h"]._fail(e)

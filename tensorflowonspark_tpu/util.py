@@ -227,8 +227,8 @@ def pin_platform(platform):
 def enable_compile_cache():
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    ONE rule, for the node bootstrap of `chip_smoke.py`, `bench.py` and
-    the `scripts/` harnesses: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    ONE rule, for every node bootstrap (`chip_smoke.py`, the cells of
+    `benchmark/`): where ``JAX_COMPILATION_CACHE_DIR`` is set,
     JAX already honours it and nothing is set in code; where it is not,
     the cache lives in ``.jax_cache/`` at the root of this checkout
     (git-ignored).  The path is part of the cache key, so it is fixed and

@@ -19,21 +19,28 @@ worker collects the same tests and only the one handed this file loads
 the library), and every compile runs in the test's own process.
 """
 import functools
+import os
 import re
+import sys
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu import benchmarks, quantize
-from tensorflowonspark_tpu.ops import (flash_attention, fused_layernorm,
-                                       paged_attention, paged_prefill,
-                                       quant_matmul)
+from tensorflowonspark_tpu import quantize
+from tensorflowonspark_tpu.ops import (flash_attention, paged_attention,
+                                       paged_prefill, quant_matmul)
 from tensorflowonspark_tpu.ops.fused_optim import adamw_fused
 
-LM = benchmarks.FLAGSHIP_LM_V2
-B, S = benchmarks.FLAGSHIP_BATCH, LM["max_seq_len"]
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+LM = chip_smoke.FLAGSHIP_LM_V2
+B, S = chip_smoke.FLAGSHIP_BATCH, LM["max_seq_len"]
 D, H, N_KV = LM["d_model"], LM["n_heads"], LM["n_kv_heads"]
 DH, D_FF, VOCAB = D // H, LM["d_ff"], LM["vocab_size"]
 
@@ -214,13 +221,6 @@ def test_adamw_fused_apply_lowers_under_a_mesh(topo, chip):
     assert text.count("tpu_custom_call") >= len(shapes)
 
 
-def test_layernorm_lowers(chip):
-    fn = functools.partial(fused_layernorm, interpret=False)
-    text = _compile(fn, chip((B * S, D), jnp.bfloat16),
-                    chip((D,), jnp.float32), chip((D,), jnp.float32))
-    assert "tpu_custom_call" in text
-
-
 @pytest.mark.parametrize("rows", [8, 16])
 def test_int8_quant_matmul_lowers(chip, rows):
     w = {"q": chip((D, D_FF), jnp.int8),
@@ -238,8 +238,10 @@ def test_int8_quant_matmul_lowers(chip, rows):
 # multiple of 8 nor the whole dim.  strict: the PR that redoes the
 # blocking turns these into plain passing tests, and cannot forget to.
 
-_DEC = benchmarks.FLAGSHIP_DECODE
-_PRE = benchmarks.FLAGSHIP_PREFILL_KERNEL
+# The serving shapes these two record: steady-state paged decode over
+# long rows, and one chunk of chunked prefill.
+_DEC = dict(n_slots=16, page_size=64, max_seq=4096)
+_PRE = dict(n_slots=4, page_size=64, max_seq=4096, chunk=256)
 
 
 def _pool(chip, n_slots, page, max_seq):

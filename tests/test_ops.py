@@ -11,8 +11,6 @@ import pytest
 
 from tensorflowonspark_tpu.ops.flash_attention import (
     attention_reference, flash_attention)
-from tensorflowonspark_tpu.ops.layernorm import (
-    fused_layernorm, layernorm_reference)
 
 
 def _qkv(B=2, S=64, H=4, D=32, dtype=jnp.float32, seed=0):
@@ -104,87 +102,6 @@ def test_flash_attention_bf16():
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(out.astype(np.float32),
                                ref.astype(np.float32), atol=3e-2, rtol=3e-2)
-
-
-def test_fused_layernorm_matches_reference():
-    x = jax.random.normal(jax.random.key(0), (4, 48, 96)) * 3 + 1
-    scale = jax.random.normal(jax.random.key(1), (96,))
-    bias = jax.random.normal(jax.random.key(2), (96,))
-    out = fused_layernorm(x, scale, bias, block_n=16, interpret=True)
-    ref = layernorm_reference(x, scale, bias)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-
-def test_fused_layernorm_grad():
-    x = jax.random.normal(jax.random.key(0), (8, 64))
-    scale = jnp.ones((64,))
-    bias = jnp.zeros((64,))
-
-    def f(fn, x, s, b):
-        return jnp.sum(fn(x, s, b) ** 3)
-
-    g1 = jax.grad(lambda *a: f(
-        lambda x, s, b: fused_layernorm(x, s, b, block_n=8, interpret=True),
-        *a), argnums=(0, 1, 2))(x, scale, bias)
-    g2 = jax.grad(lambda *a: f(layernorm_reference, *a),
-                  argnums=(0, 1, 2))(x, scale, bias)
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
-
-
-def test_transformer_fused_ln_matches_flax_ln():
-    # fused_ln=True must be numerically interchangeable with the default
-    # nn.LayerNorm path (param names match, so checkpoints interchange)
-    from tensorflowonspark_tpu.models.transformer import (
-        Transformer, TransformerConfig)
-
-    kw = dict(vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
-              max_seq_len=16, dtype="float32", rope=True,
-              attention_impl="dense")
-    tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, 64, (2, 16)), jnp.int32)
-    m_ref = Transformer(TransformerConfig(**kw))
-    m_fused = Transformer(TransformerConfig(fused_ln=True, **kw))
-    params = m_ref.init(jax.random.key(0), tokens)["params"]
-    out_ref = m_ref.apply({"params": params}, tokens)
-    out_fused = m_fused.apply({"params": params}, tokens)  # same param tree
-    np.testing.assert_allclose(np.asarray(out_fused), np.asarray(out_ref),
-                               atol=2e-4, rtol=2e-4)
-
-
-def test_fused_ln_routing(monkeypatch):
-    # the routing itself, asserted directly (on the CPU test platform the
-    # interpret-mode pallas path would pass numerically either way):
-    # multi-device hosts must take the XLA reference — pallas_call cannot
-    # be GSPMD-partitioned, and in_shardings-sharded jits trace with an
-    # EMPTY abstract mesh, so only the device count is a reliable signal
-    from tensorflowonspark_tpu.models import transformer as tr_mod
-    from tensorflowonspark_tpu.ops import layernorm as ln_mod
-
-    ln = tr_mod.FusedLayerNorm()
-    x = jnp.asarray(np.random.RandomState(0).randn(8, 32), jnp.float32)
-    params = ln.init(jax.random.key(0), x)
-
-    def boom(*a, **kw):
-        raise AssertionError("pallas kernel selected on a multi-device host")
-
-    # this CPU test platform has 8 devices -> must not touch the kernel
-    monkeypatch.setattr(ln_mod, "fused_layernorm", boom)
-    out = ln.apply(params, x)
-    np.testing.assert_allclose(
-        np.asarray(out),
-        np.asarray(ln_mod.layernorm_reference(x, params["params"]["scale"],
-                                              params["params"]["bias"])),
-        atol=1e-5, rtol=1e-5)
-
-    # single-device host -> the kernel IS selected
-    called = []
-    monkeypatch.setattr(ln_mod, "fused_layernorm",
-                        lambda x, s, b, eps: called.append(1) or
-                        ln_mod.layernorm_reference(x, s, b, eps))
-    monkeypatch.setattr(tr_mod, "_single_device", lambda: True)
-    ln.apply(params, x)
-    assert called
 
 
 def test_transformer_flash_impl_matches_dense():

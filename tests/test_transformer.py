@@ -284,11 +284,6 @@ def test_rmsnorm_validation():
         Transformer(TransformerConfig(
             **{**CFG.__dict__, "norm_type": "welch"})).init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
-    with pytest.raises(ValueError, match="fused_ln"):
-        Transformer(TransformerConfig(
-            **{**CFG.__dict__, "norm_type": "rmsnorm",
-               "fused_ln": True})).init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
 
 
 def test_gated_moe_experts(toy_batch):
