@@ -31,6 +31,21 @@ outside the window are never fetched: the block index maps clamp to the
 window's own blocks, and a block index that does not change starts no
 copy.
 
+Where a head divides 128 (64, 32; no narrow key/value heads; `H*D` in whole
+lane tiles: `_heads_a_block`) the kernels read q, k, v and dO and write the
+output, dq, dk and dv in the layout the projections around attention
+produce and consume, `[B, S, H*D]`: a block is `block` rows by one tile of
+128 lanes, its last index the pair or four of heads that share the tile,
+told apart by a lane mask that follows a grid axis, so the body is no
+longer for it: a product that contracts 128 lanes of which the other head's
+are zero costs the MXU what a contraction over 64 does and adds zeros.  No
+`[B, H, S, D]` copy of any of them exists (each was one a kernel call, 64
+lanes padded to 128); `lse` and `delta`, which the kernels make and read,
+stay `[B, H, S, 128]`.  Every other shape is staged `[B*H, S, D]` by a
+transpose each way (`_heads_a_block` says why for each), and the process
+counters `flash.calls.packed` / `flash.calls.transposed` say which a traced
+kernel call took.
+
 Composes with ring attention (parallel/ring_attention.py): ring handles the
 cross-device sequence axis, this kernel the on-device blocks.
 
@@ -297,12 +312,40 @@ def _q_blocks_of(j, block_q, block_k, causal, window, nq):
     return clamp
 
 
+def _own(shape, head, head_dim):
+    """Which lanes of a `(rows, lanes)` tile of a block belong to its head
+    `head`; None where the block is one head."""
+    if shape[1] == head_dim:
+        return None
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // head_dim == head
+
+
+def _own_lanes(x, head, head_dim):
+    """`x` with the lanes of the block's other heads zeroed: a product that
+    contracts the lanes then sees this head alone, and one that keeps them
+    leaves the others' zero."""
+    own = _own(x.shape, head, head_dim)
+    return x if own is None else jnp.where(own, x, 0)
+
+
+def _put_head(ref, rows, val, head, head_dim):
+    """Write `val` into `ref[0, rows]`: into this head's lanes alone where
+    the block holds several (the others keep what their own turn wrote)."""
+    own = _own(val.shape, head, head_dim)
+    ref[0, rows, :] = val if own is None else jnp.where(own, val,
+                                                        ref[0, rows, :])
+
+
 def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
                 sm_scale, causal, sub, grid, patterns, seq_len, need_lse,
-                window=None):
-    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+                head_dim, window=None):
+    # grid (B, head blocks, nq, heads a block, nk): the output block does
+    # not move over the two inner axes, so each head of a lane block
+    # writes its own lanes before the block goes back once
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     (nq, nk), (tq, tk) = grid, sub
-    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
+    qi, ki = _grid_pos(2, nq), _grid_pos(4, nk)
+    head = _grid_pos(3, q_ref.shape[2] // head_dim)
     lse_ref = rest[0] if need_lse else None
     # where a row's keys all sit in one resident block the running state
     # is never revisited, and there is none: a strip writes its rows' output
@@ -310,7 +353,7 @@ def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
     def _write(rows, acc, m, l):
         l = jnp.maximum(l, 1e-30)
-        o_ref[0, 0, rows, :] = (acc / l).astype(o_ref.dtype)
+        _put_head(o_ref, rows, (acc / l).astype(o_ref.dtype), head, head_dim)
         if need_lse:
             # lse rows that saw no valid key (padding) get a finite sentinel
             # so the backward's exp(NEG_INF - lse) underflows to exactly 0
@@ -321,7 +364,7 @@ def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     if direct:
         if seq_len < nq * block_q:
             # padding rows no strip reaches still need defined values
-            _write(slice(None), jnp.zeros((block_q, q_ref.shape[3])),
+            _write(slice(None), jnp.zeros((block_q, q_ref.shape[2])),
                    jnp.full((block_q, 1), NEG_INF), jnp.zeros((block_q, 1)))
     else:
         m_scr, l_scr, acc_scr = rest[-3:]
@@ -335,9 +378,9 @@ def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     def _strip(r0, r1, span):
         rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
                                                     span[3] * tk)
-        q = q_ref[0, 0, rows, :].astype(jnp.float32)          # [Tq, D]
-        k = k_ref[0, 0, cols, :].astype(jnp.float32)          # [Tk, D]
-        v = v_ref[0, 0, cols, :].astype(jnp.float32)
+        q = _own_lanes(q_ref[0, rows, :], head, head_dim).astype(jnp.float32)
+        k = k_ref[0, cols, :].astype(jnp.float32)     # [Tk, lanes]
+        v = v_ref[0, cols, :].astype(jnp.float32)
         s = _strip_scores(q, k, sm_scale, qi * block_q + r0 * tq,
                           ki * block_k + span[0] * tk, 0, tk, span, seq_len,
                           causal, window)
@@ -367,10 +410,11 @@ def _fwd_kernel(kinds_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 
 def _bwd_dq_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                    dq_ref, dq_scr, *, sm_scale, causal, sub, grid, patterns,
-                   seq_len, window=None):
-    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+                   seq_len, head_dim, window=None):
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     (nq, nk), (tq, tk) = grid, sub
-    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
+    qi, ki = _grid_pos(2, nq), _grid_pos(4, nk)       # the forward's grid
+    head = _grid_pos(3, q_ref.shape[2] // head_dim)
 
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
@@ -380,10 +424,11 @@ def _bwd_dq_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     def _strip(r0, r1, span):
         rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
                                                     span[3] * tk)
-        q = q_ref[0, 0, rows, :].astype(jnp.float32)           # [Tq, D]
-        k = k_ref[0, 0, cols, :].astype(jnp.float32)           # [Tk, D]
-        v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        do = do_ref[0, 0, rows, :].astype(jnp.float32)         # [Tq, D]
+        q = _own_lanes(q_ref[0, rows, :], head, head_dim).astype(jnp.float32)
+        do = _own_lanes(do_ref[0, rows, :], head,
+                        head_dim).astype(jnp.float32)
+        k = k_ref[0, cols, :].astype(jnp.float32)     # [Tk, lanes]
+        v = v_ref[0, cols, :].astype(jnp.float32)
         lse = lse_ref[0, 0, rows, :1]                          # [Tq, 1]
         dlt = dlt_ref[0, 0, rows, :1]
         s = _strip_scores(q, k, sm_scale, qi * block_q + r0 * tq,
@@ -400,7 +445,8 @@ def _bwd_dq_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
 
     def _finish():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        _put_head(dq_ref, slice(None), dq_scr[:].astype(dq_ref.dtype), head,
+                  head_dim)
 
     _when(ki == nk - 1, _finish)
 
@@ -408,12 +454,14 @@ def _bwd_dq_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 def _bwd_dkv_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *,
                     sm_scale, causal, sub, grid, patterns, seq_len,
-                    window=None):
-    # grid (B, H_kv, nk, group, nq): dk/dv accumulate across the GQA
-    # group's q heads AND the q blocks before one narrow write — the
+                    head_dim, window=None):
+    # grid (B, kv head blocks, nk, members, nq): dk/dv accumulate across
+    # a block's members AND the q blocks before one narrow write — the
     # output block index is constant over both inner dims, so pallas
-    # keeps it resident until the last (g, qi) visit
-    block_q, block_k = q_ref.shape[2], k_ref.shape[2]
+    # keeps it resident until the last (g, qi) visit.  The members are
+    # the GQA group's q heads, or the heads of one lane block, each
+    # adding into its own lanes of dk and dv
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
     (nq, nk), (tq, tk) = grid, sub
     ki = _grid_pos(2, nk)
     g = pl.program_id(3)
@@ -429,10 +477,11 @@ def _bwd_dkv_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     def _strip(c0, c1, span):
         rows, cols = slice(span[0] * tq, span[3] * tq), slice(c0 * tk,
                                                               c1 * tk)
-        q = q_ref[0, 0, rows, :].astype(jnp.float32)           # [Tq, D]
-        k = k_ref[0, 0, cols, :].astype(jnp.float32)           # [Tk, D]
-        v = v_ref[0, 0, cols, :].astype(jnp.float32)
-        do = do_ref[0, 0, rows, :].astype(jnp.float32)
+        # where the members share a lane block, `g` is the head's place in it
+        q = _own_lanes(q_ref[0, rows, :], g, head_dim).astype(jnp.float32)
+        do = _own_lanes(do_ref[0, rows, :], g, head_dim).astype(jnp.float32)
+        k = k_ref[0, cols, :].astype(jnp.float32)     # [Tk, lanes]
+        v = v_ref[0, cols, :].astype(jnp.float32)
         # keys down the rows, queries along the lanes: the two products
         # that accumulate dk and dv contract the lanes as they lie, and no
         # [Tq, Tk] tile is transposed
@@ -455,25 +504,108 @@ def _bwd_dkv_kernel(kinds_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
     _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
 
     def _finish():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
     pl.when(jnp.logical_and(g == ng - 1, qi == nq - 1))(_finish)
 
 
-def _pad_seq(x, block):
-    """Pad the sequence axis of [B, H, S, D] up to a multiple of `block`
-    (a pad of zero rows is no operation)."""
-    return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block), (0, 0)))
+# ---- how the kernels see [B, S, H, D] ----------------------------------------
+#
+# The projections around attention produce and consume `[B, S, H*D]`, and a
+# kernel can take a head out of that layout by a block of lanes: no copy
+# of q, k, v, dO, the output or a gradient is made for its sake.  Which
+# shapes allow it is `_heads_a_block`; the others are staged
+# `[B*H, S, D]`, a transposed copy each way, as every shape once was.
+
+def _heads_a_block(n_heads, n_kv_heads, head_dim):
+    """Heads in one block of lanes of the `[B, S, H*D]` layout, or None
+    where the shapes need the transposed staging.
+
+    A block's last dimension has to be whole lane tiles (Mosaic refuses a
+    block of 1 over the heads axis of `[B, S, H, D]`).  A head that divides
+    128 shares a tile with its neighbours, told apart inside the kernel by
+    a lane mask, which needs `H*D` in whole tiles and, since a query head
+    and its key/value head must then lie in the same lanes, no narrow
+    key/value heads.  Any other head size (96, 80) straddles the tiles.
+    A head of 128 or a multiple would be a block by itself, and the
+    kernels take it (`pack` 1: it lowers, and the attention sublayer runs
+    on the chip that way at the sparse-expert cell's shapes), but that
+    cell's whole step stopped on the chip with it and the cause is not
+    known (PERF.md section 7, PR 31): until it is, such a head keeps the
+    transposed staging, where the padding to 128 lanes costs it nothing."""
+    if (head_dim < _LANES and _LANES % head_dim == 0
+            and n_kv_heads == n_heads and (n_heads * head_dim) % _LANES == 0):
+        return _LANES // head_dim
+    return None
+
+
+def _stage(x, block, pack):
+    """`[B, S, H, D]` as the kernels index it, the sequence padded up to a
+    multiple of `block` (a pad of zero rows is no operation): the view
+    `[B, S, H*D]` where `pack` heads share a lane block, a transposed
+    `[B*H, S, D]` where it is None."""
+    B, S, H, D = x.shape
+    x = (x.reshape(B, S, H * D) if pack
+         else x.transpose(0, 2, 1, 3).reshape(B * H, S, D))
+    return jnp.pad(x, ((0, 0), (0, (-S) % block), (0, 0)))
+
+
+def _unstage(x, shape, pack):
+    """A kernel's result back as `shape` = `(B, S, H, D)`."""
+    B, S, H, D = shape
+    if pack:
+        return x[:, :S].reshape(shape)
+    return x[:, :S].reshape(B, H, S, D).transpose(0, 2, 1, 3)
+
+
+def _head_rows(block, n_heads, head_dim, pack, index):
+    """BlockSpec of `block` rows of one head of a staged operand;
+    `index(*grid ids)` gives `(batch, head, row block)`."""
+    if pack:
+        def at(*ids):
+            b, h, i = index(*ids)
+            return (b, i, h // pack)
+        return pl.BlockSpec((1, block, head_dim * pack), at)
+
+    def at(*ids):
+        b, h, i = index(*ids)
+        return (b * n_heads + h, i, 0)
+    return pl.BlockSpec((1, block, head_dim), at)
+
+
+def _by_queries(q, k, n_blocks, blocks, causal, window, pack):
+    """The grid the forward and dq walk, `(B, head blocks, nq, heads a
+    block, nk)`, with the BlockSpecs of a head's query rows, of the key
+    rows it sees and of its lane-replicated row statistics (`lse`,
+    `delta`).  Narrow kv blocks are indexed by the q head's GROUP: no
+    repeated kv ever materializes in HBM (the GQA bandwidth win)."""
+    (B, _, H, D), H_kv = q.shape, k.shape[2]
+    (nq, nk), (block_q, block_k) = n_blocks, blocks
+    group, per = H // H_kv, pack or 1     # `per` heads a block of lanes
+
+    def kv_index(b, hb, i, hh, j, _):
+        seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
+        return (b, (hb * per + hh) // group, seen(j))
+
+    q_spec = _head_rows(block_q, H, D, pack,
+                        lambda b, hb, i, hh, j, _: (b, hb * per + hh, i))
+    kv_spec = _head_rows(block_k, H_kv, D, pack, kv_index)
+    stat_spec = pl.BlockSpec(
+        (1, 1, block_q, _LANES),
+        lambda b, hb, i, hh, j, _: (b, hb * per + hh, i, 0))
+    return (B, H // per, nq, per, nk), q_spec, kv_spec, stat_spec
 
 
 def _scheduled_call(kernel, name, n_blocks, blocks, seq_len, causal, window,
-                    interpret, out_shape, **grid_spec):
+                    interpret, pack, out_shape, **grid_spec):
     """`pl.pallas_call` of one of the three kernels with its schedule: the
     table of block kinds rides in as a scalar-prefetch operand (the index
     maps take it as a last argument and ignore it)."""
     sub = _pick_subtile(*blocks)
     _count_subtiles(seq_len, *blocks, sub, causal, window)
+    trace.counters().inc(
+        "flash.calls.packed" if pack else "flash.calls.transposed")
     kinds, patterns = _schedule(n_blocks, blocks, sub, seq_len, causal,
                                 window, by_keys=name != "flash_dkv")
     body = functools.partial(
@@ -502,61 +634,60 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     entirely — pallas outputs can't be dead-code-eliminated, so an unused
     lse would cost real HBM writes on every inference forward."""
     B, S, H, D = q.shape
-    group = H // k.shape[2]   # GQA: q heads per (narrow) kv head
-    qt = _pad_seq(q.transpose(0, 2, 1, 3), block_q)
-    kt = _pad_seq(k.transpose(0, 2, 1, 3), block_k)
-    vt = _pad_seq(v.transpose(0, 2, 1, 3), block_k)
-    Sq, Sk = qt.shape[2], kt.shape[2]
+    pack = _heads_a_block(H, k.shape[2], D)
+    qs, ks, vs = (_stage(x, block, pack)
+                  for x, block in ((q, block_q), (k, block_k), (v, block_k)))
+    Sq, Sk = qs.shape[1], ks.shape[1]
     nq, nk = Sq // block_q, Sk // block_k
-
-    o_spec = pl.BlockSpec((1, 1, block_q, D),
-                          lambda b, h, i, j, _: (b, h, i, 0))
-    lse_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                            lambda b, h, i, j, _: (b, h, i, 0))
-    # narrow kv blocks are indexed by the q head's GROUP — no repeated
-    # kv ever materializes in HBM (the GQA bandwidth win, kept here)
-    def kv_index(b, h, i, j, _):
-        seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
-        return (b, h // group, seen(j), 0)
-
-    kv_spec = pl.BlockSpec((1, 1, block_k, D), kv_index)
+    grid, o_spec, kv_spec, lse_spec = _by_queries(
+        q, k, (nq, nk), (block_q, block_k), causal, window, pack)
     result = _scheduled_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, need_lse=need_lse),
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, need_lse=need_lse,
+                          head_dim=D),
         "flash_fwd", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret,
-        grid=(B, H, nq, nk),
+        interpret, pack,
+        grid=grid,
         in_specs=[o_spec, kv_spec, kv_spec],
         out_specs=[o_spec] + ([lse_spec] if need_lse else []),
-        out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype)] + (
+        out_shape=[jax.ShapeDtypeStruct(qs.shape, q.dtype)] + (
             [jax.ShapeDtypeStruct((B, H, Sq, _LANES), jnp.float32)]
             if need_lse else []),
         # running max, denominator, accumulator: none where nk is 1
         scratch_shapes=[] if nk == 1 else [
             _scratch((block_q, _LANES)),
             _scratch((block_q, _LANES)),
-            _scratch((block_q, D)),
+            _scratch(o_spec.block_shape[1:]),
         ],
-    )(qt, kt, vt)
-    out = result[0][:, :, :S].transpose(0, 2, 1, 3)
-    return out, (result[1] if need_lse else None)
+    )(qs, ks, vs)
+    return (_unstage(result[0], q.shape, pack),
+            result[1] if need_lse else None)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                     interpret, g_lse=None, window=None):
     B, S, H, D = q.shape
-    group = H // k.shape[2]   # GQA: q heads per (narrow) kv head
     H_kv = k.shape[2]
-    qt = _pad_seq(q.transpose(0, 2, 1, 3), block_q)
-    kt = _pad_seq(k.transpose(0, 2, 1, 3), block_k)
-    vt = _pad_seq(v.transpose(0, 2, 1, 3), block_k)
-    dot = _pad_seq(g.transpose(0, 2, 1, 3), block_q)
-    Sq, Sk = qt.shape[2], kt.shape[2]
+    group = H // H_kv         # GQA: q heads per (narrow) kv head
+    pack = _heads_a_block(H, H_kv, D)
+    per = pack or 1           # heads a grid step's blocks hold
+    qs, ks, vs, dos = (
+        _stage(x, block, pack) for x, block in (
+            (q, block_q), (k, block_k), (v, block_k), (g, block_q)))
+    Sq, Sk = qs.shape[1], ks.shape[1]
     nq, nk = Sq // block_q, Sk // block_k
 
-    # delta = rowsum(dO * O): [B, H, Sq] — O(B·S·H·D) elementwise, jax-side
-    delta = jnp.einsum("bshd,bshd->bhs", g.astype(jnp.float32),
-                       out.astype(jnp.float32))
+    # delta = rowsum(dO * O): [B, H, Sq] — O(B·S·H·D) elementwise, jax-side.
+    # The sum over a head's lanes is a product with the heads' 0/1 indicator
+    # (exact at the highest precision: every term is x * 1): it reads dO
+    # and O as `[B, S, H*D]`, where a reduction over the last axis of
+    # `[B, S, H, D]` would have XLA lay both out anew in float32 first
+    prod = (g.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        B, S, H * D)
+    of_head = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
+        jnp.float32)
+    delta = jnp.einsum("bsk,kh->bhs", prod, of_head,
+                       precision=jax.lax.Precision.HIGHEST)
     # an lse cotangent folds exactly into delta: ds_ij = p_ij*(dp_ij -
     # delta_i + g_lse_i), since dlse_i/ds_ij = p_ij
     if g_lse is not None:
@@ -566,57 +697,52 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
     delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
 
-    def k_index(b, h, i, j, _):
-        seen = _k_blocks_of(i, block_q, block_k, causal, window, nk)
-        return (b, h // group, seen(j), 0)
-
-    q_spec = pl.BlockSpec((1, 1, block_q, D),
-                          lambda b, h, i, j, _: (b, h, i, 0))
-    k_spec = pl.BlockSpec((1, 1, block_k, D), k_index)
-    r_spec = pl.BlockSpec((1, 1, block_q, _LANES),
-                          lambda b, h, i, j, _: (b, h, i, 0))
-
+    grid, q_spec, k_spec, r_spec = _by_queries(
+        q, k, (nq, nk), (block_q, block_k), causal, window, pack)
     dq = _scheduled_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale),
+        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, head_dim=D),
         "flash_dq", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret,
-        grid=(B, H, nq, nk),
+        interpret, pack,
+        grid=grid,
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        scratch_shapes=[_scratch((block_q, D))],
-    )(qt, kt, vt, dot, lse, delta)
+        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
+        scratch_shapes=[_scratch(q_spec.block_shape[1:])],
+    )(qs, ks, vs, dos, lse, delta)
 
-    # swap grid roles: (b, kv-head, k-block, group-member, q-block) —
-    # q innermost; dk/dv come out NARROW, accumulated across the group
-    # (the narrow output replaces the former repeat-then-sum cotangent)
-    def q_index(b, kh, j, g, i, _):
+    # swap grid roles: (b, kv head block, k-block, member, q-block), q
+    # innermost; a member is a q head of the kv head's GQA group or a head
+    # of the lane block (never both: `_heads_a_block`).  dk/dv come out
+    # NARROW, accumulated across the members (the narrow output replaces
+    # the former repeat-then-sum cotangent)
+    def q_index(b, kb, j, g, i, _):
         seeing = _q_blocks_of(j, block_q, block_k, causal, window, nq)
-        return (b, kh * group + g, seeing(i), 0)
+        return (b, kb * group * per + g, seeing(i))
 
-    qk_spec = pl.BlockSpec((1, 1, block_q, D), q_index)
-    kk_spec = pl.BlockSpec((1, 1, block_k, D),
-                           lambda b, kh, j, g, i, _: (b, kh, j, 0))
+    qk_spec = _head_rows(block_q, H, D, pack, q_index)
+    kk_spec = _head_rows(block_k, H_kv, D, pack,
+                         lambda b, kb, j, g, i, _: (b, kb * per, j))
 
-    def q_lane_index(*args):
-        b, h, i, _ = q_index(*args)
+    def q_lane_index(*ids):
+        b, h, i = q_index(*ids)
         return (b, h, 0, i)
 
     rk_spec = pl.BlockSpec((1, 1, 1, block_q), q_lane_index)
     dk, dv = _scheduled_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale),
+        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, head_dim=D),
         "flash_dkv", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret,
-        grid=(B, H_kv, nk, group, nq),
+        interpret, pack,
+        grid=(B, H_kv // per, nk, group * per, nq),
         in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
         out_specs=[kk_spec, kk_spec],
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
-        scratch_shapes=[_scratch((block_k, D)), _scratch((block_k, D))],
-    )(qt, kt, vt, dot, lse_t, delta_t)
+        out_shape=[jax.ShapeDtypeStruct(ks.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vs.shape, v.dtype)],
+        scratch_shapes=[_scratch(kk_spec.block_shape[1:]),
+                        _scratch(kk_spec.block_shape[1:])],
+    )(qs, ks, vs, dos, lse_t, delta_t)
 
-    tr = lambda x, s: x[:, :, :s].transpose(0, 2, 1, 3)
-    return tr(dq, S), tr(dk, S), tr(dv, S)
+    return (_unstage(dq, q.shape, pack), _unstage(dk, k.shape, pack),
+            _unstage(dv, v.shape, pack))
 
 
 def attention_reference(q, k, v, causal=True, sm_scale=None, window=None):
@@ -719,7 +845,9 @@ def _resolve_call_args(q, k, sm_scale, block_q, block_k, interpret):
     not measured again; the sub-tile was, on the v5e (PERF.md section 6, PR
     29).  A 1024x1024 strip of float32 scores is 4 MB of v5e-class ~128MB
     VMEM; pre-v4 generations with small VMEM may need block sizes passed
-    explicitly."""
+    explicitly.  A block is that many rows of ONE head (of the heads of
+    one lane tile in turn, `_heads_a_block`), whichever way the operands
+    are staged."""
     if q.shape[2] % k.shape[2]:
         raise ValueError(
             f"q heads {q.shape[2]} must be a multiple of kv heads "
@@ -758,7 +886,9 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
     are indexed per q-head group inside the kernel, so the repeated k/v
     (and the repeat's summed cotangent) never materialize in HBM.
     Sequence lengths need not be multiples of the block sizes (padded rows
-    and keys are masked out of both passes).  `interpret=None`
+    and keys are masked out of both passes).  The arguments are read where
+    they lie: reshaped from a projection's `[B, S, H*D]` they cost no copy
+    (`_heads_a_block` says for which shapes).  `interpret=None`
     auto-selects: native Mosaic on TPU, interpreter elsewhere (the CPU test
     mesh).
     """

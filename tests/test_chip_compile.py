@@ -19,6 +19,7 @@ worker collects the same tests and only the one handed this file loads
 the library), and every compile runs in the test's own process.
 """
 import functools
+import math
 import os
 import re
 import sys
@@ -101,7 +102,9 @@ def _kernels(text):
 # flagship's head of 128, and GPT-2 large as `gpt2-large.fed_b8` runs it,
 # one resident block of 1024 a head walked in sub-tiles at a head of 64
 FLASH_SHAPES = {"flagship": (B, S, H, N_KV, DH),
-                "gpt2-large": (8, 1024, 20, 20, 64)}
+                "gpt2-large": (8, 1024, 20, 20, 64),
+                # an odd head count at 64: the transposed staging
+                "odd-heads": (2, 1024, 3, 3, 64)}
 
 
 def _qkv(chip, shape="flagship"):
@@ -145,6 +148,50 @@ def test_flash_with_a_window_lowers(chip):
            chip((1, 8192, 4, 128), jnp.bfloat16))
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
     assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
+# (batch, sequence, query heads, key/value heads, head size, model width,
+# window): the attention sublayer of `gpt2-large.fed_b8`, and two more
+# shapes of two and four heads a lane block, longer than one resident block
+SUBLAYERS = {"gpt2-large": (8, 1024, 20, 20, 64, 1280, None),
+             "heads-of-64-s4k": (2, 4096, 16, 16, 64, 1024, None),
+             "heads-of-32-window": (2, 4096, 32, 32, 32, 1024, 1024)}
+
+
+@pytest.mark.parametrize("shape", list(SUBLAYERS))
+def test_attention_sublayer_stages_no_copy_of_its_heads(chip, shape):
+    """Projection -> `flash_attention` -> output projection, forward and
+    gradient: the kernels index q, k, v, dO and write the output, dq, dk,
+    dv as the projections' own `[B, S, H*D]`, so the compiled sublayer
+    holds no copy or transpose the size of any of them (each was one a
+    kernel call, a head of 64 padded to 128 lanes, when the kernels took
+    `[B, H, S, D]`)."""
+    b, s, h, h_kv, dh, d, window = SUBLAYERS[shape]
+
+    def loss(x, wq, wk, wv, wo):
+        q = (x @ wq).reshape(b, s, h, dh)
+        k = (x @ wk).reshape(b, s, h_kv, dh)
+        v = (x @ wv).reshape(b, s, h_kv, dh)
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
+        y = out.reshape(b, s, h * dh) @ wo
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    chip((b, s, d), jnp.bfloat16),
+                    chip((d, h * dh), jnp.bfloat16),
+                    chip((d, h_kv * dh), jnp.bfloat16),
+                    chip((d, h_kv * dh), jnp.bfloat16),
+                    chip((h * dh, d), jnp.bfloat16))
+    assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    sizes = {b * s * h * dh, b * s * h_kv * dh}
+    staged = [
+        line.strip()[:120]
+        for line in text[text.index("\nENTRY"):].splitlines()
+        for m in [re.match(r"\s*(?:ROOT )?%[\w.-]+ = bf16\[([\d,]+)\]\S* "
+                           r"(?:copy|transpose)\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) in sizes]
+    assert not staged, staged
 
 
 def test_grouped_matmul_lowers(chip):
