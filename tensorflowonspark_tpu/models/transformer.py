@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tensorflowonspark_tpu import trace
 from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
 from tensorflowonspark_tpu.ops.paged_attention import paged_attention
 from tensorflowonspark_tpu.ops.paged_prefill import paged_prefill
@@ -44,10 +45,14 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     head_dim: Optional[int] = None  # None = d_model // n_heads; set where
     # n_heads * head_dim is not d_model (out is [n_heads*head_dim, d_model])
-    layer_types: Optional[tuple] = None  # one kind a layer, "full_attention"
-    # or "sliding_attention" (None = all full); a sliding layer sees the
-    # last `sliding_window` keys only and rotates by `rope_local_theta`
+    layer_types: Optional[tuple] = None  # one kind a layer, "full_attention",
+    # "sliding_attention" or "conv" (None = all full); a sliding layer sees
+    # the last `sliding_window` keys only and rotates by `rope_local_theta`;
+    # a conv layer mixes the sequence with `ShortConv`, not attention
     sliding_window: Optional[int] = None
+    conv_kernel: int = 3          # taps of a conv layer's depthwise filter
+    qk_norm: bool = False         # RMSNorm over head_dim on every query and
+    # key head (a scale of head_dim each), before the rotation
     rope_local_theta: Optional[float] = None  # sliding layers' base (plain
     # rotary; None = rope_theta)
     rope_yarn_factor: float = 1.0  # full layers: YaRN scaling of rope_theta's
@@ -66,11 +71,22 @@ class TransformerConfig:
     moe_top_k: int = 1            # experts per token under the topk router
     moe_capacity_factor: float = 1.25  # per-expert slots = factor*k*T/E
     moe_d_ff: Optional[int] = None  # an expert's width (None = d_ff)
+    moe_dense_layers: int = 0     # the first n layers keep the dense MLP
+    # (of width d_ff) whatever `moe_every` says of them
+    moe_scoring: str = "softmax"  # dropless: softmax over the router's
+    # logits | sigmoid of each (weights over their sum + 1e-6)
+    moe_expert_bias: bool = False  # dropless: a leaf `expert_bias` [E] is
+    # added to the scores for the CHOICE of the k experts only; the weights
+    # come from the scores alone, so its gradient is zero and an optimizer
+    # without weight decay leaves it where it is
     moe_experts_held: Optional[int] = None  # dropless: this chip's share of
     # an expert-parallel layer: experts [offset, offset + held) live here,
     # the router scores all `num_experts`, and what the absent experts
     # would have added is left out of the layer's output (None = all)
     moe_expert_offset: int = 0
+    tie_embeddings: bool = False  # the head reads the embedding's table
+    # (`x @ E^T`): no `lm_head` leaf; under `return_hidden` the caller
+    # hands `E^T` to ops.xent.fused_unembed_xent
     remat: bool = False
     ring_attention_axis: Optional[str] = None  # e.g. "tp" to enable CP
     ulysses_axis: Optional[str] = None  # all-to-all sequence parallelism
@@ -154,21 +170,29 @@ class TransformerConfig:
             raise ValueError(f"layer_types {sorted(bad)} not in {LAYER_KINDS}")
         if SLIDING in kinds and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
+        if CONV in kinds and (self.ring_attention_axis or self.ulysses_axis):
+            raise NotImplementedError(
+                "conv layers shift along a sequence that is whole on the "
+                "device: not with sequence-parallel attention")
         missing = [name for name, on in (
             ("layer_types", bool(kinds)),
             ("sliding_window", bool(self.sliding_window)),
+            ("qk_norm", self.qk_norm),
             ("moe_experts_held", self.moe_experts_held is not None),
+            ("moe_scoring='sigmoid'", self.moe_scoring == "sigmoid"),
+            ("moe_expert_bias", self.moe_expert_bias),
             ("moe_router='dropless'", self.moe_router == "dropless"
              and self.num_experts > 0)) if on]
         if self.decode and missing:
             raise NotImplementedError(
                 f"decode=True with {', '.join(missing)}: the kv cache keeps "
-                "no window, its incremental attention no layer kinds, and "
+                "no window and no conv layer's last rows, its incremental "
+                "attention no layer kinds and no query/key norms, and "
                 "routing has no incremental form here (ROADMAP R1/R5)")
 
 
-FULL, SLIDING = "full_attention", "sliding_attention"
-LAYER_KINDS = (FULL, SLIDING)
+FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
+LAYER_KINDS = (FULL, SLIDING, CONV)
 
 
 def rope_inv_freq(head_dim, theta, yarn_factor=1.0, original_max=0,
@@ -327,6 +351,7 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None):
         cfg = self.cfg
+        trace.counters().inc("mixer.calls.attention")   # once a traced call
         dtype = jnp.dtype(cfg.dtype)
         head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
         sliding = self.layer_type == SLIDING
@@ -345,6 +370,11 @@ class Attention(nn.Module):
         q = q.reshape(B, S, cfg.n_heads, head_dim)
         k = k.reshape(B, S, n_kv, head_dim)
         v = v.reshape(B, S, n_kv, head_dim)
+        if cfg.qk_norm:     # per head, each with its own scale of head_dim
+            q = nn.RMSNorm(name="q_norm", dtype=jnp.float32,
+                           epsilon=cfg.ln_eps)(q).astype(dtype)
+            k = nn.RMSNorm(name="k_norm", dtype=jnp.float32,
+                           epsilon=cfg.ln_eps)(k).astype(dtype)
         decoding = cfg.decode and (
             self.has_variable("cache", "cached_key")
             or self.has_variable("cache", "pages_key"))
@@ -569,6 +599,42 @@ class Attention(nn.Module):
             logits = jnp.where(visible[None, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
+
+
+class ShortConv(nn.Module):
+    """Gated short convolution, a conv layer's sequence mixer (LFM2):
+    `[b, c, z] = split3(in_proj(x))`, `g = b * z`, a causal depthwise
+    filter of `conv_kernel` taps over `g` (`taps` [D, L], one tap vector a
+    channel, the last tap on the position itself, zeros left of the row's
+    start), `out_proj(c * s)`.  The filter is L shifted multiply-adds over
+    [B, S, D] in the activation type: a depthwise convolution of D groups
+    is no MXU work, and XLA fuses the sum with the two gates."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        cfg = self.cfg
+        if mask is not None:
+            raise NotImplementedError(
+                "key-padding masks are not supported in conv layers")
+        trace.counters().inc("mixer.calls.conv")        # once a traced call
+        dtype = jnp.dtype(cfg.dtype)
+        D, L = cfg.d_model, cfg.conv_kernel
+        S = x.shape[1]
+
+        def proj(name, features, h):
+            return QuantDense(features, use_bias=cfg.use_bias, name=name,
+                              dtype=dtype, impl=cfg.quant_matmul_impl)(h)
+
+        b, c, z = jnp.split(proj("in_proj", 3 * D, x), 3, axis=-1)
+        taps = self.param("taps", nn.initializers.lecun_normal(),
+                          (D, L), jnp.float32).astype(dtype)
+        g = b * z
+        s = g * taps[:, L - 1]
+        for back in range(1, min(L, S)):     # position t reads t - back
+            s = s + jnp.pad(g[:, :S - back],
+                            ((0, 0), (back, 0), (0, 0))) * taps[:, L - 1 - back]
+        return proj("out_proj", D, c * s)
 
 
 def _kv_quantize(x):
@@ -1009,7 +1075,7 @@ _moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
 
 
 MOE_COUNTERS = ("moe.pairs.local", "moe.pairs.absent", "moe.load.max",
-                "moe.load.mean")
+                "moe.load.mean", "moe.picks.moved", "moe.picks.kept")
 
 
 def moe_stats(intermediates):
@@ -1017,7 +1083,10 @@ def moe_stats(intermediates):
     sowed (`moe_stats`, under `mutable=["intermediates"]`): `moe.pairs.local`
     and `moe.pairs.absent` (picks that fell on held and on absent experts),
     `moe.load.max` and `moe.load.mean` (tokens of the fullest held expert
-    and of the mean one), each summed over the layers.  A loss function
+    and of the mean one), `moe.picks.moved` and `moe.picks.kept` (picks of
+    top-k(score + bias) that are not, and that are, among top-k(score): 0
+    and all of them where there is no selection bias), each summed over the
+    layers.  A loss function
     returns them as its aux metrics and names them in its `counters`
     (`MOE_COUNTERS`): `parallel.train.make_train_step` then adds each step's
     to `trace.counters()` with no sync, and once a step (what the
@@ -1073,10 +1142,20 @@ class MoEMLP(nn.Module):
         if cfg.moe_experts_held is not None and not dropless:
             raise ValueError("moe_experts_held (a share of the experts) "
                              "needs moe_router='dropless'")
+        if cfg.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring={cfg.moe_scoring!r} not in "
+                             "('softmax', 'sigmoid')")
+        if not dropless and (cfg.moe_scoring != "softmax"
+                             or cfg.moe_expert_bias):
+            raise ValueError("moe_scoring and moe_expert_bias need "
+                             "moe_router='dropless'")
         gate_logits = QuantDense(E, use_bias=False, name="router",
                                  impl=cfg.quant_matmul_impl)(
             x.astype(jnp.float32))
-        probs = jax.nn.softmax(gate_logits, axis=-1)
+        probs = (jax.nn.sigmoid(gate_logits) if cfg.moe_scoring == "sigmoid"
+                 else jax.nn.softmax(gate_logits, axis=-1))
+        bias = (self.param("expert_bias", nn.initializers.zeros, (E,),
+                           jnp.float32) if cfg.moe_expert_bias else None)
 
         def experts(name, shape):
             if dropless:
@@ -1097,7 +1176,7 @@ class MoEMLP(nn.Module):
         if dropless:
             # no balancing loss is sown here: the counters say how the
             # load fell (`moe_stats`)
-            return self._dropless_route(x, probs, wi, up, wo)
+            return self._dropless_route(x, probs, bias, wi, up, wo)
 
         def expert_mlp(xe):
             """xe: [E, ..., D] -> [E, ..., D], batched over the expert dim."""
@@ -1123,9 +1202,11 @@ class MoEMLP(nn.Module):
         self.sow("intermediates", "moe_aux_loss", aux)
         return y
 
-    def _dropless_route(self, x, probs, wi, up, wo):
+    def _dropless_route(self, x, probs, bias, wi, up, wo):
         """Top-k routing over all `num_experts`, computed for the experts
-        held here.  Of a token's k picks those that fall on held experts
+        held here.  The k experts are chosen by `probs + bias` where there
+        is a selection bias, and weighted by `probs` alone, over their sum.
+        Of a token's k picks those that fall on held experts
         are sorted by expert, run through the grouped matmul, weighted
         and summed back per token; the static row buffer is sized for the
         worst case (every pick held), and the kernel does no work for the
@@ -1145,8 +1226,19 @@ class MoEMLP(nn.Module):
                              f"the {E} the router scores")
         T = B * S
         xt = x.reshape(T, D).astype(jnp.dtype(cfg.dtype))
-        topk_p, topk_idx = jax.lax.top_k(probs.reshape(T, E), k)   # f32
-        if k > 1:      # weights renormalised over the picks, as `topk` does
+        scores = probs.reshape(T, E)                               # f32
+        topk_p, topk_idx = jax.lax.top_k(scores, k)
+        moved = jnp.int32(0)
+        if bias is not None:
+            unbiased = topk_idx
+            _, topk_idx = jax.lax.top_k(scores + bias, k)
+            topk_p = jnp.take_along_axis(scores, topk_idx, axis=-1)
+            moved = jnp.sum(~jnp.any(
+                topk_idx[:, :, None] == unbiased[:, None, :], axis=-1))
+        if cfg.moe_scoring == "sigmoid":
+            topk_p = topk_p / (jnp.sum(topk_p, axis=-1, keepdims=True)
+                               + 1e-6)
+        elif k > 1:    # weights renormalised over the picks, as `topk` does
             topk_p = topk_p / jnp.maximum(
                 jnp.sum(topk_p, axis=-1, keepdims=True), 1e-9)
         local = (topk_idx >= off) & (topk_idx < off + held)        # [T, k]
@@ -1166,8 +1258,8 @@ class MoEMLP(nn.Module):
         y = _moe_combine(out, topk_p, order, pos, local)
         n_local = jnp.sum(sizes)
         self.sow("intermediates", "moe_stats", jnp.stack([
-            n_local, T * k - n_local, jnp.max(sizes),
-            n_local / held]).astype(jnp.float32))      # as MOE_COUNTERS
+            n_local, T * k - n_local, jnp.max(sizes), n_local / held,
+            moved, T * k - moved]).astype(jnp.float32))  # as MOE_COUNTERS
         # the choices themselves, for a look at how rounding moves them
         # (`benchmark/tests/moe_routes.py`); unread, they cost nothing
         self.sow("intermediates", "moe_picks", topk_idx)
@@ -1292,7 +1384,8 @@ class Block(nn.Module):
                 f"norm_style={cfg.norm_style!r} not in ('pre', 'post')")
         ln1 = _make_ln(cfg, "ln1")
         ln2 = _make_ln(cfg, "ln2")
-        attn = Attention(cfg, self.layer_type, name="attn")
+        attn = (ShortConv(cfg, name="conv") if self.layer_type == CONV
+                else Attention(cfg, self.layer_type, name="attn"))
         mlp = (MoEMLP(cfg, name="moe") if self.use_moe
                else DenseMLP(cfg, name="mlp"))
         x = _sp_constrain(x, cfg)
@@ -1314,12 +1407,14 @@ class Transformer(nn.Module):
         """Token ids -> logits; ``return_hidden=True`` returns the post-ln_f
         hidden states instead, for losses that fuse the unembedding matmul
         (ops.xent.fused_unembed_xent) — the lm_head params still exist and
-        receive their gradient through the fused op."""
+        receive their gradient through the fused op (with `tie_embeddings`
+        the head is the embedding's table transposed, and the table gets
+        the sum of both uses' gradients)."""
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
-                     dtype=dtype)(tokens)
-        x = _embed_out_constrain(x, cfg)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
+                         dtype=dtype)
+        x = _embed_out_constrain(embed(tokens), cfg)
         if not cfg.rope:  # RoPE rotates q/k inside attention instead
             pos_ids = jnp.arange(tokens.shape[1])
             if cfg.decode:
@@ -1345,15 +1440,19 @@ class Transformer(nn.Module):
             block_cls = nn.remat(Block)
         for i in range(cfg.n_layers):
             # every k-th layer is MoE, counting so that moe_every=1 means
-            # every layer (k=2 keeps the old odd-layer placement)
-            use_moe = cfg.num_experts > 0 and (
-                i % cfg.moe_every == cfg.moe_every - 1)
+            # every layer (k=2 keeps the old odd-layer placement); the
+            # first `moe_dense_layers` stay dense
+            use_moe = (cfg.num_experts > 0 and i >= cfg.moe_dense_layers
+                       and i % cfg.moe_every == cfg.moe_every - 1)
             kind = cfg.layer_types[i] if cfg.layer_types else FULL
             x = block_cls(cfg, use_moe=use_moe, layer_type=kind,
                           name=f"layer_{i}")(x)
         x = _make_ln(cfg, "ln_f")(x)
-        if return_hidden and not self.is_initializing():
+        if return_hidden and (cfg.tie_embeddings
+                              or not self.is_initializing()):
             return x.astype(dtype)
+        if cfg.tie_embeddings:
+            return embed.attend(x.astype(dtype))
         logits = QuantDense(cfg.vocab_size, use_bias=False, name="lm_head",
                             dtype=dtype, impl=cfg.quant_matmul_impl)(x)
         if return_hidden:

@@ -70,13 +70,19 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     in the step, no sync in the loop): moe.pairs.local  moe.pairs.absent
     (a token's picks that fell on experts held here, and on absent ones)
     moe.load.max  moe.load.mean (tokens of the fullest held expert and of
-    the mean one; all four summed over layers and steps)
+    the mean one)  moe.picks.moved  moe.picks.kept (picks that a
+    selection bias took from, and left among, the k largest scores; all
+    six summed over layers and steps)
     counters of a process that traces flash attention
     (``ops.flash_attention``, added on the host each time a kernel call is
     traced, so per compiled program and not per step):
     flash.subtiles.computed  flash.subtiles.masked  flash.subtiles.square
     (sub-tiles a head computes, those of them that carry the mask
     arithmetic, and those the padded square holds)
+    flash.calls.packed  flash.calls.transposed (kernel calls by layout);
+    and of one that traces a transformer block
+    (``models.transformer``, the same way): mixer.calls.attention
+    mixer.calls.conv (what a step program's sequence mixers are)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

@@ -104,7 +104,10 @@ def _kernels(text):
 FLASH_SHAPES = {"flagship": (B, S, H, N_KV, DH),
                 "gpt2-large": (8, 1024, 20, 20, 64),
                 # an odd head count at 64: the transposed staging
-                "odd-heads": (2, 1024, 3, 3, 64)}
+                "odd-heads": (2, 1024, 3, 3, 64),
+                # the attention layer of `lfm2-8b-a1b.fed_s8k_b2`: a head
+                # of 64 under narrow key/value heads, staged transposed
+                "lfm2-8b-a1b": (2, 8192, 32, 8, 64)}
 
 
 def _qkv(chip, shape="flagship"):
@@ -194,11 +197,19 @@ def test_attention_sublayer_stages_no_copy_of_its_heads(chip, shape):
     assert not staged, staged
 
 
-def test_grouped_matmul_lowers(chip):
-    """The expert products of the same cell, forward and both gradients:
-    rows for the worst routing (2 x 8192 tokens x 8 picks), 16 held experts
-    of 2304 x 896 and back."""
+# (rows for the worst routing, held experts, model width, expert width):
+# `mellum2-12b-a2.5b.fed_s8k_b2` (2 x 8192 tokens x 8 picks) and
+# `lfm2-8b-a1b.fed_s8k_b2` (2 x 8192 tokens x 4 picks)
+EXPERTS = {"mellum2-12b-a2.5b": (131072, 16, 2304, 896),
+           "lfm2-8b-a1b": (65536, 8, 2048, 1792)}
+
+
+@pytest.mark.parametrize("cell", list(EXPERTS))
+def test_grouped_matmul_lowers(chip, cell):
+    """The expert products of a sparse cell, forward and both gradients."""
     from tensorflowonspark_tpu.ops.grouped_matmul import grouped_matmul
+
+    rows, held, d, f = EXPERTS[cell]
 
     def loss(rows, w_in, w_out, sizes):
         h = grouped_matmul(rows, w_in, sizes, interpret=False)
@@ -206,14 +217,40 @@ def test_grouped_matmul_lowers(chip):
         return jnp.sum(out.astype(jnp.float32))
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
-                    chip((131072, 2304), jnp.bfloat16),
-                    chip((16, 2304, 896), jnp.bfloat16),
-                    chip((16, 896, 2304), jnp.bfloat16),
-                    chip((16,), jnp.int32))
+                    chip((rows, d), jnp.bfloat16),
+                    chip((held, d, f), jnp.bfloat16),
+                    chip((held, f, d), jnp.bfloat16),
+                    chip((held,), jnp.int32))
     # the first product, the rows' gradient twice, the weights' twice (the
     # sum's own forward is dead code)
     assert text.count("tpu_custom_call") >= 5
     assert _kernels(text) == {"moe_gmm", "moe_tgmm"}
+
+
+def test_short_conv_mixer_compiles_to_fusions_and_no_convolution(chip):
+    """The conv layer's mixer at `lfm2-8b-a1b.fed_s8k_b2`'s shapes, forward
+    and gradient: three matmuls a direction and elementwise fusions; no
+    kernel, and the taps are not handed to the convolution unit as a
+    depthwise filter of 2048 groups."""
+    from tensorflowonspark_tpu.models.transformer import (
+        ShortConv, TransformerConfig)
+
+    cfg = TransformerConfig(d_model=2048, n_heads=32, conv_kernel=3)
+    mixer = ShortConv(cfg)
+    x = chip((2, 8192, 2048), jnp.bfloat16)
+    one = x.sharding
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.key(0), jnp.zeros((1, 8, 2048)))["params"]))
+
+    def loss(p, x_):
+        return jnp.sum(mixer.apply({"params": p}, x_).astype(jnp.float32)
+                       ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    assert "tpu_custom_call" not in text
+    assert "feature_group_count=2048" not in text
 
 
 def test_adamw_fused_apply_lowers(chip):

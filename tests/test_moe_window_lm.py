@@ -205,9 +205,10 @@ def test_dropless_when_every_pick_is_held(row_chunks):
     cfg = _moe_cfg(2, 4)
     out, sown = MoEMLP(cfg).apply({"params": _share(whole, 2, 4)}, x,
                                   mutable=["intermediates"])
-    local, absent, fullest, mean = np.asarray(
+    local, absent, fullest, mean, moved, kept = np.asarray(
         sown["intermediates"]["moe_stats"][0])
     assert (local, absent) == (2 * 24 * 2, 0) and fullest == 48 == mean
+    assert (moved, kept) == (0, 2 * 24 * 2)     # no selection bias here
     np.testing.assert_allclose(out, _reference_layer(whole, x, 2, 4),
                                atol=1e-5)
     # and its gradients: the rows, the router, the experts
