@@ -47,10 +47,39 @@ get).  Over a mesh ``make_train_step`` passes those shardings to
 ``apply(..., shardings=)`` and each leaf's kernel runs under
 ``shard_map`` by its param's spec — Mosaic kernels cannot be partitioned
 by GSPMD (see ``_run_leaf``); ``update`` takes no shardings and is the
-single-device form.  Blocking to the kernel's (rows, 128) grid
-happens on flat views of the (local) leaf inside the jitted update,
-which XLA lowers to bitcasts (plus a pad copy only for parameters whose
-size is not a lane multiple — none of the flagship's are).
+single-device form.
+
+Blocking (PR 33): a leaf whose shape allows it reaches the kernel as its
+own 2-D view ``[prod(shape[:-1]), shape[-1]]``, which the compiler makes
+by a bitcast, blocked ``(256, 512)`` over a two-axis grid; the last
+block of either axis may be ragged (GPT-2's ``50257 x 1280`` table).
+``_direct_view`` is the shape test and the only selector: ``ndim >= 2``,
+a last dim of a lane tile or more, leading dims that fold into rows over
+whole sublane tiles.  Every other leaf (biases, norm scales, a router
+``[2304, 64]``, conv taps ``[2048, 3]``) is flattened, padded and packed
+to ``[n, 128]`` rows and laid back, as every leaf was before.  That
+packing is NOT a bitcast on the TPU: an ``f32[1280,5120]`` and an
+``f32[51200,128]``, both in tiles of (8, 128), hold their elements in
+different places, so each ``reshape`` of g, p, mu, nu in and of the three
+results out was a pass over the leaf: 48 B a parameter beside the
+kernel's 24, 47.2 ms of the 462 ms GPT-2 large step against the kernel's
+21.7 (ledger, PR 32), and the pad and slice of a leaf that is no whole
+number of blocks another 10.  The counters ``adamw.elems.direct`` /
+``adamw.elems.packed`` (``trace.py``) say how many elements took each
+path.  One residue: where the device's own layout of a leaf is
+column-major (``f32[1280,50257]{0,1}``, GPT-2's untied head: the chip
+pads the shorter way), XLA copies it to the kernel's row-major operands
+and back.
+
+In place: the moments alias their outputs (``input_output_aliases``) and,
+in ``apply``, the parameter does, so under the train step's donation
+the kernel writes the state where it read it.  Without the aliases the
+kernel's fresh outputs were copied into the donated buffers, one
+``copy`` an output of every large leaf; with them a caller that does NOT
+donate gets XLA's protective copy of the inputs and the same numbers.
+Donate parameters and state in the order the results come back
+(``make_train_step`` does: one ``TrainState`` in and out), or a donated
+buffer is matched to another result of the same shape and copied across.
 
 ``mu_dtype="bfloat16"`` stores the first moment in bf16 exactly like
 ``optax.adamw(mu_dtype=...)`` (compute stays f32 in VMEM; the narrow
@@ -68,9 +97,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128               # TPU lane width: last dim of every block
-DEFAULT_BLOCK_ROWS = 256  # (256, 128) f32 block = 128 KB per operand in VMEM
+from tensorflowonspark_tpu import trace
+
+LANE = 128               # TPU lane width: last dim of every packed block
+DEFAULT_BLOCK_ROWS = 256  # rows a block: a multiple of _SUBLANE
 _SUBLANE = 16            # sublane multiple that tiles bf16 and f32 alike
+# lanes a block of a leaf taken in its own layout: seven operands of
+# (256, 512) f32, double-buffered, are 7.3 MB of the 16 MB scoped VMEM.  On
+# the v5e the kernel reads 650-670 GB/s of the 819 whatever the block, from
+# (256, 128) to (128, 1024) or whole rows (chip runs, PR 33: PERF.md)
+_DIRECT_LANES = 4 * LANE
 
 
 class FusedAdamWState(NamedTuple):
@@ -97,7 +133,7 @@ class FusedOptimizer(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# kernels — one (block_rows, LANE) tile per grid step, everything f32 on the
+# kernels — one block of every operand per grid step, everything f32 on the
 # VPU; scalars (lr, clip scale, bias corrections) ride in SMEM
 # ---------------------------------------------------------------------------
 
@@ -145,13 +181,28 @@ def _lion_kernel(s_ref, g_ref, p_ref, mu_ref, o_ref, mu_o_ref,
 
 
 # ---------------------------------------------------------------------------
-# per-leaf driver: flatten to (rows, LANE), pad the tail block, run the grid
+# per-leaf driver: the leaf as [rows, lanes] in its own layout where its
+# shape allows, else packed to (rows, LANE) with a padded tail; run the grid
 # ---------------------------------------------------------------------------
 
+def _direct_view(shape):
+    """`(rows, lanes)` of the 2-D view `[prod(shape[:-1]), shape[-1]]` where
+    the compiler makes that view of the leaf by a bitcast, else None: the
+    last dim fills a lane tile, and a leading dim folds into rows only over
+    whole sublane tiles (of bf16's 16 rows, which holds f32's 8 too).  The
+    shape is the only selector (as `flash_attention._heads_a_block`): biases,
+    norm scales, a router `[2304, 64]`, taps `[2048, 3]` are packed."""
+    if len(shape) < 2 or shape[-1] < LANE:
+        return None
+    if len(shape) > 2 and shape[-2] % _SUBLANE:
+        return None
+    return math.prod(shape[:-1]), shape[-1]
+
+
 def _block_rows_for(n, block_rows):
-    """Rows per grid step: the default, shrunk for small params so a bias
-    vector does not pad out to a full block (sublane-multiple so one tile
-    size serves f32 and bf16 operands)."""
+    """Rows per grid step of a packed leaf: the default, shrunk for small
+    params so a bias vector does not pad out to a full block
+    (sublane-multiple so one tile size serves f32 and bf16 operands)."""
     rows = -(-n // LANE)
     return min(block_rows, -(-rows // _SUBLANE) * _SUBLANE)
 
@@ -171,47 +222,72 @@ def _from_blocks(y, shape):
 
 
 def _run_leaf(kernel, scalars, arrays, out_dtypes, block_rows, interpret,
-              sharding=None):
-    """Run `kernel` over same-shaped leaf `arrays` blocked to (bm, LANE).
+              write_param, sharding=None):
+    """Run `kernel` over same-shaped leaf `arrays` (g, p, the moments).
 
     `arrays[0]` supplies the logical shape; outputs are the first
-    `len(out_dtypes)` kernel refs after the inputs, unpadded back to it.
-    Padding lanes hold zeros; both kernels map zero grad/state to zero
-    output (eps keeps the adam quotient finite), so the pad never NaNs.
+    `len(out_dtypes)` kernel refs after the inputs (the parameter or the
+    update, then the moments), in the leaf's shape.
+
+    Direct (`_direct_view`): every operand is the leaf's own
+    `[rows, lanes]`, blocked `(block_rows, _DIRECT_LANES)` (or the whole
+    dim where it is smaller) over a two-axis grid with `cdiv` on both: the
+    bodies are elementwise, so what a ragged last block reads beyond the
+    leaf is never written back.  Packed: flattened, zero-padded to whole
+    blocks of `(bm, LANE)` and sliced back, a pass over the leaf each way;
+    both kernels map zero grad/state to zero output (eps keeps the adam
+    quotient finite), so the pad never NaNs.  The process counters
+    `adamw.elems.direct` / `adamw.elems.packed` (`trace.py`) count the
+    elements by the path, each time a leaf's call is traced.
+
+    The moments alias their outputs, and under `write_param` the parameter
+    does: with the state donated (`make_train_step`) the kernel updates it
+    in its own buffers; a caller that does not donate gets XLA's
+    protective copy and the same numbers.
 
     `sharding` — the leaf's NamedSharding when the caller jits over a
     mesh (None/False: a single device).  Mosaic refuses to be partitioned
     by GSPMD ("Mosaic kernels cannot be automatically partitioned" — the
     interpreter's plain-XLA lowering never showed it), so there the leaf
     runs under shard_map by the param's own spec: each device blocks and
-    updates its LOCAL shard (a replicated leaf is updated redundantly on
-    every device, which is what data parallelism means), and the outputs
-    leave with the same spec, so the train step's donated state aliases
-    line up.
-
-    NO pallas-level input_output_aliases: aliasing only saves a buffer
-    allocation, not HBM traffic, and the train step's jit donation
-    already recycles the old state buffers.
+    updates its LOCAL shard, whose shape is what `_direct_view` sees (a
+    replicated leaf is updated redundantly on every device, which is what
+    data parallelism means), and the outputs leave with the same spec, so
+    the train step's donated state aliases line up.
     """
     def local(scalars, *arrays):
         shape = arrays[0].shape
-        n = math.prod(shape) if shape else 1
-        bm = _block_rows_for(n, block_rows)
-        blocks = [_to_blocks(a, bm) for a in arrays]
-        rows = blocks[0].shape[0]
-        bspec = pl.BlockSpec((bm, LANE), lambda i: (i, 0))
-        sspec = pl.BlockSpec((1, 4), lambda i: (0, 0),
+        n = math.prod(shape)
+        view = _direct_view(shape)
+        if view:
+            rows, lanes = view
+            bm, bl = min(block_rows, rows), min(_DIRECT_LANES, lanes)
+            blocks = [a.reshape(rows, lanes) for a in arrays]
+        else:
+            bm, bl = _block_rows_for(n, block_rows), LANE
+            blocks = [_to_blocks(a, bm) for a in arrays]
+            rows, lanes = blocks[0].shape
+        trace.counters().inc(
+            "adamw.elems.direct" if view else "adamw.elems.packed", n)
+        bspec = pl.BlockSpec((bm, bl), lambda i, j: (i, j))
+        sspec = pl.BlockSpec((1, 4), lambda i, j: (0, 0),
                              memory_space=pltpu.SMEM)
+        # operands: scalars, g, p, moments; outputs: p or update, moments
+        first = 2 if write_param else 3
         outs = pl.pallas_call(
             kernel,
-            grid=(rows // bm,),
+            grid=(pl.cdiv(rows, bm), pl.cdiv(lanes, bl)),
             in_specs=[sspec] + [bspec] * len(blocks),
             out_specs=[bspec] * len(out_dtypes),
-            out_shape=[jax.ShapeDtypeStruct((rows, LANE), d)
+            out_shape=[jax.ShapeDtypeStruct((rows, lanes), d)
                        for d in out_dtypes],
+            input_output_aliases={i: i - 2
+                                  for i in range(first, len(blocks) + 1)},
             interpret=interpret,
             name="adamw_fused",
         )(scalars, *blocks)
+        if view:
+            return tuple(o.reshape(shape) for o in outs)
         return tuple(_from_blocks(o, shape) for o in outs)
 
     if sharding:
@@ -326,7 +402,7 @@ def adamw_fused(learning_rate, b1=0.9, b2=0.999, eps=1e-8,
             out, new_mu, new_nu = _run_leaf(
                 kern, scal, [g, p, mu, nu],
                 [out_dtype, mu.dtype, nu.dtype], block_rows, interp,
-                sharding)
+                write_param, sharding)
             return _LeafOut(out, new_mu, new_nu)
 
         flat = jax.tree_util.tree_map(leaf, updates, params, state.mu,
@@ -386,7 +462,7 @@ def lion_fused(learning_rate, b1=0.9, b2=0.99, weight_decay=0.0, mask=None,
             out_dtype = p.dtype if write_param else g.dtype
             out, new_mu = _run_leaf(
                 kern, scal, [g, p, mu], [out_dtype, mu.dtype],
-                block_rows, interp, sharding)
+                block_rows, interp, write_param, sharding)
             return _LeafOut(out, new_mu, None)
 
         flat = jax.tree_util.tree_map(leaf, updates, params, state.mu, wds,

@@ -234,7 +234,7 @@ def _opt_state_shardings(optimizer, param_shardings, repl,
     moments keep each parameter's exact shape and mirror the param
     pytree, so every moment shards by its param's OWN spec — fsdp and tp
     axes alike, the placement f32 optax moments get — and the kernel's
-    (rows, 128) blocking happens per shard inside the jitted step with
+    blocking of a leaf happens per shard inside the jitted step with
     no cross-shard blocks (the fused analog of optim8bit's shard-aligned
     layouts, with alignment by construction instead of a layouts= knob).
 

@@ -80,6 +80,11 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     (sub-tiles a head computes, those of them that carry the mask
     arithmetic, and those the padded square holds)
     flash.calls.packed  flash.calls.transposed (kernel calls by layout);
+    of one that traces a fused optimizer (``ops.fused_optim``, the same
+    way, each time a leaf's kernel call is traced): adamw.elems.direct
+    adamw.elems.packed (a leaf's elements, local to the shard under a
+    mesh, by whether the kernel blocks the leaf's own layout or a packed
+    ``[n, 128]`` copy of it; Lion's leaves count under the same names);
     and of one that traces a transformer block
     (``models.transformer``, the same way): mixer.calls.attention
     mixer.calls.conv (what a step program's sequence mixers are)

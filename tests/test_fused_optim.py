@@ -12,7 +12,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 import optax
 
-from tensorflowonspark_tpu import optim
+from tensorflowonspark_tpu import optim, trace
 from tensorflowonspark_tpu.ops import fused_optim
 
 
@@ -122,6 +122,100 @@ def test_mu_dtype_bf16_variant():
         # both sides store bf16 momentum (~3 decimal digits), so expression
         # -order drift lands at bf16 resolution, not f32
         np.testing.assert_allclose(p_f[k], p_ref[k], rtol=1e-3, atol=1e-4)
+
+
+# leaf shapes on every side of `_direct_view`'s test, with the path each
+# takes: direct leaves are blocked in their own [rows, lanes], the others
+# packed to [n, 128]
+LEAF_SHAPES = {
+    "rows-ragged": ((1003, 256), "direct"),
+    "lanes-ragged": ((64, 300), "direct"),
+    "both-ragged": ((1003, 300), "direct"),
+    "lane-blocks-ragged": ((48, 1100), "direct"),   # 512 + 512 + 76 lanes
+    "3d-folds": ((4, 32, 256), "direct"),
+    "3d-does-not-fold": ((3, 10, 256), "packed"),
+    "narrow-last-dim": ((256, 64), "packed"),
+    "1d": ((1280,), "packed"),
+    "scalar": ((), "packed"),
+}
+
+_OPTIMIZERS = {
+    # name -> (fused, optax reference, rtol, atol)
+    "adamw-f32": (
+        lambda: fused_optim.adamw_fused(3e-3, weight_decay=0.1,
+                                        clip_norm=1.0),
+        lambda: optax.chain(optax.clip_by_global_norm(1.0),
+                            optax.adamw(3e-3, weight_decay=0.1)),
+        1e-6, 1e-7),
+    # both sides store bf16 momentum: drift lands at bf16 resolution
+    "adamw-bf16-mu": (
+        lambda: fused_optim.adamw_fused(3e-3, weight_decay=0.1,
+                                        clip_norm=1.0, mu_dtype="bfloat16"),
+        lambda: optax.chain(optax.clip_by_global_norm(1.0),
+                            optax.adamw(3e-3, weight_decay=0.1,
+                                        mu_dtype=jnp.bfloat16)),
+        1e-3, 1e-4),
+    "lion": (
+        lambda: fused_optim.lion_fused(1e-3, weight_decay=0.05,
+                                       clip_norm=1.0),
+        lambda: optax.chain(optax.clip_by_global_norm(1.0),
+                            optax.lion(1e-3, weight_decay=0.05)),
+        1e-6, 1e-7),
+}
+
+
+@pytest.mark.parametrize("method", ["apply", "update"])
+@pytest.mark.parametrize("optimizer", list(_OPTIMIZERS))
+@pytest.mark.parametrize("leaf", list(LEAF_SHAPES))
+def test_fused_matches_optax_by_leaf_shape(leaf, optimizer, method):
+    """Step for step against the optax chain, whichever blocking the
+    leaf's shape chooses; ragged last blocks in rows and in lanes write
+    nothing beyond the leaf and read nothing into it."""
+    shape, _ = LEAF_SHAPES[leaf]
+    make_fused, make_ref, rtol, atol = _OPTIMIZERS[optimizer]
+    fused, ref = make_fused(), make_ref()
+    p_ref = p_f = {"x": jnp.asarray(
+        np.random.RandomState(1).randn(*shape), jnp.float32)}
+    s_ref, s_f = ref.init(p_ref), fused.init(p_f)
+    for i in range(3):
+        g = _grads(p_ref, i)
+        u, s_ref = ref.update(g, s_ref, p_ref)
+        p_ref = optax.apply_updates(p_ref, u)
+        if method == "apply":
+            p_f, s_f = fused.apply(g, s_f, p_f)
+        else:
+            u_f, s_f = fused.update(g, s_f, p_f)
+            p_f = optax.apply_updates(p_f, u_f)
+        assert p_f["x"].shape == shape and s_f.mu["x"].shape == shape
+        np.testing.assert_allclose(p_f["x"], p_ref["x"], rtol=rtol,
+                                   atol=atol)
+    # a bf16 moment may round the other way: one unit of its last place
+    np.testing.assert_allclose(
+        np.asarray(s_f.mu["x"], np.float32),
+        np.asarray(s_ref[1][0].mu["x"], np.float32),
+        rtol=1e-2 if s_f.mu["x"].dtype == jnp.bfloat16 else 1e-5, atol=atol)
+
+
+def test_counters_say_which_path_each_leaf_took():
+    """`adamw.elems.direct` / `adamw.elems.packed` count each leaf's
+    elements by the blocking its shape chose, once a traced call."""
+    params = {k: jnp.ones(shape, jnp.float32)
+              for k, (shape, _) in LEAF_SHAPES.items()}
+    want = {"direct": 0, "packed": 0}
+    for shape, path in LEAF_SHAPES.values():
+        assert (fused_optim._direct_view(shape) is not None) == (
+            path == "direct")
+        want[path] += int(np.prod(shape, dtype=np.int64))
+    names = {k: "adamw.elems." + k for k in want}
+    for opt in (fused_optim.adamw_fused(1e-3), fused_optim.lion_fused(1e-3)):
+        before = {k: trace.counters().get(n) for k, n in names.items()}
+        step = jax.jit(opt.apply)
+        state = opt.init(params)
+        for _ in range(2):          # the second call is not traced again
+            new, state = step(params, state, params)
+        assert {k: trace.counters().get(n) - before[k]
+                for k, n in names.items()} == want
+    assert new["scalar"].shape == ()
 
 
 def test_update_requires_params_for_decay():
