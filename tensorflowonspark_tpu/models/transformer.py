@@ -53,6 +53,20 @@ class TransformerConfig:
     conv_kernel: int = 3          # taps of a conv layer's depthwise filter
     qk_norm: bool = False         # RMSNorm over head_dim on every query and
     # key head (a scale of head_dim each), before the rotation
+    kv_lora_rank: Optional[int] = None  # latent attention (MLA): keys and
+    # values come up from a latent of this rank (`kv_a` [D, rank +
+    # qk_rope_head_dim], RMSNorm on the latent, `kv_b` [rank, H * (nope +
+    # v)]), queries through one of `q_lora_rank` (`q_a`, RMSNorm, `q_b`
+    # [rank, H * (nope + rope)]); a head scores `[q_nope | q_rope]`
+    # against `[k_nope | k_rope]`, where `k_rope` is ONE rotated vector a
+    # token shared by all heads, under a scale of (nope + rope) ** -0.5,
+    # and its values are `v_head_dim` wide (`out` is [H * v, D])
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0     # only these lanes are rotated
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # latent attention: a rotated pair is
+    # lanes (2i, 2i+1), not (i, i + half)
     rope_local_theta: Optional[float] = None  # sliding layers' base (plain
     # rotary; None = rope_theta)
     rope_yarn_factor: float = 1.0  # full layers: YaRN scaling of rope_theta's
@@ -79,6 +93,11 @@ class TransformerConfig:
     # added to the scores for the CHOICE of the k experts only; the weights
     # come from the scores alone, so its gradient is zero and an optimizer
     # without weight decay leaves it where it is
+    moe_shared_experts: int = 0   # dropless: n shared experts, one gated
+    # MLP of n x moe_d_ff that every token passes, added to the routed sum;
+    # with `moe_experts_held` every chip computes it in full, alike
+    moe_routed_scale: float = 1.0  # dropless: the routed sum's factor
+    # (the weights over their sum, times this)
     moe_experts_held: Optional[int] = None  # dropless: this chip's share of
     # an expert-parallel layer: experts [offset, offset + held) live here,
     # the router scores all `num_experts`, and what the absent experts
@@ -87,6 +106,14 @@ class TransformerConfig:
     tie_embeddings: bool = False  # the head reads the embedding's table
     # (`x @ E^T`): no `lm_head` leaf; under `return_hidden` the caller
     # hands `E^T` to ops.xent.fused_unembed_xent
+    mtp_modules: int = 0          # multi-token-prediction modules behind
+    # the last block (training only): module k reads the hidden state
+    # before it (before the last norm) and the embedding of the token k+1
+    # ahead, `proj([RMSNorm(h) | RMSNorm(e)])` [2D, D], one more block, a
+    # last norm of its own, and shares the table and the head; under
+    # `return_hidden` the model returns `(hidden, (hidden_1, ...))`
+    mtp_loss_weight: float = 0.0  # `next_token_losses`: the weight of the
+    # modules' mean cross entropy beside the next token's
     remat: bool = False
     ring_attention_axis: Optional[str] = None  # e.g. "tp" to enable CP
     ulysses_axis: Optional[str] = None  # all-to-all sequence parallelism
@@ -174,7 +201,28 @@ class TransformerConfig:
             raise NotImplementedError(
                 "conv layers shift along a sequence that is whole on the "
                 "device: not with sequence-parallel attention")
+        latent = self.kv_lora_rank is not None
+        if latent and not (self.q_lora_rank and self.qk_nope_head_dim
+                           and self.qk_rope_head_dim and self.v_head_dim):
+            raise ValueError(
+                "kv_lora_rank (latent attention) needs q_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if latent and (self.ring_attention_axis or self.ulysses_axis
+                       or set(kinds) - {FULL}):
+            raise NotImplementedError(
+                "latent attention with layer kinds or sequence-parallel "
+                "attention")
         missing = [name for name, on in (
+            ("kv_lora_rank", latent),
+            ("q_lora_rank", self.q_lora_rank is not None),
+            ("qk_nope_head_dim", bool(self.qk_nope_head_dim)),
+            ("qk_rope_head_dim", bool(self.qk_rope_head_dim)),
+            ("v_head_dim", bool(self.v_head_dim)),
+            ("rope_interleave", self.rope_interleave),
+            ("moe_shared_experts", bool(self.moe_shared_experts)),
+            ("moe_routed_scale", self.moe_routed_scale != 1.0),
+            ("mtp_modules", bool(self.mtp_modules)),
+            ("mtp_loss_weight", bool(self.mtp_loss_weight)),
             ("layer_types", bool(kinds)),
             ("sliding_window", bool(self.sliding_window)),
             ("qk_norm", self.qk_norm),
@@ -186,9 +234,10 @@ class TransformerConfig:
         if self.decode and missing:
             raise NotImplementedError(
                 f"decode=True with {', '.join(missing)}: the kv cache keeps "
-                "no window and no conv layer's last rows, its incremental "
-                "attention no layer kinds and no query/key norms, and "
-                "routing has no incremental form here (ROADMAP R1/R5)")
+                "no window, no latent and no conv layer's last rows, its "
+                "incremental attention no layer kinds and no query/key "
+                "norms, routing has no incremental form here and a "
+                "prediction module no consumer (ROADMAP R1/R5)")
 
 
 FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
@@ -220,8 +269,13 @@ def rope_inv_freq(head_dim, theta, yarn_factor=1.0, original_max=0,
     return (1.0 - ramp) * freqs + ramp * freqs / yarn_factor
 
 
-def apply_rope(x, positions, theta=10000.0, inv_freq=None, factor=1.0):
-    """Rotary position embedding over [..., S, H, D] (split-half pairing).
+def apply_rope(x, positions, theta=10000.0, inv_freq=None, factor=1.0,
+               interleave=False):
+    """Rotary position embedding over [..., S, H, D] (split-half pairing;
+    `interleave`: pair m is lanes (2m, 2m+1), and the rotated pairs come
+    back de-interleaved, every first lane and then every second, as the
+    published latent-attention models do it: a query and a key rotated
+    this way have the dot product the in-place rotation gives).
 
     `positions`: [S] (or [B, S]) absolute token positions; q·k after
     rotation depends only on relative position, so RoPE composes with
@@ -240,8 +294,9 @@ def apply_rope(x, positions, theta=10000.0, inv_freq=None, factor=1.0):
     sin = jnp.sin(angles)[..., None, :]
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
+    x1, x2 = ((x[..., 0::2], x[..., 1::2]) if interleave
+              else (x[..., :half], x[..., half:]))
+    x1, x2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
@@ -351,6 +406,8 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, mask=None):
         cfg = self.cfg
+        if cfg.kv_lora_rank is not None:
+            return self._latent(x, mask)
         trace.counters().inc("mixer.calls.attention")   # once a traced call
         dtype = jnp.dtype(cfg.dtype)
         head_dim = cfg.head_dim or cfg.d_model // cfg.n_heads
@@ -411,10 +468,7 @@ class Attention(nn.Module):
             q = apply_rope(q, pos, **rope)
             k = apply_rope(k, pos, **rope)
 
-        if cfg.attention_impl not in ("auto", "flash", "dense"):
-            raise ValueError(
-                f"attention_impl={cfg.attention_impl!r} not in "
-                "('auto', 'flash', 'dense')")
+        flash = _flash_wanted(cfg, mask)
         if cfg.ring_attention_axis and cfg.ulysses_axis:
             raise ValueError(
                 "ring_attention_axis and ulysses_axis are mutually "
@@ -445,9 +499,7 @@ class Attention(nn.Module):
             # the bytes); the local cores broadcast to full heads on-device
             out = _seqpar_dispatch(q, k, v, cfg)
         else:
-            if mask is None and (cfg.attention_impl == "flash" or (
-                    cfg.attention_impl == "auto"
-                    and jax.default_backend() == "tpu")):
+            if flash:
                 # GQA-native kernel: narrow k/v go straight in (no
                 # repeated kv in HBM, dk/dv come back narrow)
                 out = _flash_dispatch(q, k, v, cfg, window)
@@ -467,6 +519,52 @@ class Attention(nn.Module):
                                             mask=mask, window=window)
         out = out.reshape(B, S, cfg.n_heads * head_dim)
         return self._proj("out", cfg.d_model, out, dtype)
+
+    def _latent(self, x, mask):
+        """Latent attention (MLA; `TransformerConfig.kv_lora_rank`).  On
+        the chip the scores and the values go through
+        `ops.flash_attention.flash_attention_latent`, which reads the one
+        rotated key a token as it is; under a key-padding mask, a mesh or
+        `attention_impl='dense'` the dense core computes the same over the
+        key written out a head."""
+        cfg = self.cfg
+        trace.counters().inc("mixer.calls.latent")      # once a traced call
+        dtype = jnp.dtype(cfg.dtype)
+        H, rank = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        B, S = x.shape[0], x.shape[1]
+
+        def norm(name, h):
+            return nn.RMSNorm(name=name, dtype=jnp.float32,
+                              epsilon=cfg.ln_eps)(h).astype(dtype)
+
+        cq = norm("q_a_norm", self._proj("q_a", cfg.q_lora_rank, x, dtype))
+        q = self._proj("q_b", H * (dn + dr), cq, dtype).reshape(
+            B, S, H, dn + dr)
+        kv_a = self._proj("kv_a", rank + dr, x, dtype)
+        ckv = norm("kv_a_norm", kv_a[..., :rank])
+        kv = self._proj("kv_b", H * (dn + dv), ckv, dtype).reshape(
+            B, S, H, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        rope = dict(inv_freq=rope_inv_freq(dr, cfg.rope_theta),
+                    interleave=cfg.rope_interleave)
+        pos = jnp.arange(S)
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], pos, **rope)], axis=-1)
+        k_rope = apply_rope(kv_a[:, :, None, rank:], pos, **rope)[:, :, 0]
+        if _flash_wanted(cfg, mask) and _ambient_mesh() is None:
+            from tensorflowonspark_tpu.ops.flash_attention import (
+                flash_attention_latent)
+            out = flash_attention_latent(q, k_nope, k_rope, v,
+                                         causal=cfg.causal)
+        else:
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope[:, :, None, :], (B, S, H, dr))], axis=-1)
+            out = dot_product_attention(q, k, v, causal=cfg.causal,
+                                        mask=mask)
+        return self._proj("out", cfg.d_model, out.reshape(B, S, H * dv),
+                          dtype)
 
     def _decode_attention(self, q, k, v, mask):
         """Incremental attention against the kv cache.
@@ -813,6 +911,17 @@ def _paged_attention_body(attn_self, q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
 
+def _flash_wanted(cfg, mask):
+    """Whether `attention_impl` asks for the Pallas kernels here: `flash`,
+    or `auto` on the chip; never under a key-padding mask."""
+    if cfg.attention_impl not in ("auto", "flash", "dense"):
+        raise ValueError(
+            f"attention_impl={cfg.attention_impl!r} not in "
+            "('auto', 'flash', 'dense')")
+    return mask is None and (cfg.attention_impl == "flash" or (
+        cfg.attention_impl == "auto" and jax.default_backend() == "tpu"))
+
+
 def _ambient_mesh():
     """The mesh set by `jax.set_mesh`, or None when there is none."""
     mesh = jax.sharding.get_abstract_mesh()
@@ -1146,8 +1255,11 @@ class MoEMLP(nn.Module):
             raise ValueError(f"moe_scoring={cfg.moe_scoring!r} not in "
                              "('softmax', 'sigmoid')")
         if not dropless and (cfg.moe_scoring != "softmax"
-                             or cfg.moe_expert_bias):
-            raise ValueError("moe_scoring and moe_expert_bias need "
+                             or cfg.moe_expert_bias
+                             or cfg.moe_shared_experts
+                             or cfg.moe_routed_scale != 1.0):
+            raise ValueError("moe_scoring, moe_expert_bias, "
+                             "moe_shared_experts and moe_routed_scale need "
                              "moe_router='dropless'")
         gate_logits = QuantDense(E, use_bias=False, name="router",
                                  impl=cfg.quant_matmul_impl)(
@@ -1176,7 +1288,13 @@ class MoEMLP(nn.Module):
         if dropless:
             # no balancing loss is sown here: the counters say how the
             # load fell (`moe_stats`)
-            return self._dropless_route(x, probs, bias, wi, up, wo)
+            y = self._dropless_route(x, probs, bias, wi, up, wo)
+            if cfg.moe_shared_experts:
+                trace.counters().inc("moe.shared.calls")  # a traced call
+                y = y + DenseMLP(dataclasses.replace(
+                    cfg, d_ff=cfg.moe_shared_experts * F),
+                    name="shared")(x)
+            return y
 
         def expert_mlp(xe):
             """xe: [E, ..., D] -> [E, ..., D], batched over the expert dim."""
@@ -1205,7 +1323,8 @@ class MoEMLP(nn.Module):
     def _dropless_route(self, x, probs, bias, wi, up, wo):
         """Top-k routing over all `num_experts`, computed for the experts
         held here.  The k experts are chosen by `probs + bias` where there
-        is a selection bias, and weighted by `probs` alone, over their sum.
+        is a selection bias, and weighted by `probs` alone, over their sum,
+        times `moe_routed_scale`.
         Of a token's k picks those that fall on held experts
         are sorted by expert, run through the grouped matmul, weighted
         and summed back per token; the static row buffer is sized for the
@@ -1241,6 +1360,8 @@ class MoEMLP(nn.Module):
         elif k > 1:    # weights renormalised over the picks, as `topk` does
             topk_p = topk_p / jnp.maximum(
                 jnp.sum(topk_p, axis=-1, keepdims=True), 1e-9)
+        if cfg.moe_routed_scale != 1.0:
+            topk_p = topk_p * cfg.moe_routed_scale
         local = (topk_idx >= off) & (topk_idx < off + held)        # [T, k]
         # rows sorted by held expert, the absent picks behind them all
         key = jnp.where(local, topk_idx - off, held).reshape(T * k)
@@ -1447,17 +1568,75 @@ class Transformer(nn.Module):
             kind = cfg.layer_types[i] if cfg.layer_types else FULL
             x = block_cls(cfg, use_moe=use_moe, layer_type=kind,
                           name=f"layer_{i}")(x)
+        ahead = ()
+        if cfg.mtp_modules and (return_hidden or self.is_initializing()):
+            ahead = self._predict_ahead(embed, tokens, x, block_cls)
         x = _make_ln(cfg, "ln_f")(x)
+        hidden = (x.astype(dtype), ahead) if cfg.mtp_modules \
+            else x.astype(dtype)
         if return_hidden and (cfg.tie_embeddings
                               or not self.is_initializing()):
-            return x.astype(dtype)
+            return hidden
         if cfg.tie_embeddings:
             return embed.attend(x.astype(dtype))
         logits = QuantDense(cfg.vocab_size, use_bias=False, name="lm_head",
                             dtype=dtype, impl=cfg.quant_matmul_impl)(x)
-        if return_hidden:
-            return x.astype(dtype)  # init pass: lm_head params were created
-        return logits
+        # under `return_hidden` this was the init pass: lm_head now exists
+        return hidden if return_hidden else logits
+
+    def _predict_ahead(self, embed, tokens, z, block_cls):
+        """The multi-token-prediction modules' hidden states, one a module
+        (DeepSeek-V3 report, section 2.2).  Module k (from 1) at position t
+        reads the state before it, `z[t]` (the last block's output, then
+        module k-1's block's), and the embedding of token t + k:
+        `Block(proj([RMSNorm(z[t]) | RMSNorm(e[t + k])]))`, then its own
+        last norm.  The row's last k positions have no such token: they
+        read zeros, see nothing later by causality, and
+        `next_token_losses` masks them."""
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        sparse = cfg.num_experts > 0
+        embedded = embed(tokens)
+        out = []
+        for k in range(1, cfg.mtp_modules + 1):
+            name = f"mtp_{k - 1}"
+            e = jnp.pad(embedded[:, k:], ((0, 0), (0, k), (0, 0)))
+            m = jnp.concatenate(
+                [_make_ln(cfg, f"{name}_hnorm")(z).astype(dtype),
+                 _make_ln(cfg, f"{name}_enorm")(e).astype(dtype)], axis=-1)
+            m = QuantDense(cfg.d_model, use_bias=False, name=f"{name}_proj",
+                           dtype=dtype, impl=cfg.quant_matmul_impl)(m)
+            z = block_cls(cfg, use_moe=sparse, name=f"{name}_block")(m)
+            out.append(_make_ln(cfg, f"{name}_ln_f")(z).astype(dtype))
+        return tuple(out)
+
+
+LOSS_COUNTERS = ("loss.terms.next1", "loss.terms.next2")
+
+
+def next_token_losses(hidden, ahead, kernel, rows, weight, chunk_size=512):
+    """`(loss, terms)` of a model with multi-token-prediction modules:
+    `CE(head(hidden[t]), rows[t + 1]) + weight x mean_k CE(head(ahead_k[t]),
+    rows[t + 1 + k])`, every term by `ops.xent.fused_unembed_xent` over the
+    ONE head `kernel` [D, V] (which so receives a gradient a term), module
+    k's over the positions that have a token k + 1 ahead.  `hidden`,
+    `ahead`: what `Transformer(..., return_hidden=True)` returns for
+    `rows[:, :-1]`; `rows` [B, S + 1] the ids.  `terms` are the two
+    summands as they are added, the second after its weight, under
+    `LOSS_COUNTERS`' names: a loss function returns them among its aux
+    metrics and names them in its `counters`, and
+    `parallel.train.make_train_step` adds each step's to `trace.counters()`
+    with no sync."""
+    from tensorflowonspark_tpu.ops.xent import fused_unembed_xent
+
+    first = fused_unembed_xent(hidden, kernel, rows[:, 1:], chunk_size)
+    second = jnp.float32(0.0)
+    for k, h in enumerate(ahead, 1):
+        targets = jnp.pad(rows[:, 1 + k:], ((0, 0), (0, k)),
+                          constant_values=-1)
+        second = second + fused_unembed_xent(h, kernel, targets, chunk_size)
+    second = second * (weight / max(len(ahead), 1))
+    return first + second, dict(zip(LOSS_COUNTERS, (first, second)))
 
 
 def lm_loss(logits, targets, ignore_id=-1):

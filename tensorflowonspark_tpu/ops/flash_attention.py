@@ -44,7 +44,10 @@ lanes padded to 128); `lse` and `delta`, which the kernels make and read,
 stay `[B, H, S, 128]`.  Every other shape is staged `[B*H, S, D]` by a
 transpose each way (`_heads_a_block` says why for each), and the process
 counters `flash.calls.packed` / `flash.calls.transposed` say which a traced
-kernel call took.
+kernel call took.  Latent attention (a query/key width unlike the value's,
+one rotary key a token for all heads) has three kernels of its own on the
+same schedule, at the end of the file (`flash_attention_latent`,
+`flash.calls.latent`).
 
 Composes with ring attention (parallel/ring_attention.py): ring handles the
 cross-device sequence axis, this kernel the on-device blocks.
@@ -250,13 +253,19 @@ def _tile_mask(q0, k0, shape, q_axis, seq_len, causal, window):
 def _strip_scores(a, b, sm_scale, q0, k0, q_axis, t, span, seq_len, causal,
                   window):
     """Scaled scores `[rows of a, rows of b]` of one strip on the MXU, f32
-    accumulation, with the mask arithmetic only on the runs of the strip a
-    mask edge crosses.  `a` holds the strip's own rows (queries if
-    `q_axis` is 0, keys if 1), `b` the other side's sub-tiles `lo..hi-1` of
-    `span` = `(lo, lo_m, hi_m, hi)`, `t` rows each: masked, plain, masked
-    runs along axis 1.  `q0`, `k0`: the strip's first query and key."""
+    accumulation, masked as `_mask_strip` says.  `a` holds the strip's own
+    rows (queries if `q_axis` is 0, keys if 1), `b` the other side's
+    sub-tiles `lo..hi-1` of `span`."""
     s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
+    return _mask_strip(s, q0, k0, q_axis, t, span, seq_len, causal, window)
+
+
+def _mask_strip(s, q0, k0, q_axis, t, span, seq_len, causal, window):
+    """A strip's scores with the mask arithmetic only on the runs of the
+    strip a mask edge crosses: along axis 1 lie the sub-tiles `lo..hi-1` of
+    `span` = `(lo, lo_m, hi_m, hi)`, `t` rows each: masked, plain, masked
+    runs.  `q0`, `k0`: the strip's first query and key."""
     lo, lo_m, hi_m, hi = span
     if lo_m == lo and hi_m == hi:
         return s
@@ -598,24 +607,37 @@ def _by_queries(q, k, n_blocks, blocks, causal, window, pack):
 
 
 def _scheduled_call(kernel, name, n_blocks, blocks, seq_len, causal, window,
-                    interpret, pack, out_shape, **grid_spec):
-    """`pl.pallas_call` of one of the three kernels with its schedule: the
-    table of block kinds rides in as a scalar-prefetch operand (the index
-    maps take it as a last argument and ignore it)."""
+                    interpret, layout, out_shape, **grid_spec):
+    """`pl.pallas_call` of one of the kernels with its schedule: the table
+    of block kinds rides in as a scalar-prefetch operand (the index maps
+    take it as a last argument and ignore it).  `layout` (`packed`,
+    `transposed` or `latent`) is counted in `flash.calls.<layout>`; the
+    dk/dv kernels (`name` ends in `dkv`) walk a key strip's queries."""
     sub = _pick_subtile(*blocks)
     _count_subtiles(seq_len, *blocks, sub, causal, window)
-    trace.counters().inc(
-        "flash.calls.packed" if pack else "flash.calls.transposed")
+    trace.counters().inc("flash.calls." + layout)
     kinds, patterns = _schedule(n_blocks, blocks, sub, seq_len, causal,
-                                window, by_keys=name != "flash_dkv")
+                                window, by_keys=not name.endswith("dkv"))
     body = functools.partial(
         kernel, causal=causal, sub=sub, grid=n_blocks, patterns=patterns,
         seq_len=seq_len, **({} if window is None else {"window": window}))
+    # the latent kernels hold two more operands a step (the rotary key and
+    # a query half as wide again): 16.1 MiB of dq's strips at the default
+    # blocks, over the default scope of 16 of the chip's 128
+    params = ({"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_LATENT_VMEM_BYTES)} if layout == "latent" else {})
     call = pl.pallas_call(
         body, out_shape=out_shape, interpret=interpret, name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
-                                               **grid_spec))
+                                               **grid_spec), **params)
     return functools.partial(call, kinds)
+
+
+_LATENT_VMEM_BYTES = 32 << 20
+
+
+def _layout(pack):
+    return "packed" if pack else "transposed"
 
 
 # Both implementations are jitted on their own: a model calls them once a
@@ -645,7 +667,7 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         functools.partial(_fwd_kernel, sm_scale=sm_scale, need_lse=need_lse,
                           head_dim=D),
         "flash_fwd", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret, pack,
+        interpret, _layout(pack),
         grid=grid,
         in_specs=[o_spec, kv_spec, kv_spec],
         out_specs=[o_spec] + ([lse_spec] if need_lse else []),
@@ -663,6 +685,26 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             result[1] if need_lse else None)
 
 
+def _row_delta(g, out, g_lse, seq_padded):
+    """delta = rowsum(dO * O): [B, H, Sq] — O(B·S·H·D) elementwise, jax-side.
+    The sum over a head's lanes is a product with the heads' 0/1 indicator
+    (exact at the highest precision: every term is x * 1): it reads dO
+    and O as `[B, S, H*D]`, where a reduction over the last axis of
+    `[B, S, H, D]` would have XLA lay both out anew in float32 first."""
+    B, S, H, D = out.shape
+    prod = (g.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+        B, S, H * D)
+    of_head = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
+        jnp.float32)
+    delta = jnp.einsum("bsk,kh->bhs", prod, of_head,
+                       precision=jax.lax.Precision.HIGHEST)
+    # an lse cotangent folds exactly into delta: ds_ij = p_ij*(dp_ij -
+    # delta_i + g_lse_i), since dlse_i/ds_ij = p_ij
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
+    return jnp.pad(delta, ((0, 0), (0, 0), (0, seq_padded - S)))
+
+
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                     interpret, g_lse=None, window=None):
@@ -677,22 +719,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     Sq, Sk = qs.shape[1], ks.shape[1]
     nq, nk = Sq // block_q, Sk // block_k
 
-    # delta = rowsum(dO * O): [B, H, Sq] — O(B·S·H·D) elementwise, jax-side.
-    # The sum over a head's lanes is a product with the heads' 0/1 indicator
-    # (exact at the highest precision: every term is x * 1): it reads dO
-    # and O as `[B, S, H*D]`, where a reduction over the last axis of
-    # `[B, S, H, D]` would have XLA lay both out anew in float32 first
-    prod = (g.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
-        B, S, H * D)
-    of_head = (jnp.arange(H * D)[:, None] // D == jnp.arange(H)).astype(
-        jnp.float32)
-    delta = jnp.einsum("bsk,kh->bhs", prod, of_head,
-                       precision=jax.lax.Precision.HIGHEST)
-    # an lse cotangent folds exactly into delta: ds_ij = p_ij*(dp_ij -
-    # delta_i + g_lse_i), since dlse_i/ds_ij = p_ij
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)
-    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, Sq - S)))
+    delta = _row_delta(g, out, g_lse, Sq)
     # dq reads both lane-replicated, a query a row; dk/dv a query a lane
     lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
     delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
@@ -702,7 +729,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     dq = _scheduled_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, head_dim=D),
         "flash_dq", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret, pack,
+        interpret, _layout(pack),
         grid=grid,
         in_specs=[q_spec, k_spec, k_spec, q_spec, r_spec, r_spec],
         out_specs=q_spec,
@@ -731,7 +758,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     dk, dv = _scheduled_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, head_dim=D),
         "flash_dkv", (nq, nk), (block_q, block_k), S, causal, window,
-        interpret, pack,
+        interpret, _layout(pack),
         grid=(B, H_kv // per, nk, group * per, nq),
         in_specs=[qk_spec, kk_spec, kk_spec, qk_spec, rk_spec, rk_spec],
         out_specs=[kk_spec, kk_spec],
@@ -898,3 +925,371 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
         raise ValueError(f"window={window} must be at least 1")
     return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                   None if window is None else int(window))
+
+
+# ---- latent attention: two widths, one rotary key a token --------------------
+#
+# Multi-head latent attention (MLA) scores a head's query against a key of
+# two parts: `k_nope` [B, S, H, Dn], a head's own (the latent projected up),
+# and `k_rope` [B, S, Dr], ONE rotated vector a token that all the heads
+# share; the values are Dv wide (192 = 128 + 64 over 128 in the published
+# models).  The three kernels below take them as they are: the scores are
+# the sum of two products, no `[B, S, H, Dn + Dr]` key with the rotary
+# part copied a head is ever built, no value is padded to the query's
+# width, and the rotary key's gradient is summed over the heads inside the
+# dk/dv kernel, whose output block for it stays resident over the heads
+# axis of the grid.  Schedule, strips and masks are the other kernels'
+# (`_schedule`); a head's rows are staged `[B*H, S, D]` as every head of
+# 128 is (`_heads_a_block`).  Counted as `flash.calls.latent`.
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _mla_fwd_kernel(kinds_ref, q_ref, kn_ref, kr_ref, v_ref, o_ref, *rest,
+                    sm_scale, causal, sub, grid, patterns, seq_len, need_lse):
+    # grid (B, H, nq, nk); the forward of `_fwd_kernel`, the scores in two
+    # products
+    block_q, block_k = q_ref.shape[1], kn_ref.shape[1]
+    (nq, nk), (tq, tk) = grid, sub
+    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
+    nope = kn_ref.shape[2]
+    lse_ref = rest[0] if need_lse else None
+    direct = nk == 1
+
+    def _write(rows, acc, m, l):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        if need_lse:
+            lse = jnp.where(m <= NEG_INF / 2, 0.0, m + jnp.log(l))
+            lse_ref[0, 0, rows, :] = jnp.broadcast_to(
+                lse, (acc.shape[0], _LANES))
+
+    if direct:
+        if seq_len < nq * block_q:
+            _write(slice(None), jnp.zeros((block_q, v_ref.shape[2])),
+                   jnp.full((block_q, 1), NEG_INF), jnp.zeros((block_q, 1)))
+    else:
+        m_scr, l_scr, acc_scr = rest[-3:]
+
+        @functools.partial(_when, ki == 0)
+        def _init():
+            m_scr[:] = jnp.full(m_scr.shape, NEG_INF, m_scr.dtype)
+            l_scr[:] = jnp.zeros(l_scr.shape, l_scr.dtype)
+            acc_scr[:] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
+
+    def _strip(r0, r1, span):
+        rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
+                                                    span[3] * tk)
+        qn = q_ref[0, rows, :nope].astype(jnp.float32)
+        qr = q_ref[0, rows, nope:].astype(jnp.float32)
+        kn = kn_ref[0, cols, :].astype(jnp.float32)
+        kr = kr_ref[0, cols, :].astype(jnp.float32)
+        v = v_ref[0, cols, :].astype(jnp.float32)
+        s = (_dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
+             ) * sm_scale
+        s = _mask_strip(s, qi * block_q + r0 * tq,
+                        ki * block_k + span[0] * tk, 0, tk, span, seq_len,
+                        causal, None)
+        m_new = jnp.max(s, axis=-1, keepdims=True)
+        if not direct:
+            m_prev = m_scr[rows, :1]
+            m_new = jnp.maximum(m_prev, m_new)
+        p = jnp.exp(s - m_new)
+        l_new = jnp.sum(p, axis=-1, keepdims=True)
+        pv = _dot(p, v, ((1,), (0,)))
+        if direct:
+            return _write(rows, pv, m_new, l_new)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_scr[rows, :] = acc_scr[rows, :] * alpha + pv
+        lanes = (qn.shape[0], _LANES)
+        m_scr[rows, :] = jnp.broadcast_to(m_new, lanes)
+        l_scr[rows, :] = jnp.broadcast_to(l_scr[rows, :1] * alpha + l_new,
+                                          lanes)
+
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
+
+    if not direct:
+        _when(ki == nk - 1, lambda: _write(
+            slice(None), acc_scr[:], m_scr[:, :1], l_scr[:, :1]))
+
+
+def _mla_dq_kernel(kinds_ref, q_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                   dlt_ref, dq_ref, dq_scr, *, sm_scale, causal, sub, grid,
+                   patterns, seq_len):
+    block_q, block_k = q_ref.shape[1], kn_ref.shape[1]
+    (nq, nk), (tq, tk) = grid, sub
+    qi, ki = _grid_pos(2, nq), _grid_pos(3, nk)
+    nope = kn_ref.shape[2]
+
+    def _init():
+        dq_scr[:] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
+
+    _when(ki == 0, _init)
+
+    def _strip(r0, r1, span):
+        rows, cols = slice(r0 * tq, r1 * tq), slice(span[0] * tk,
+                                                    span[3] * tk)
+        qn = q_ref[0, rows, :nope].astype(jnp.float32)
+        qr = q_ref[0, rows, nope:].astype(jnp.float32)
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        kn = kn_ref[0, cols, :].astype(jnp.float32)
+        kr = kr_ref[0, cols, :].astype(jnp.float32)
+        v = v_ref[0, cols, :].astype(jnp.float32)
+        lse = lse_ref[0, 0, rows, :1]
+        dlt = dlt_ref[0, 0, rows, :1]
+        s = (_dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
+             ) * sm_scale
+        s = _mask_strip(s, qi * block_q + r0 * tq,
+                        ki * block_k + span[0] * tk, 0, tk, span, seq_len,
+                        causal, None)
+        p = jnp.exp(s - lse)
+        ds = p * (_dot(do, v, ((1,), (1,))) - dlt)            # [Tq, Tk]
+        dq_scr[rows, :nope] += sm_scale * _dot(ds, kn, ((1,), (0,)))
+        dq_scr[rows, nope:] += sm_scale * _dot(ds, kr, ((1,), (0,)))
+
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
+
+    def _finish():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    _when(ki == nk - 1, _finish)
+
+
+def _mla_dkv_kernel(kinds_ref, q_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    dlt_ref, dkn_ref, dkr_ref, dv_ref, dkn_scr, dkr_scr,
+                    dv_scr, *, sm_scale, causal, sub, grid, patterns,
+                    seq_len):
+    # grid (B, nk, H, nq): a head's dk_nope and dv accumulate across the q
+    # blocks and go back once a head; the rotary key's gradient accumulates
+    # across the heads as well, its block index constant over both inner
+    # axes, and goes back once a key block
+    block_q, block_k = q_ref.shape[1], kn_ref.shape[1]
+    (nq, nk), (tq, tk) = grid, sub
+    ki, qi = _grid_pos(1, nk), _grid_pos(3, nq)
+    h, n_heads = pl.program_id(2), pl.num_programs(2)
+    nope = kn_ref.shape[2]
+
+    def _init_head():
+        dkn_scr[:] = jnp.zeros(dkn_scr.shape, dkn_scr.dtype)
+        dv_scr[:] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
+
+    def _init_key():
+        dkr_scr[:] = jnp.zeros(dkr_scr.shape, dkr_scr.dtype)
+
+    _when(qi == 0, _init_head)
+    pl.when(jnp.logical_and(h == 0, qi == 0))(_init_key)
+
+    def _strip(c0, c1, span):
+        rows, cols = slice(span[0] * tq, span[3] * tq), slice(c0 * tk,
+                                                              c1 * tk)
+        qn = q_ref[0, rows, :nope].astype(jnp.float32)
+        qr = q_ref[0, rows, nope:].astype(jnp.float32)
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        kn = kn_ref[0, cols, :].astype(jnp.float32)
+        kr = kr_ref[0, cols, :].astype(jnp.float32)
+        v = v_ref[0, cols, :].astype(jnp.float32)
+        # keys down the rows, queries along the lanes, as `_bwd_dkv_kernel`
+        lse = lse_ref[0, 0, :, rows]                           # [1, Tq]
+        dlt = dlt_ref[0, 0, :, rows]
+        s = (_dot(kn, qn, ((1,), (1,))) + _dot(kr, qr, ((1,), (1,)))
+             ) * sm_scale
+        s = _mask_strip(s, qi * block_q + span[0] * tq,
+                        ki * block_k + c0 * tk, 1, tq, span, seq_len,
+                        causal, None)
+        p = jnp.exp(s - lse)                                   # [Tk, Tq]
+        dv_scr[cols, :] += _dot(p, do, ((1,), (0,)))
+        ds = p * (_dot(v, do, ((1,), (1,))) - dlt)
+        dkn_scr[cols, :] += sm_scale * _dot(ds, qn, ((1,), (0,)))
+        dkr_scr[cols, :] += sm_scale * _dot(ds, qr, ((1,), (0,)))
+
+    _for_each_strip(kinds_ref, patterns, qi, ki, nk, _strip)
+
+    def _finish_head():
+        dkn_ref[0] = dkn_scr[:].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    def _finish_key():
+        dkr_ref[0] = dkr_scr[:].astype(dkr_ref.dtype)
+
+    _when(qi == nq - 1, _finish_head)
+    pl.when(jnp.logical_and(h == n_heads - 1, qi == nq - 1))(_finish_key)
+
+
+def _latent_specs(n_heads, by_keys):
+    """`(head, token)`, the BlockSpec makers of a latent kernel's grid:
+    `(B, H, nq, nk)`, or with `by_keys` `(B, nk, H, nq)`.  `head(block,
+    width, keys)` is `block` rows of one head of a `[B*H, S, width]` operand,
+    key rows if `keys` and query rows if not; `token(block, width)` the key
+    rows of an operand with one vector a token, `[B, S, width]`."""
+    def ids(*grid):                       # -> (batch, head, q block, k block)
+        return (grid[0], grid[2], grid[3], grid[1]) if by_keys else grid[:4]
+
+    def head(block, width, keys=False):
+        def at(*grid):
+            b, h, i, j = ids(*grid)
+            return (b * n_heads + h, j if keys else i, 0)
+        return pl.BlockSpec((1, block, width), at)
+
+    def token(block, width):
+        def at(*grid):
+            b, _, _, j = ids(*grid)
+            return (b, j, 0)
+        return pl.BlockSpec((1, block, width), at)
+
+    return head, token
+
+
+def _pad_rows(x, block):
+    """`[B, S, D]` with S padded up to a multiple of `block`."""
+    return jnp.pad(x, ((0, 0), (0, (-x.shape[1]) % block), (0, 0)))
+
+
+_MLA_STATIC = ("causal", "sm_scale", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC + ("need_lse",))
+def _mla_fwd_impl(q, kn, kr, v, causal, sm_scale, block_q, block_k,
+                  interpret, need_lse):
+    """`(out [B, S, H, Dv], lse [B, H, Sq padded, LANES] or None)`."""
+    B, S, H, Dq = q.shape
+    Dn, Dr, Dv = kn.shape[3], kr.shape[2], v.shape[3]
+    qs, kns, vs = (_stage(x, block, None) for x, block in (
+        (q, block_q), (kn, block_k), (v, block_k)))
+    krs = _pad_rows(kr, block_k)
+    Sq, Sk = qs.shape[1], kns.shape[1]
+    nq, nk = Sq // block_q, Sk // block_k
+
+    head, token = _latent_specs(H, by_keys=False)
+    o_spec = head(block_q, Dv)
+    result = _scheduled_call(
+        functools.partial(_mla_fwd_kernel, sm_scale=sm_scale,
+                          need_lse=need_lse),
+        "mla_fwd", (nq, nk), (block_q, block_k), S, causal, None, interpret,
+        "latent",
+        grid=(B, H, nq, nk),
+        in_specs=[head(block_q, Dq), head(block_k, Dn, keys=True),
+                  token(block_k, Dr), head(block_k, Dv, keys=True)],
+        out_specs=[o_spec] + ([pl.BlockSpec(
+            (1, 1, block_q, _LANES), lambda b, h, i, j, _: (b, h, i, 0))]
+            if need_lse else []),
+        out_shape=[jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype)] + (
+            [jax.ShapeDtypeStruct((B, H, Sq, _LANES), jnp.float32)]
+            if need_lse else []),
+        scratch_shapes=[] if nk == 1 else [
+            _scratch((block_q, _LANES)), _scratch((block_q, _LANES)),
+            _scratch((block_q, Dv))],
+    )(qs, kns, krs, vs)
+    return (_unstage(result[0], (B, S, H, Dv), None),
+            result[1] if need_lse else None)
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC)
+def _mla_bwd_impl(q, kn, kr, v, out, lse, g, causal, sm_scale, block_q,
+                  block_k, interpret):
+    B, S, H, Dq = q.shape
+    Dn, Dr, Dv = kn.shape[3], kr.shape[2], v.shape[3]
+    qs, kns, vs, dos = (_stage(x, block, None) for x, block in (
+        (q, block_q), (kn, block_k), (v, block_k), (g, block_q)))
+    krs = _pad_rows(kr, block_k)
+    Sq, Sk = qs.shape[1], kns.shape[1]
+    nq, nk = Sq // block_q, Sk // block_k
+    delta = _row_delta(g, out, None, Sq)
+    lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
+    delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
+
+    # dq: the forward's grid (B, H, nq, nk)
+    by_q, kr_q = _latent_specs(H, by_keys=False)
+    stat_q = pl.BlockSpec((1, 1, block_q, _LANES),
+                          lambda b, h, i, j, _: (b, h, i, 0))
+    dq = _scheduled_call(
+        functools.partial(_mla_dq_kernel, sm_scale=sm_scale),
+        "mla_dq", (nq, nk), (block_q, block_k), S, causal, None, interpret,
+        "latent",
+        grid=(B, H, nq, nk),
+        in_specs=[by_q(block_q, Dq), by_q(block_k, Dn, keys=True),
+                  kr_q(block_k, Dr), by_q(block_k, Dv, keys=True),
+                  by_q(block_q, Dv), stat_q, stat_q],
+        out_specs=by_q(block_q, Dq),
+        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
+        scratch_shapes=[_scratch((block_q, Dq))],
+    )(qs, kns, krs, vs, dos, lse, delta)
+
+    # dk/dv: grid (B, nk, H, nq), q innermost, the heads around it
+    by_k, kr_k = _latent_specs(H, by_keys=True)
+    kr_k = kr_k(block_k, Dr)
+    stat_k = pl.BlockSpec((1, 1, 1, block_q),
+                          lambda b, j, h, i, _: (b, h, 0, i))
+    dkn, dkr, dv = _scheduled_call(
+        functools.partial(_mla_dkv_kernel, sm_scale=sm_scale),
+        "mla_dkv", (nq, nk), (block_q, block_k), S, causal, None, interpret,
+        "latent",
+        grid=(B, nk, H, nq),
+        in_specs=[by_k(block_q, Dq), by_k(block_k, Dn, keys=True), kr_k,
+                  by_k(block_k, Dv, keys=True), by_k(block_q, Dv), stat_k,
+                  stat_k],
+        out_specs=[by_k(block_k, Dn, keys=True), kr_k,
+                   by_k(block_k, Dv, keys=True)],
+        out_shape=[jax.ShapeDtypeStruct(kns.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(krs.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(vs.shape, v.dtype)],
+        scratch_shapes=[_scratch((block_k, Dn)), _scratch((block_k, Dr)),
+                        _scratch((block_k, Dv))],
+    )(qs, kns, krs, vs, dos, lse_t, delta_t)
+    return (_unstage(dq, q.shape, None), _unstage(dkn, kn.shape, None),
+            dkr[:, :S], _unstage(dv, v.shape, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _mla(q, kn, kr, v, causal, sm_scale, block_q, block_k, interpret):
+    return _mla_fwd_impl(q, kn, kr, v, causal, sm_scale, block_q, block_k,
+                         interpret, need_lse=False)[0]
+
+
+def _mla_vjp_fwd(q, kn, kr, v, causal, sm_scale, block_q, block_k,
+                 interpret):
+    out, lse = _mla_fwd_impl(q, kn, kr, v, causal, sm_scale, block_q,
+                             block_k, interpret, need_lse=True)
+    return out, (q, kn, kr, v, out, lse)
+
+
+def _mla_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+    return _mla_bwd_impl(*res, g, causal, sm_scale, block_q, block_k,
+                         interpret)
+
+
+_mla.defvjp(_mla_vjp_fwd, _mla_vjp_bwd)
+
+
+def latent_attention_reference(q, k_nope, k_rope, v, causal=True,
+                               sm_scale=None):
+    """What `flash_attention_latent` computes, by `attention_reference`
+    over the explicit `[k_nope | k_rope]` key a head and the values padded
+    to the query's width: for the tests."""
+    Dq = q.shape[3]
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, :, None, :], k_nope.shape[:3] + k_rope.shape[2:])], axis=-1)
+    vp = jnp.pad(v, ((0, 0),) * 3 + ((0, Dq - v.shape[3]),))
+    return attention_reference(q, k, vp, causal=causal, sm_scale=sm_scale
+                               )[..., :v.shape[3]]
+
+
+def flash_attention_latent(q, k_nope, k_rope, v, causal=True, sm_scale=None,
+                           block_q=1024, block_k=1024, interpret=None):
+    """Flash attention for latent (MLA) heads: `q` [B, S, H, Dn + Dr] whose
+    last `Dr` lanes are rotated, `k_nope` [B, S, H, Dn], `k_rope` [B, S, Dr]
+    (one rotated key a token, shared by all H heads), `v` [B, S, H, Dv]:
+    `softmax((q_n . k_n + q_r . k_r) * sm_scale) v`, `[B, S, H, Dv]`.
+    `sm_scale` defaults to `(Dn + Dr) ** -0.5`.  Differentiable in all
+    four; `k_rope`'s gradient is the sum over the heads."""
+    if (q.shape[3] != k_nope.shape[3] + k_rope.shape[2]
+            or k_nope.shape[2] != q.shape[2] or v.shape[2] != q.shape[2]):
+        raise ValueError(
+            f"latent attention wants q [B, S, H, Dn + Dr], k_nope [B, S, H, "
+            f"Dn], k_rope [B, S, Dr], v [B, S, H, Dv]; got {q.shape}, "
+            f"{k_nope.shape}, {k_rope.shape}, {v.shape}")
+    sm_scale, block_q, block_k, interpret = _resolve_call_args(
+        q, k_nope, sm_scale, block_q, block_k, interpret)
+    return _mla(q, k_nope, k_rope, v, causal, sm_scale, block_q, block_k,
+                interpret)

@@ -72,14 +72,20 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     moe.load.max  moe.load.mean (tokens of the fullest held expert and of
     the mean one)  moe.picks.moved  moe.picks.kept (picks that a
     selection bias took from, and left among, the k largest scores; all
-    six summed over layers and steps)
+    six summed over layers and steps); of one whose loss has a
+    multi-token-prediction term (``models.transformer.next_token_losses``,
+    carried the same way): loss.terms.next1  loss.terms.next2 (the two
+    summands of the loss as they are added, the second after its weight,
+    summed over steps)
     counters of a process that traces flash attention
     (``ops.flash_attention``, added on the host each time a kernel call is
     traced, so per compiled program and not per step):
     flash.subtiles.computed  flash.subtiles.masked  flash.subtiles.square
     (sub-tiles a head computes, those of them that carry the mask
     arithmetic, and those the padded square holds)
-    flash.calls.packed  flash.calls.transposed (kernel calls by layout);
+    flash.calls.packed  flash.calls.transposed  flash.calls.latent
+    (kernel calls by layout; latent: the kernels with a query/key width
+    unlike the value's and one rotary key a token);
     of one that traces a fused optimizer (``ops.fused_optim``, the same
     way, each time a leaf's kernel call is traced): adamw.elems.direct
     adamw.elems.packed (a leaf's elements, local to the shard under a
@@ -87,7 +93,8 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     ``[n, 128]`` copy of it; Lion's leaves count under the same names);
     and of one that traces a transformer block
     (``models.transformer``, the same way): mixer.calls.attention
-    mixer.calls.conv (what a step program's sequence mixers are)
+    mixer.calls.conv  mixer.calls.latent (what a step program's sequence
+    mixers are)  moe.shared.calls (its shared experts)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

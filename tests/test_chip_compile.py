@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from tensorflowonspark_tpu import quantize
 from tensorflowonspark_tpu.ops import (flash_attention, paged_attention,
                                        paged_prefill, quant_matmul)
+from tensorflowonspark_tpu.ops.flash_attention import flash_attention_latent
 from tensorflowonspark_tpu.ops.fused_optim import adamw_fused
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -80,8 +81,8 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "adamw_fused", "moe_tgmm",
-           "moe_gmm")
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "mla_fwd", "mla_dq",
+           "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm")
 
 
 def _kernels(text):
@@ -151,6 +152,36 @@ def test_flash_with_a_window_lowers(chip):
            chip((1, 8192, 4, 128), jnp.bfloat16))
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
     assert _kernels(text) == {"flash_fwd", "flash_dq", "flash_dkv"}
+
+
+def _latent(chip):
+    """The latent mixer of `joyai-llm-flash.fed_s8k_b2`: 32 heads, queries
+    of 128 + 64 rotated lanes, ONE rotated key of 64 a token, values of
+    128."""
+    return (chip((2, 8192, 32, 192), jnp.bfloat16),
+            chip((2, 8192, 32, 128), jnp.bfloat16),
+            chip((2, 8192, 64), jnp.bfloat16),
+            chip((2, 8192, 32, 128), jnp.bfloat16))
+
+
+def test_latent_flash_forward_lowers(chip):
+    fn = functools.partial(flash_attention_latent, interpret=False)
+    text = _compile(fn, *_latent(chip))
+    assert _kernels(text) == {"mla_fwd"}
+
+
+def test_latent_flash_backward_lowers_with_one_rotary_key_a_token(chip):
+    def loss(q, kn, kr, v):
+        out = flash_attention_latent(q, kn, kr, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *_latent(chip))
+    assert _kernels(text) == {"mla_fwd", "mla_dq", "mla_dkv"}
+    # the rotary key is never copied a head, nor a value padded to 192
+    assert not re.search(r"bf16\[(2,8192,32|2,32,8192|64,8192),(192|64)\]"
+                         r"[^\n]* (broadcast|pad)\(", text)
+    # its gradient comes out of the kernel summed over the heads
+    assert re.search(r"bf16\[2,8192,64\]", text)
 
 
 # (batch, sequence, query heads, key/value heads, head size, model width,
