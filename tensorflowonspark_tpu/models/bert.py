@@ -19,7 +19,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models.transformer import (
-    Block, TransformerConfig, _activation, lm_loss)
+    Block, TransformerConfig, _activation, lm_loss, remat_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,7 +76,7 @@ class BertEncoder(nn.Module):
         x = nn.LayerNorm(name="ln_embed", dtype=jnp.float32,
                          epsilon=cfg.ln_eps)(x).astype(dtype)
         bcfg = cfg.block_config()
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = remat_block() if cfg.remat else Block
         for i in range(cfg.n_layers):
             x = block_cls(bcfg, name=f"layer_{i}")(x, mask=attention_mask)
         if cfg.norm_style == "post":

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tensorflowonspark_tpu.models.transformer import (
-    Block, TransformerConfig)
+    Block, TransformerConfig, remat_block)
 from tensorflowonspark_tpu.parallel.pipeline import (
     pipeline_apply, stack_stage_params)
 
@@ -79,7 +79,7 @@ class PipelinedLM:
                 f"n_stages={self.n_stages}")
         self._embed = _Embedder(self.cfg)
         self._head = _Head(self.cfg)
-        block_cls = nn.remat(Block) if self.cfg.remat else Block
+        block_cls = remat_block() if self.cfg.remat else Block
         self._block = block_cls(self.cfg)
 
     @property

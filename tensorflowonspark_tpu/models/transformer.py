@@ -114,7 +114,10 @@ class TransformerConfig:
     # `return_hidden` the model returns `(hidden, (hidden_1, ...))`
     mtp_loss_weight: float = 0.0  # `next_token_losses`: the weight of the
     # modules' mean cross entropy beside the next token's
-    remat: bool = False
+    remat: bool = False           # rematerialise each block in the backward
+    # pass, keeping its input and, where attention ran in the Pallas kernels,
+    # their output `[B, S, H*Dv]` and row statistics `f32[B, H, S]`
+    # (`remat_block`): the recomputed forward holds no attention kernel
     ring_attention_axis: Optional[str] = None  # e.g. "tp" to enable CP
     ulysses_axis: Optional[str] = None  # all-to-all sequence parallelism
     sp_axis: Optional[str] = None  # Megatron-SP: shard residual stream's
@@ -504,6 +507,7 @@ class Attention(nn.Module):
                 # repeated kv in HBM, dk/dv come back narrow)
                 out = _flash_dispatch(q, k, v, cfg, window)
             else:
+                _count_remat(cfg, False)
                 # dense path: broadcast back to full heads for the
                 # attention cores (the narrow projection already saved
                 # the params + kv-cache HBM; XLA fuses the repeat)
@@ -553,7 +557,9 @@ class Attention(nn.Module):
         q = jnp.concatenate(
             [q[..., :dn], apply_rope(q[..., dn:], pos, **rope)], axis=-1)
         k_rope = apply_rope(kv_a[:, :, None, rank:], pos, **rope)[:, :, 0]
-        if _flash_wanted(cfg, mask) and _ambient_mesh() is None:
+        flash = _flash_wanted(cfg, mask) and _ambient_mesh() is None
+        _count_remat(cfg, flash)
+        if flash:
             from tensorflowonspark_tpu.ops.flash_attention import (
                 flash_attention_latent)
             out = flash_attention_latent(q, k_nope, k_rope, v,
@@ -922,6 +928,17 @@ def _flash_wanted(cfg, mask):
         cfg.attention_impl == "auto" and jax.default_backend() == "tpu"))
 
 
+def _count_remat(cfg, kernels):
+    """Under `remat`, once a traced mixer: `remat.attention.saved` where it
+    went to the Pallas kernels, whose output and row statistics the block's
+    policy keeps (`remat_block`), `remat.attention.rerun` where it took the
+    dense core, which the backward pass computes again.  The
+    sequence-parallel mixers (ring, Ulysses) count as neither."""
+    if cfg.remat:
+        trace.counters().inc(
+            "remat.attention." + ("saved" if kernels else "rerun"))
+
+
 def _ambient_mesh():
     """The mesh set by `jax.set_mesh`, or None when there is none."""
     mesh = jax.sharding.get_abstract_mesh()
@@ -1002,6 +1019,7 @@ def _flash_dispatch(q, k, v, cfg, window=None):
     from tensorflowonspark_tpu.parallel.ring_attention import _kv_repeat
     mesh = _ambient_mesh()
     if mesh is None:
+        _count_remat(cfg, True)
         return flash_attention(q, k, v, causal=cfg.causal, window=window)
     axes = mesh.axis_names
 
@@ -1024,12 +1042,14 @@ def _flash_dispatch(q, k, v, cfg, window=None):
     # recompute attention redundantly on every member of that axis
     for name, got in (("dp", dp), ("tp", tp)):
         if got is None and name in axes and mesh.shape[name] > 1:
+            _count_remat(cfg, False)
             kf, vf = _kv_repeat(q, k, v)   # dense core needs full heads
             return dot_product_attention(q, kf, vf, causal=cfg.causal,
                                          window=window)
     import functools
     from jax.sharding import PartitionSpec as P
 
+    _count_remat(cfg, True)
     spec = P(dp, None, tp, None)
     local = functools.partial(flash_attention, causal=cfg.causal,
                               window=window)
@@ -1520,6 +1540,21 @@ class Block(nn.Module):
         return ln2(x + mlp(x)).astype(dtype)
 
 
+def remat_block():
+    """`Block` as `remat=True` wraps it: recomputed in the backward pass
+    from its input, but for attention's output and row log-sum-exp, which
+    the forward rules of `ops.flash_attention` name `flash_out` and
+    `flash_lse` and this policy saves, one `[B, S, H*Dv]` and one
+    `f32[B, H, S]` a layer, so the backward's `flash_dq` / `flash_dkv`
+    start from them and the forward kernel, the dearest call of the block,
+    runs once a step and not twice.  A mixer on the dense core (a mask,
+    `attention_impl='dense'`, latent attention under a mesh) names nothing
+    and is recomputed whole; `remat.attention.saved` / `.rerun` count
+    which a traced mixer was."""
+    return nn.remat(Block, policy=jax.checkpoint_policies
+                    .save_only_these_names("flash_out", "flash_lse"))
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
 
@@ -1556,9 +1591,7 @@ class Transformer(nn.Module):
             pos = nn.Embed(cfg.max_seq_len, cfg.d_model, name="pos_embed",
                            dtype=dtype)(pos_ids)
             x = x + pos
-        block_cls = Block
-        if cfg.remat:
-            block_cls = nn.remat(Block)
+        block_cls = remat_block() if cfg.remat else Block
         for i in range(cfg.n_layers):
             # every k-th layer is MoE, counting so that moe_every=1 means
             # every layer (k=2 keeps the old odd-layer placement); the

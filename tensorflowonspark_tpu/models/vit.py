@@ -16,8 +16,8 @@ import dataclasses
 import flax.linen as nn
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.models.transformer import (Block,
-                                                      TransformerConfig)
+from tensorflowonspark_tpu.models.transformer import (
+    Block, TransformerConfig, remat_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,7 +84,7 @@ class ViT(nn.Module):
                          (1, n_tokens, cfg.d_model))
         x = x + pos.astype(dtype)
         bcfg = self.cfg.block_config()
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = remat_block() if cfg.remat else Block
         for i in range(cfg.n_layers):
             x = block_cls(bcfg, name=f"layer_{i}")(x)
         x = nn.LayerNorm(name="ln_f", dtype=jnp.float32)(x)

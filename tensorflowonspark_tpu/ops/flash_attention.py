@@ -6,9 +6,18 @@ grid streams K/V blocks through VMEM (innermost grid dim) while per-q-block
 running max / denominator / accumulator live in VMEM scratch that persists
 across the sequential k-steps of the TPU grid, and emits the per-row
 logsumexp.  The backward recomputes probabilities blockwise from (q, k,
-lse) — flash-style recompute, residuals O(B·S·H·D) — in two kernels: one
-accumulating dq over streamed K/V blocks, one accumulating dk/dv over
-streamed Q/dO blocks.  All matmuls run on the MXU with f32 accumulation.
+lse) — flash-style recompute — in two kernels: one accumulating dq over
+streamed K/V blocks, one accumulating dk/dv over streamed Q/dO blocks.  All
+matmuls run on the MXU with f32 accumulation.  What a backward holds of
+the forward is q, k, v, the output, `O(B·S·H·D)`, and the row logsumexp as
+`f32[B, H, S]`, one value a query: the forward rules (`_residuals`) keep
+lane 0 of the 128 equal lanes the kernel writes and the backward
+broadcasts it back for the dq kernel, so 128 times that is alive only
+around the backward's own kernel calls, not from the forward pass to the
+backward.  The two are named `flash_out` and `flash_lse`
+(`jax.ad_checkpoint.checkpoint_name`): a rematerialised block whose policy
+saves those names (`models.transformer.remat_block`) recomputes q, k and v
+and no forward kernel.
 
 A grid step keeps a large resident block (1024 rows by default: a grid
 step costs the same whatever it holds) and computes it in strips of
@@ -40,8 +49,8 @@ told apart by a lane mask that follows a grid axis, so the body is no
 longer for it: a product that contracts 128 lanes of which the other head's
 are zero costs the MXU what a contraction over 64 does and adds zeros.  No
 `[B, H, S, D]` copy of any of them exists (each was one a kernel call, 64
-lanes padded to 128); `lse` and `delta`, which the kernels make and read,
-stay `[B, H, S, 128]`.  Every other shape is staged `[B*H, S, D]` by a
+lanes padded to 128); `lse` and `delta`, as the kernels write and read
+them, stay `[B, H, S, 128]`.  Every other shape is staged `[B*H, S, D]` by a
 transpose each way (`_heads_a_block` says why for each), and the process
 counters `flash.calls.packed` / `flash.calls.transposed` say which a traced
 kernel call took.  Latent attention (a query/key width unlike the value's,
@@ -60,13 +69,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tensorflowonspark_tpu import trace
 
 NEG_INF = -1e30  # large-finite: exp(NEG_INF - m) == 0 without inf-inf NaNs
-_LANES = 128  # lse/delta carry a lane-replicated trailing dim for layout
+_LANES = 128  # the kernels write lse and read lse/delta lane-replicated
 
 
 def _scratch(shape, dtype=jnp.float32):
@@ -720,9 +730,11 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     nq, nk = Sq // block_q, Sk // block_k
 
     delta = _row_delta(g, out, g_lse, Sq)
+    # `lse` comes as the residual holds it, a value a query (`_residuals`).
     # dq reads both lane-replicated, a query a row; dk/dv a query a lane
-    lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
-    delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
+    lse_t, delta_t = lse[:, :, None, :], delta[:, :, None, :]
+    lse, delta = (jnp.broadcast_to(x[..., None], (B, H, Sq, _LANES))
+                  for x in (lse, delta))
 
     grid, q_spec, k_spec, r_spec = _by_queries(
         q, k, (nq, nk), (block_q, block_k), causal, window, pack)
@@ -803,10 +815,23 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     return out
 
 
+def _residuals(out, lse):
+    """What a forward rule keeps of its kernel's results for the backward,
+    under the names a rematerialisation policy can save them by
+    (`models.transformer.remat_block`): the output, and the row statistics
+    as ONE value a query, `f32[B, H, Sq padded]`, not the 128 equal lanes
+    the kernel writes (GPT-2 large at B=8: 0.66 MB a layer, not 84).  The
+    backward broadcasts them back to the lanes the dq kernel reads, as it
+    does `delta`."""
+    return (checkpoint_name(out, "flash_out"),
+            checkpoint_name(lse[:, :, :, 0], "flash_lse"))
+
+
 def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    window):
-    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                               interpret, need_lse=True, window=window)
+    out, lse = _residuals(*_flash_fwd_impl(
+        q, k, v, causal, sm_scale, block_q, block_k, interpret,
+        need_lse=True, window=window))
     return out, (q, k, v, out, lse)
 
 
@@ -829,18 +854,17 @@ def _flash_lse(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 
 def _flash_lse_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k,
                        interpret):
-    out, lse_full = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q,
-                                    block_k, interpret, need_lse=True)
-    lse = lse_full[:, :, :q.shape[1], 0]
-    return (out, lse), (q, k, v, out, lse_full)
+    out, lse = _residuals(*_flash_fwd_impl(
+        q, k, v, causal, sm_scale, block_q, block_k, interpret,
+        need_lse=True))
+    return (out, lse[:, :, :q.shape[1]]), (q, k, v, out, lse)
 
 
 def _flash_lse_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res,
                        cotangents):
-    q, k, v, out, lse_full = res
     g, g_lse = cotangents
-    return _flash_bwd_impl(q, k, v, out, lse_full, g, causal, sm_scale,
-                           block_q, block_k, interpret, g_lse=g_lse)
+    return _flash_bwd_impl(*res, g, causal, sm_scale, block_q, block_k,
+                           interpret, g_lse=g_lse)
 
 
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
@@ -1196,8 +1220,9 @@ def _mla_bwd_impl(q, kn, kr, v, out, lse, g, causal, sm_scale, block_q,
     Sq, Sk = qs.shape[1], kns.shape[1]
     nq, nk = Sq // block_q, Sk // block_k
     delta = _row_delta(g, out, None, Sq)
-    lse_t, delta_t = lse[:, :, None, :, 0], delta[:, :, None, :]
-    delta = jnp.broadcast_to(delta[..., None], (B, H, Sq, _LANES))
+    lse_t, delta_t = lse[:, :, None, :], delta[:, :, None, :]
+    lse, delta = (jnp.broadcast_to(x[..., None], (B, H, Sq, _LANES))
+                  for x in (lse, delta))
 
     # dq: the forward's grid (B, H, nq, nk)
     by_q, kr_q = _latent_specs(H, by_keys=False)
@@ -1249,8 +1274,9 @@ def _mla(q, kn, kr, v, causal, sm_scale, block_q, block_k, interpret):
 
 def _mla_vjp_fwd(q, kn, kr, v, causal, sm_scale, block_q, block_k,
                  interpret):
-    out, lse = _mla_fwd_impl(q, kn, kr, v, causal, sm_scale, block_q,
-                             block_k, interpret, need_lse=True)
+    out, lse = _residuals(*_mla_fwd_impl(
+        q, kn, kr, v, causal, sm_scale, block_q, block_k, interpret,
+        need_lse=True))
     return out, (q, kn, kr, v, out, lse)
 
 

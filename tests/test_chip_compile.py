@@ -85,18 +85,21 @@ KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "mla_fwd", "mla_dq",
            "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm")
 
 
-def _kernels(text):
-    """Which of `KERNELS` the `tpu_custom_call` instructions of a compiled
-    text are named after.  An instruction takes its name from the
-    `pallas_call`'s `name=` with the transformations it went through
+def _kernel_calls(text):
+    """The `tpu_custom_call` instructions of a compiled text, each by the
+    one of `KERNELS` it is named after.  An instruction takes its name from
+    the `pallas_call`'s `name=` with the transformations it went through
     around it (`%transpose_jvp_flash_dq__.3`), and a device trace's event
     is called by the whole instruction; one named after none of them is
     returned as it is called."""
-    found = set()
-    for name in re.findall(
-            r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"', text):
-        found.add(next((k for k in KERNELS if k in name), name))
-    return found
+    return [next((k for k in KERNELS if k in name), name)
+            for name in re.findall(
+                r'%([\w.]+) = [^\n]*custom_call_target="tpu_custom_call"',
+                text)]
+
+
+def _kernels(text):
+    return set(_kernel_calls(text))
 
 
 # (batch, sequence, query heads, key/value heads, head size): the private
@@ -226,6 +229,58 @@ def test_attention_sublayer_stages_no_copy_of_its_heads(chip, shape):
                            r"(?:copy|transpose)\(", line)]
         if m and math.prod(map(int, m.group(1).split(","))) in sizes]
     assert not staged, staged
+
+
+# Two dense layers under `remat=True` at the widths of two cells (a small
+# vocabulary: the head is not what is looked at), the batch and sequence of
+# the cell, and the forward kernel's name
+REMAT = {
+    "gpt2-large": (dict(
+        vocab_size=1024, d_model=1280, n_heads=20, n_layers=2, d_ff=5120,
+        max_seq_len=1024, use_bias=True, ln_eps=1e-5), (8, 1024),
+        "flash_fwd"),
+    "joyai-llm-flash": (dict(
+        vocab_size=1024, d_model=2048, n_heads=32, n_layers=2, d_ff=7168,
+        max_seq_len=8192, rope=True, rope_theta=32e6, kv_lora_rank=512,
+        q_lora_rank=1536, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_interleave=True, activation="silu",
+        norm_type="rmsnorm", mlp_style="gated"), (2, 8192), "mla_fwd"),
+}
+
+
+@pytest.mark.parametrize("cell", list(REMAT))
+def test_a_rematerialised_block_runs_the_forward_kernel_once(
+        chip, cell, monkeypatch):
+    """The gradient of a two-layer `remat=True` model: the block's policy
+    keeps the kernels' output and row statistics (`remat_block`), so the
+    compiled step holds ONE forward kernel a layer, beside one dq and one
+    dk/dv, where a block rematerialised whole held two."""
+    import tensorflowonspark_tpu.ops as ops
+    from tensorflowonspark_tpu.models.transformer import (
+        Transformer, TransformerConfig, lm_loss)
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    model_kw, (b, s), forward = REMAT[cell]
+    cfg = TransformerConfig(dtype="bfloat16", attention_impl="flash",
+                            remat=True, **model_kw)
+    model = Transformer(cfg)
+    tokens = chip((b, s), jnp.int32)
+    one = tokens.sharding
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def loss(p, t):
+        return lm_loss(model.apply({"params": p}, t), t)
+
+    text = _compile(jax.grad(loss), params, tokens)
+    calls = _kernel_calls(text)
+    assert calls.count(forward) == cfg.n_layers, calls
+    assert len(calls) == 3 * cfg.n_layers, calls       # + one dq, one dk/dv
+    # ... and no row statistics a lane outlive a backward kernel call: the
+    # saved ones are `f32[B, H, S]`
+    assert re.search(rf"f32\[{b},{cfg.n_heads},{s}\]", text)
 
 
 # (rows for the worst routing, held experts, model width, expert width):

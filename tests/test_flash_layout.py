@@ -5,11 +5,15 @@ staging for the shapes the lane rule cannot serve or does not yet let by.  Inter
 mode against the dense reference; fast tier (`tests/test_ops.py`, which
 holds the kernels' other parity tests, is in the slow one).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tensorflowonspark_tpu.models.transformer import (
+    Block, Transformer, TransformerConfig, lm_loss, remat_block)
 from tensorflowonspark_tpu.ops.flash_attention import (
     attention_reference, flash_attention)
 
@@ -131,3 +135,135 @@ def test_flash_with_lse_over_lane_blocks(H, D, staging):
     want = jax.grad(scalar(dense), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# (query heads, key/value heads, window, latent): S=200 at blocks of 128 is
+# padded up to 256 in every one
+RESIDUALS = {"plain": (2, 2, None, False), "gqa_window": (4, 2, 60, False),
+             "latent": (2, 2, None, True)}
+
+
+@pytest.mark.parametrize("case", list(RESIDUALS))
+def test_backward_holds_the_row_statistics_a_query_not_a_lane(case):
+    """Between forward and backward a call keeps q, k, v, the output and
+    `f32[B, H, S padded]` named `flash_lse`, never the kernel's own
+    `[B, H, S, 128]`; the gradients from the compact residual match the
+    dense reference where S is padded up to a block."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from tensorflowonspark_tpu.ops.flash_attention import (
+        flash_attention_latent, latent_attention_reference)
+
+    H, H_kv, window, latent = RESIDUALS[case]
+    S, D = 200, 64
+    q, k, v, w = _heads(H, H_kv, D, S)
+    if latent:
+        args = (jnp.concatenate([q, q[..., :32]], -1), k, k[:, :, 0, :32], v)
+        fn = lambda *a: jnp.sum(w * flash_attention_latent(  # noqa: E731
+            *a, block_q=128, block_k=128, interpret=True))
+        ref = lambda *a: jnp.sum(  # noqa: E731
+            w * latent_attention_reference(*a))
+    else:
+        args = (q, k, v)
+        fn = lambda *a: jnp.sum(w * flash_attention(  # noqa: E731
+            *a, window=window, block_q=128, block_k=128, interpret=True))
+        ref = lambda *a: jnp.sum(w * attention_reference(  # noqa: E731
+            *a, window=window))
+    kept = [(aval.shape, why) for aval, why in saved_residuals(fn, *args)]
+    assert ((1, H, 256), "flash_lse") in [
+        (shape, why.split("'")[1]) for shape, why in kept if "named" in why]
+    assert (1, S, H, D) in [shape for shape, _ in kept]          # the output
+    assert not [shape for shape, _ in kept if shape[-1] == 128], kept
+    got = jax.grad(fn, tuple(range(len(args))))(*args)
+    want = jax.grad(ref, tuple(range(len(args))))(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+# ---- remat keeps attention's output and row statistics ---------------------
+#
+# `remat=True` wraps a block as `remat_block()`: the forward rules of
+# `ops/flash_attention.py` name the kernels' output and row log-sum-exp and
+# the block's policy saves them, so the backward pass recomputes the block
+# with no forward kernel in it.  Interpret mode, float32.  (Here and not
+# in `tests/test_transformer.py`, which is in the slow tier.)
+
+CFG = TransformerConfig(vocab_size=128, d_model=64, n_heads=4, n_layers=2,
+                        d_ff=128, max_seq_len=32, dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def toy_batch():
+    return jnp.asarray(np.random.RandomState(0).randint(
+        0, 128, size=(4, 32)).astype(np.int32))
+
+
+def _counted(fn, *names):
+    """What `fn()` added to the process counters `names`."""
+    from tensorflowonspark_tpu import trace
+    before = trace.counters().snapshot()
+    out = fn()
+    now = trace.counters().snapshot()
+    return out, [now.get(n, 0) - before.get(n, 0) for n in names]
+
+
+REMAT_COUNTERS = ("remat.attention.saved", "remat.attention.rerun")
+REMAT_MIXERS = {
+    "plain": dict(),
+    "gqa_window": dict(n_kv_heads=2, rope=True, sliding_window=16,
+                       layer_types=("sliding_attention", "full_attention")),
+    "latent": dict(rope=True, norm_type="rmsnorm", kv_lora_rank=16,
+                   q_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                   v_head_dim=16),
+}
+
+
+@pytest.mark.parametrize("mixer", list(REMAT_MIXERS))
+def test_remat_saves_attention_and_changes_no_bit_of_the_gradient(
+        toy_batch, mixer):
+    base = dataclasses.replace(CFG, attention_impl="flash",
+                               **REMAT_MIXERS[mixer])
+    impl = "_mla_fwd_impl" if mixer == "latent" else "_flash_fwd_impl"
+    grads = {}
+    for remat in (False, True):
+        model = Transformer(dataclasses.replace(base, remat=remat))
+        params = model.init(jax.random.key(0), toy_batch)["params"]
+
+        def loss(p):
+            return lm_loss(model.apply({"params": p}, toy_batch[:, :-1]),
+                           toy_batch[:, 1:])
+
+        text, counted = _counted(
+            lambda: str(jax.make_jaxpr(jax.grad(loss))(params)),
+            *REMAT_COUNTERS)
+        # a forward kernel a layer: the recomputed block holds none
+        assert text.count(f"name={impl}") == CFG.n_layers
+        # once a traced mixer, under `remat` only
+        assert counted == ([CFG.n_layers, 0] if remat else [0, 0])
+        # one program each: op by op the CPU rounds a fused chain its own way
+        grads[remat] = jax.jit(jax.grad(loss))(params)
+    same = jax.tree.map(lambda a, b: bool((a == b).all()),
+                        grads[False], grads[True])
+    assert jax.tree.all(same), same
+
+
+def test_remat_counts_a_mixer_on_the_dense_core_as_rerun():
+    cfg = dataclasses.replace(CFG, attention_impl="flash", remat=True)
+    x = jax.random.normal(jax.random.key(1), (2, 32, 64))
+    mask = jnp.ones((2, 32), bool).at[1, :4].set(False)
+    block = remat_block()(cfg)
+    params = block.init(jax.random.key(2), x)
+
+    def traced(module, **kw):
+        return _counted(lambda: jax.grad(lambda p: jnp.sum(
+            module.apply(p, x, **kw)))(params), *REMAT_COUNTERS)[1]
+
+    assert traced(block) == [1, 0]
+    assert traced(block, mask=mask) == [0, 1]     # a mask: the dense core
+    dense = dataclasses.replace(cfg, attention_impl="dense")
+    assert traced(remat_block()(dense)) == [0, 1]
+    plain = dataclasses.replace(cfg, remat=False)
+    assert traced(remat_block()(plain)) == [0, 0]  # `remat` is the switch
+    # the names are a policy's to use: without one nothing changes
+    np.testing.assert_array_equal(block.apply(params, x),
+                                  Block(plain).apply(params, x))
