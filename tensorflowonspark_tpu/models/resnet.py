@@ -16,6 +16,7 @@ import functools
 from typing import Any, Callable, Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -141,9 +142,10 @@ class ResNet(nn.Module):
         else:
             x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
         x = norm(name="norm_init")(x)
-        x = act(x)
-        if not self.small_inputs:
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        with jax.named_scope("stem"):
+            x = act(x)
+            if not self.small_inputs:
+                x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         # with output_stride, the last N stages trade their stride-2 for
         # dilation: stride 32 -> 16 dilates the last stage, -> 8 the last
         # two (the striding stages are 1..len-1; the stem contributes /4)

@@ -1291,7 +1291,9 @@ class MoEMLP(nn.Module):
 
         def experts(name, shape):
             if dropless:
-                return _ExpertKernel(shape, name=name)().astype(dtype)
+                kernel = _ExpertKernel(shape, name=name)()
+                with jax.named_scope("cast"):
+                    return kernel.astype(dtype)
             return self.param(f"{name}/kernel",
                               nn.initializers.lecun_normal(),
                               shape).astype(dtype)
@@ -1366,37 +1368,42 @@ class MoEMLP(nn.Module):
         T = B * S
         xt = x.reshape(T, D).astype(jnp.dtype(cfg.dtype))
         scores = probs.reshape(T, E)                               # f32
-        topk_p, topk_idx = jax.lax.top_k(scores, k)
-        moved = jnp.int32(0)
-        if bias is not None:
-            unbiased = topk_idx
-            _, topk_idx = jax.lax.top_k(scores + bias, k)
-            topk_p = jnp.take_along_axis(scores, topk_idx, axis=-1)
-            moved = jnp.sum(~jnp.any(
-                topk_idx[:, :, None] == unbiased[:, None, :], axis=-1))
-        if cfg.moe_scoring == "sigmoid":
-            topk_p = topk_p / (jnp.sum(topk_p, axis=-1, keepdims=True)
-                               + 1e-6)
-        elif k > 1:    # weights renormalised over the picks, as `topk` does
-            topk_p = topk_p / jnp.maximum(
-                jnp.sum(topk_p, axis=-1, keepdims=True), 1e-9)
-        if cfg.moe_routed_scale != 1.0:
-            topk_p = topk_p * cfg.moe_routed_scale
-        local = (topk_idx >= off) & (topk_idx < off + held)        # [T, k]
-        # rows sorted by held expert, the absent picks behind them all
-        key = jnp.where(local, topk_idx - off, held).reshape(T * k)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # row->pair
-        pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
-            jnp.arange(T * k, dtype=jnp.int32),
-            unique_indices=True).reshape(T, k)                    # pair->row
-        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
-                        dtype=jnp.int32)                           # [held]
-        xs = _moe_dispatch(xt, order, pos, local)                  # [T*k, D]
-        h = _activation(grouped_matmul(xs, wi, sizes), cfg.activation)
-        if up is not None:
-            h = h * grouped_matmul(xs, up, sizes)
-        out = grouped_matmul(h, wo, sizes)                         # [T*k, D]
-        y = _moe_combine(out, topk_p, order, pos, local)
+        with jax.named_scope("route"):
+            topk_p, topk_idx = jax.lax.top_k(scores, k)
+            moved = jnp.int32(0)
+            if bias is not None:
+                unbiased = topk_idx
+                _, topk_idx = jax.lax.top_k(scores + bias, k)
+                topk_p = jnp.take_along_axis(scores, topk_idx, axis=-1)
+                moved = jnp.sum(~jnp.any(
+                    topk_idx[:, :, None] == unbiased[:, None, :], axis=-1))
+            if cfg.moe_scoring == "sigmoid":
+                topk_p = topk_p / (jnp.sum(topk_p, axis=-1, keepdims=True)
+                                   + 1e-6)
+            elif k > 1:    # renormalised over the picks, as `topk` does
+                topk_p = topk_p / jnp.maximum(
+                    jnp.sum(topk_p, axis=-1, keepdims=True), 1e-9)
+            if cfg.moe_routed_scale != 1.0:
+                topk_p = topk_p * cfg.moe_routed_scale
+            local = (topk_idx >= off) & (topk_idx < off + held)    # [T, k]
+            # rows sorted by held expert, the absent picks behind them all
+            key = jnp.where(local, topk_idx - off, held).reshape(T * k)
+            # `order`: row -> pair; `pos`: pair -> row
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
+                jnp.arange(T * k, dtype=jnp.int32),
+                unique_indices=True).reshape(T, k)
+            sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :],
+                            axis=0, dtype=jnp.int32)               # [held]
+        with jax.named_scope("dispatch"):
+            xs = _moe_dispatch(xt, order, pos, local)              # [T*k, D]
+        with jax.named_scope("experts"):
+            h = _activation(grouped_matmul(xs, wi, sizes), cfg.activation)
+            if up is not None:
+                h = h * grouped_matmul(xs, up, sizes)
+            out = grouped_matmul(h, wo, sizes)                     # [T*k, D]
+        with jax.named_scope("combine"):
+            y = _moe_combine(out, topk_p, order, pos, local)
         n_local = jnp.sum(sizes)
         self.sow("intermediates", "moe_stats", jnp.stack([
             n_local, T * k - n_local, jnp.max(sizes), n_local / held,
@@ -1676,12 +1683,15 @@ def lm_loss(logits, targets, ignore_id=-1):
     """Causal-LM cross entropy written gather-free (one-hot einsum) so a
     vocab-sharded lm_head works under jit sharding propagation."""
     vocab = logits.shape[-1]
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    onehot = jax.nn.one_hot(jnp.maximum(targets, 0), vocab, dtype=jnp.float32)
-    gold = jnp.einsum("bsv,bsv->bs", logits, onehot)
-    mask = (targets != ignore_id).astype(jnp.float32)
-    return jnp.sum((logz - gold) * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with trace.loss_scope("lm_loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        onehot = jax.nn.one_hot(jnp.maximum(targets, 0), vocab,
+                                dtype=jnp.float32)
+        gold = jnp.einsum("bsv,bsv->bs", logits, onehot)
+        mask = (targets != ignore_id).astype(jnp.float32)
+        return (jnp.sum((logz - gold) * mask)
+                / jnp.maximum(jnp.sum(mask), 1.0))
 
 
 def build_transformer(**kwargs):

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tensorflowonspark_tpu import trace
+
 
 def _chunk_stats(h_c, kernel, tgt_c, mask_c):
     """Loss pieces for one chunk: (sum((logz - gold) * mask), logits fn)."""
@@ -75,6 +77,7 @@ def _pad_chunks(flat_h, flat_t, chunk_size, ignore_id):
     return flat_h, flat_t, n_chunks
 
 
+@trace.loss_scope("unembed_xent")
 def _fwd(hidden, kernel, targets, chunk_size, ignore_id):
     B, S, D = hidden.shape
     flat_h = hidden.reshape(B * S, D)
@@ -97,6 +100,7 @@ def _fwd(hidden, kernel, targets, chunk_size, ignore_id):
     return total / count, (hidden, kernel, targets, count)
 
 
+@trace.loss_scope("unembed_xent")
 def _bwd(chunk_size, ignore_id, res, g):
     hidden, kernel, targets, count = res
     B, S, D = hidden.shape

@@ -115,24 +115,26 @@ def make_train_step(loss_fn, optimizer, mesh=None, param_shardings=None,
 
         import optax
         fused_apply = getattr(optimizer, "apply", None)
-        if callable(fused_apply):
-            # single-pass fused optimizer: param write fused into the
-            # kernel's one pass over grad/moments (no apply_updates pass).
-            # Over a mesh each leaf's kernel is shard_mapped by the
-            # param's own sharding (Mosaic cannot be GSPMD-partitioned).
-            params, opt_state = fused_apply(
-                grads, state.opt_state, state.params,
-                shardings=fused_param_sh)
-        else:
-            updates, opt_state = optimizer.update(grads, state.opt_state,
-                                                  state.params)
-            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            if callable(fused_apply):
+                # single-pass fused optimizer: param write fused into the
+                # kernel's one pass over grad/moments (no apply_updates
+                # pass).  Over a mesh each leaf's kernel is shard_mapped by
+                # the param's own sharding (Mosaic cannot be
+                # GSPMD-partitioned).
+                params, opt_state = fused_apply(
+                    grads, state.opt_state, state.params,
+                    shardings=fused_param_sh)
+            else:
+                updates, opt_state = optimizer.update(
+                    grads, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+            # the fused path computes this same reduction for its clip
+            # scale; XLA CSEs the two, so the metric stays free there
+            grad_norm = optax.global_norm(grads)
         new_state = TrainState(step=state.step + 1, params=params,
                                opt_state=opt_state)
-        # the fused path computes this same reduction for its clip scale;
-        # XLA CSEs the two, so the metric stays free there
-        metrics = {"loss": loss,
-                   "grad_norm": optax.global_norm(grads), **aux}
+        metrics = {"loss": loss, "grad_norm": grad_norm, **aux}
         return new_state, metrics
 
     fused_param_sh = None   # NamedSharding per param leaf, over a mesh

@@ -96,6 +96,36 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     mixer.calls.conv  mixer.calls.latent (what a step program's sequence
     mixers are)  moe.shared.calls (its shared experts)
 
+Scopes of the train step in the DEVICE trace (``jax.named_scope``: metadata
+on the operations they cover, a component of each one's ``op_name`` beside
+the flax modules' names; no operation, no switch; the persistent compile
+cache keys on them, ``util.enable_compile_cache``).  The benchmark splits
+the step's device time by these paths; after each scope the metric of
+``BENCHMARK.json`` that reads it::
+
+    route      moe_route_ms.*: top-k, weights, sort key, row positions,
+               group sizes
+    dispatch   moe_dispatch_ms.*: the row gather and its backward
+    combine    moe_dispatch_ms.*: the weighted sum back, and its backward
+    experts    moe_experts_outside_gmm_ms.*: the grouped matmuls with the
+               activation between them (the kernels themselves taken off)
+    cast       moe_experts_outside_gmm_ms.*: the held experts' weights to
+               the compute type
+        (these five in ``models.transformer.MoEMLP``, dropless, under
+        ``layer_<n>/moe/``; with what stays filed at the layer they sum
+        to moe_outside_gmm_ms.*)
+    unembed_xent   head_ms.moe/.lfm/.joy: ``ops.xent.fused_unembed_xent``,
+               forward and backward rule, the head's product fused into
+               the loss
+    lm_loss    no metric of its own, lowers device_unnamed_pct:
+               ``models.transformer.lm_loss`` (both losses through
+               :func:`loss_scope`)
+    optimizer  optimizer_outside_kernel_ms: ``parallel.train``'s step, the
+               optimizer's update and the gradients' global norm (the fused
+               kernels, ``optimizer/adamw_fused``, are adamw_kernel_ms.*'s)
+    stem       no metric of its own, lowers device_unnamed_pct.img:
+               ``models.resnet.ResNet``, the stem's activation and max-pool
+
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`
 (the ``trace-span`` graftcheck ResourceSpec enforces this statically).
@@ -453,6 +483,22 @@ def span_ended(name, seconds, cause=None, **attrs):
     afterwards (`jax.monitoring`'s compile events)."""
     t1 = _now_ms()
     process().record(name, t1 - seconds * 1e3, t1, cause, attrs)
+
+
+@contextlib.contextmanager
+def loss_scope(name):
+    """`jax.named_scope(name)` for code that a differentiated function
+    calls at its top, outside every module (a loss), as a context manager
+    or a decorator.  JAX writes a transformation around the OUTERMOST
+    scope of the path (`jvp(Transformer)/layer_0/...`), and there `name`
+    would read `jvp(name)`, `transpose(jvp(name))`: one of JAX's own markers
+    to whoever splits a path by its components.  So the scope is entered
+    twice: the outer one takes the marker, the inner one stays the plain
+    component `name` (`jvp(name)/name/...`)."""
+    import jax
+
+    with jax.named_scope(name), jax.named_scope(name):
+        yield
 
 
 def report(source=None, since=0):

@@ -7,6 +7,7 @@ import errno
 import logging
 import os
 import random
+import re
 import socket
 import time
 
@@ -229,12 +230,24 @@ def enable_compile_cache():
 
     ONE rule, for every node bootstrap (`chip_smoke.py`, the cells of
     `benchmark/`): where ``JAX_COMPILATION_CACHE_DIR`` is set,
-    JAX already honours it and nothing is set in code; where it is not,
-    the cache lives in ``.jax_cache/`` at the root of this checkout
+    JAX already honours it and no directory is set in code; where it is
+    not, the cache lives in ``.jax_cache/`` at the root of this checkout
     (git-ignored).  The path is part of the cache key, so it is fixed and
     absolute — never under a tempdir, a pid or the clock — and never
     relative: executors chdir into per-run scratch directories.  Call
     before the first compile of the process.
+
+    In both cases the names in the program are part of what the cache keys
+    on (``jax_compilation_cache_include_metadata_in_key``).  JAX's default
+    strips every location before it hashes a module, and a
+    `jax.named_scope` lives only in locations: two programs that differ in
+    a scope's name alone then share one entry, and the second is handed the
+    first's executable with the first's ``op_name``s, which are what the
+    device trace is split by (`benchmark/tracered.region_of`).  Locations
+    hold file names and line numbers too, so an edit that moves a traced
+    line compiles once more; the checkout's own root is cut from the file
+    names (``jax_hlo_source_file_canonicalization_regex``), so the same
+    tree at another path still hits.
 
     It also makes the process's set-up visible from inside: every
     duration of a millisecond or more that JAX reports under
@@ -243,15 +256,17 @@ def enable_compile_cache():
     retrieval) becomes a `trace` span named after the event's last path
     component.
     """
+    import jax
+
     _trace_compile_events()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(root + os.sep))
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache")
-    import jax
-
+    path = os.path.join(root, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 

@@ -1,4 +1,5 @@
 import os
+import re
 
 import pytest
 
@@ -43,9 +44,17 @@ def test_bind_socket_port_list():
         s1.close()
 
 
-def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch, tmp_path):
+# what the rule sets wherever the cache lives: the names in the program are
+# part of the key, the checkout's root is not (tests/test_trace_scopes.py
+# compiles through it)
+CACHE_KEY_OPTIONS = ["jax_compilation_cache_include_metadata_in_key",
+                     "jax_hlo_source_file_canonicalization_regex"]
+
+
+def test_compile_cache_placed_from_outside_sets_no_directory(monkeypatch,
+                                                             tmp_path):
     """JAX_COMPILATION_CACHE_DIR set: jax honours it itself, the helper
-    touches no config."""
+    sets what the cache keys on and no directory."""
     import jax
 
     calls = []
@@ -53,7 +62,7 @@ def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch, tmp_path):
                         lambda *a: calls.append(a))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert util.enable_compile_cache() == str(tmp_path)
-    assert calls == []
+    assert [a[0] for a in calls] == CACHE_KEY_OPTIONS
 
 
 def test_compile_cache_default_is_fixed_inside_the_checkout(monkeypatch):
@@ -72,7 +81,15 @@ def test_compile_cache_default_is_fixed_inside_the_checkout(monkeypatch):
     path = util.enable_compile_cache()
     assert path == os.path.join(repo, ".jax_cache")
     assert not path.startswith(tempfile.gettempdir())
-    assert [a[1] for a in calls] == [path]
+    assert [a[0] for a in calls] == CACHE_KEY_OPTIONS + [
+        "jax_compilation_cache_dir"]
+    assert calls[-1][1] == path
+    assert calls[0][1] is True
+    # the same tree at another path keeps its entries: its root is cut
+    # from the file names that the key now holds
+    assert re.sub(calls[1][1], "", os.path.join(
+        repo, "tensorflowonspark_tpu", "util.py")) == os.path.join(
+            "tensorflowonspark_tpu", "util.py")
     monkeypatch.chdir(tempfile.gettempdir())
     assert util.enable_compile_cache() == path
 
