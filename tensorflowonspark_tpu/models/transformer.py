@@ -46,6 +46,8 @@ class TransformerConfig:
     dtype: str = "bfloat16"
     rope: bool = False            # rotary position embeddings instead of
     # a learned absolute pos_embed table
+    learned_positions: bool = True  # without rope: a learned pos_embed
+    # table (False: no positions at all, order comes from the mixers)
     rope_theta: float = 10000.0
     head_dim: Optional[int] = None  # None = d_model // n_heads; set where
     # n_heads * head_dim is not d_model (out is [n_heads*head_dim, d_model])
@@ -54,7 +56,19 @@ class TransformerConfig:
     # the last `sliding_window` keys only and rotates by `rope_local_theta`;
     # a conv layer mixes the sequence with `ShortConv`, not attention
     sliding_window: Optional[int] = None
+    layer_pattern: Optional[str] = None  # one character a layer, each layer
+    # holding ONE part under one norm, `x + part(norm(x))` (Nemotron-H's
+    # `hybrid_override_pattern`): "M" a Mamba-2 mixer, "*" attention, "E"
+    # sparse experts (MoEMLP); None = every layer holds a mixer
+    # (`layer_types`) and a feed-forward part
     conv_kernel: int = 3          # taps of a conv layer's depthwise filter
+    # (and of a Mamba-2 mixer's, over x, B and C, with a bias)
+    ssm_heads: int = 0            # Mamba-2 mixer: heads of `ssm_head_dim`
+    # lanes of x, each reading the group h // (heads / groups) of the
+    # `ssm_groups` groups of B and C of `ssm_state` lanes (`Mamba2Mixer`)
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
     qk_norm: bool = False         # RMSNorm over head_dim on every query and
     # key head (a scale of head_dim each), before the rotation
     kv_lora_rank: Optional[int] = None  # latent attention (MLA): keys and
@@ -97,8 +111,8 @@ class TransformerConfig:
     # added to the scores for the CHOICE of the k experts only; the weights
     # come from the scores alone, so its gradient is zero and an optimizer
     # without weight decay leaves it where it is
-    moe_shared_experts: int = 0   # dropless: n shared experts, one gated
-    # MLP of n x moe_d_ff that every token passes, added to the routed sum;
+    moe_shared_experts: int = 0   # dropless: n shared experts, one MLP of
+    # n x moe_d_ff that every token passes, added to the routed sum;
     # with `moe_experts_held` every chip computes it in full, alike
     moe_routed_scale: float = 1.0  # dropless: the routed sum's factor
     # (the weights over their sum, times this)
@@ -126,7 +140,8 @@ class TransformerConfig:
     ulysses_axis: Optional[str] = None  # all-to-all sequence parallelism
     sp_axis: Optional[str] = None  # Megatron-SP: shard residual stream's
     # sequence dim over this axis between blocks (usually "tp")
-    attention_impl: str = "auto"  # auto | flash (pallas) | dense
+    attention_impl: str = "auto"  # auto | flash (pallas) | dense; the
+    # Mamba-2 mixers' SSD scan follows it (ops/ssd.py kernels | jax.numpy)
     use_bias: bool = False        # bias terms on qkv/out/mlp denses
     # (True matches GPT-2-family checkpoints; see convert.py)
     ln_eps: float = 1e-6          # layernorm epsilon (GPT-2 ckpts: 1e-5)
@@ -136,6 +151,7 @@ class TransformerConfig:
     # tail; convergence-equivalent for pre-LN decoders)
     norm_style: str = "pre"       # pre-LN (GPT/LLaMA) | post-LN (BERT)
     activation: str = "gelu_tanh"  # gelu_tanh | gelu_exact | relu | silu
+    # | relu2 (relu squared)
     mlp_style: str = "plain"      # plain (wo(act(wi x))) | gated (LLaMA
     # GLU: wo(act(wi_gate x) * (wi_up x)); SwiGLU with activation='silu')
     decode: bool = False          # autoregressive mode: kv cache of
@@ -204,10 +220,23 @@ class TransformerConfig:
             raise ValueError(f"layer_types {sorted(bad)} not in {LAYER_KINDS}")
         if SLIDING in kinds and not self.sliding_window:
             raise ValueError("sliding_attention layers need sliding_window")
-        if CONV in kinds and (self.ring_attention_axis or self.ulysses_axis):
+        pattern = self.layer_pattern or ""
+        if pattern and (kinds or len(pattern) != self.n_layers
+                        or set(pattern) - set(PARTS)):
+            raise ValueError(
+                f"layer_pattern {pattern!r} needs n_layers={self.n_layers} "
+                f"characters of {PARTS!r} and no layer_types")
+        if "E" in pattern and not self.num_experts:
+            raise ValueError("layer_pattern 'E' layers need num_experts")
+        if MAMBA in pattern and not (self.ssm_heads and self.ssm_head_dim
+                                     and self.ssm_state):
+            raise ValueError("Mamba layers need ssm_heads, ssm_head_dim and "
+                             "ssm_state")
+        if ((CONV in kinds or MAMBA in pattern)
+                and (self.ring_attention_axis or self.ulysses_axis)):
             raise NotImplementedError(
-                "conv layers shift along a sequence that is whole on the "
-                "device: not with sequence-parallel attention")
+                "conv and Mamba layers run along a sequence that is whole "
+                "on the device: not with sequence-parallel attention")
         latent = self.kv_lora_rank is not None
         if latent and not (self.q_lora_rank and self.qk_nope_head_dim
                            and self.qk_rope_head_dim and self.v_head_dim):
@@ -227,6 +256,8 @@ class TransformerConfig:
             ("v_head_dim", bool(self.v_head_dim)),
             ("rope_interleave", self.rope_interleave),
             ("moe_shared_experts", bool(self.moe_shared_experts)),
+            ("layer_pattern", bool(pattern)),
+            ("learned_positions=False", not self.learned_positions),
             ("moe_routed_scale", self.moe_routed_scale != 1.0),
             ("mtp_modules", bool(self.mtp_modules)),
             ("mtp_loss_weight", bool(self.mtp_loss_weight)),
@@ -241,14 +272,16 @@ class TransformerConfig:
         if self.decode and missing:
             raise NotImplementedError(
                 f"decode=True with {', '.join(missing)}: the kv cache keeps "
-                "no window, no latent and no conv layer's last rows, its "
-                "incremental attention no layer kinds and no query/key "
-                "norms, routing has no incremental form here and a "
-                "prediction module no consumer (ROADMAP R1/R5)")
+                "no window, no latent, no conv layer's last rows and no "
+                "Mamba state, its incremental attention no layer kinds and "
+                "no query/key norms, routing has no incremental form here "
+                "and a prediction module no consumer (ROADMAP R1/R5)")
 
 
 FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
 LAYER_KINDS = (FULL, SLIDING, CONV)
+MAMBA = "M"
+PARTS = "M*E"         # `layer_pattern`: Mamba-2, attention, experts
 
 
 def rope_inv_freq(head_dim, theta, yarn_factor=1.0, original_max=0,
@@ -709,14 +742,25 @@ class Attention(nn.Module):
         return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
 
+def causal_conv(g, taps, bias=None):
+    """A causal depthwise filter over `g` [B, S, C]: `taps` [C, L], one
+    tap vector a channel, the last tap on the position itself, zeros left
+    of the row's start, then `bias` [C] where given.  L shifted
+    multiply-adds in `g`'s type: a depthwise convolution of C groups is no
+    MXU work, and XLA fuses the sum with what surrounds it."""
+    S, L = g.shape[1], taps.shape[1]
+    s = g * taps[:, L - 1]
+    for back in range(1, min(L, S)):     # position t reads t - back
+        s = s + jnp.pad(g[:, :S - back],
+                        ((0, 0), (back, 0), (0, 0))) * taps[:, L - 1 - back]
+    return s if bias is None else s + bias
+
+
 class ShortConv(nn.Module):
     """Gated short convolution, a conv layer's sequence mixer (LFM2):
     `[b, c, z] = split3(in_proj(x))`, `g = b * z`, a causal depthwise
-    filter of `conv_kernel` taps over `g` (`taps` [D, L], one tap vector a
-    channel, the last tap on the position itself, zeros left of the row's
-    start), `out_proj(c * s)`.  The filter is L shifted multiply-adds over
-    [B, S, D] in the activation type: a depthwise convolution of D groups
-    is no MXU work, and XLA fuses the sum with the two gates."""
+    filter of `conv_kernel` taps over `g` (`taps` [D, L], `causal_conv`),
+    `out_proj(c * s)`."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -728,7 +772,6 @@ class ShortConv(nn.Module):
         trace.counters().inc("mixer.calls.conv")        # once a traced call
         dtype = jnp.dtype(cfg.dtype)
         D, L = cfg.d_model, cfg.conv_kernel
-        S = x.shape[1]
 
         def proj(name, features, h):
             return QuantDense(features, use_bias=cfg.use_bias, name=name,
@@ -737,12 +780,84 @@ class ShortConv(nn.Module):
         b, c, z = jnp.split(proj("in_proj", 3 * D, x), 3, axis=-1)
         taps = self.param("taps", nn.initializers.lecun_normal(),
                           (D, L), jnp.float32).astype(dtype)
-        g = b * z
-        s = g * taps[:, L - 1]
-        for back in range(1, min(L, S)):     # position t reads t - back
-            s = s + jnp.pad(g[:, :S - back],
-                            ((0, 0), (back, 0), (0, 0))) * taps[:, L - 1 - back]
-        return proj("out_proj", D, c * s)
+        return proj("out_proj", D, c * causal_conv(b * z, taps))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's: the inverse softplus of steps drawn log-uniform over
+    [0.001, 0.1], floored at 1e-4."""
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(1e-1))), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's: A = -(1, 2, ..., heads)."""
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=dtype))
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; Nemotron-H's layer
+    `M`): `[z | xBC | dt] = in_proj(x)`; `xBC = silu(causal_conv(xBC) +
+    bias)` (`conv_kernel` taps); `[x | B | C] = xBC`, x as `ssm_heads`
+    heads of `ssm_head_dim`, B and C as `ssm_groups` groups of
+    `ssm_state`; `dt = softplus(dt + dt_bias)`, `A = -exp(A_log)`;
+    `y = SSD(x, dt, A, B, C) + D x` (`ops.ssd`: head h reads group
+    h // (heads / groups)); `y = RMSNorm(y * silu(z))` over groups of
+    heads x head_dim / groups lanes, one scale of heads x head_dim;
+    `out_proj(y)`.  The SSD scan takes the Pallas kernels where
+    `attention_impl` asks for them (`auto` on the chip) and no mesh is
+    set, the same algorithm in `jax.numpy` elsewhere."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from tensorflowonspark_tpu.ops.ssd import ssd
+
+        cfg = self.cfg
+        if mask is not None:
+            raise NotImplementedError(
+                "key-padding masks are not supported in Mamba layers")
+        trace.counters().inc("mixer.calls.mamba")       # once a traced call
+        dtype = jnp.dtype(cfg.dtype)
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, width = H * P, H * P + 2 * G * N
+        nb, S, _ = x.shape
+        f32 = jnp.float32
+
+        def proj(name, features, h):
+            return QuantDense(features, use_bias=cfg.use_bias, name=name,
+                              dtype=dtype, impl=cfg.quant_matmul_impl)(h)
+
+        z, xbc, dt = jnp.split(proj("in_proj", inner + width + H, x),
+                               [inner, inner + width], axis=-1)
+        taps = self.param("conv_taps", nn.initializers.lecun_normal(),
+                          (width, cfg.conv_kernel), f32).astype(dtype)
+        bias = self.param("conv_bias", nn.initializers.zeros, (width,),
+                          f32).astype(dtype)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,), f32)
+        a_log = self.param("A_log", _a_log_init, (H,), f32)
+        skip = self.param("D", nn.initializers.ones, (H,), f32)
+        scale = self.param("norm", nn.initializers.ones, (inner,), f32)
+        with jax.named_scope("conv"):
+            xbc = nn.silu(causal_conv(xbc, taps, bias))
+        xs, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        xs = xs.reshape(nb, S, H, P)
+        with jax.named_scope("ssd"):
+            y = ssd(xs, jax.nn.softplus(dt.astype(f32) + dt_bias),
+                    -jnp.exp(a_log), b.reshape(nb, S, G, N),
+                    c.reshape(nb, S, G, N),
+                    kernel=_flash_wanted(cfg, mask)
+                    and _ambient_mesh() is None)
+        with jax.named_scope("gate_norm"):
+            y = y.astype(f32) + skip[:, None] * xs.astype(f32)
+            y = (y.reshape(nb, S, inner) * nn.silu(z.astype(f32))).reshape(
+                nb, S, G, inner // G)
+            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1,
+                                           keepdims=True) + cfg.ln_eps)
+            y = (y.reshape(nb, S, inner) * scale).astype(dtype)
+        return proj("out_proj", cfg.d_model, y)
 
 
 def _kv_quantize(x):
@@ -1096,8 +1211,10 @@ def _activation(x, name):
         return nn.relu(x)
     if name == "silu":
         return nn.silu(x)
+    if name == "relu2":
+        return jnp.square(nn.relu(x))
     raise ValueError(f"activation={name!r} not in "
-                     "('gelu_tanh', 'gelu_exact', 'relu', 'silu')")
+                     "('gelu_tanh', 'gelu_exact', 'relu', 'silu', 'relu2')")
 
 
 class DenseMLP(nn.Module):
@@ -1445,8 +1562,7 @@ class MoEMLP(nn.Module):
             if cfg.moe_shared_experts:
                 trace.counters().inc("moe.shared.calls")  # a traced call
                 y = y + DenseMLP(dataclasses.replace(
-                    cfg, d_ff=cfg.moe_shared_experts * F),
-                    name="shared")(x)
+                    cfg, d_ff=cfg.moe_shared_experts * F), name="shared")(x)
             return y
 
         def expert_mlp(xe):
@@ -1657,10 +1773,13 @@ class Block(nn.Module):
     """One transformer block; ``cfg.norm_style`` picks the residual form:
     pre-LN ``x + f(ln(x))`` (GPT/LLaMA-style, the training-stable default)
     or post-LN ``ln(x + f(x))`` (original-BERT-style, needed for faithful
-    BERT checkpoints — see convert.from_hf_bert)."""
+    BERT checkpoints — see convert.from_hf_bert).  With `part` (a character
+    of `TransformerConfig.layer_pattern`) the block holds that one part,
+    pre-LN under one norm: ``x + part(norm(x))``."""
     cfg: TransformerConfig
     use_moe: bool = False
     layer_type: str = FULL
+    part: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, mask=None):
@@ -1668,6 +1787,16 @@ class Block(nn.Module):
         if cfg.norm_style not in ("pre", "post"):
             raise ValueError(
                 f"norm_style={cfg.norm_style!r} not in ('pre', 'post')")
+        if self.part is not None:
+            if cfg.norm_style != "pre":
+                raise ValueError("a layer of one part is pre-LN")
+            x = _sp_constrain(x, cfg)
+            h = _make_ln(cfg, "norm")(x)
+            if self.part == "E":
+                return x + MoEMLP(cfg, name="moe")(h)
+            mixer = (Mamba2Mixer(cfg, name="mamba") if self.part == MAMBA
+                     else Attention(cfg, name="attn"))
+            return x + mixer(h, mask=mask)
         ln1 = _make_ln(cfg, "ln1")
         ln2 = _make_ln(cfg, "ln2")
         attn = (ShortConv(cfg, name="conv") if self.layer_type == CONV
@@ -1695,9 +1824,12 @@ def remat_block():
     runs once a step and not twice.  A mixer on the dense core (a mask,
     `attention_impl='dense'`, latent attention under a mesh) names nothing
     and is recomputed whole; `remat.attention.saved` / `.rerun` count
-    which a traced mixer was."""
+    which a traced mixer was.  A Mamba-2 mixer's SSD kernels name their
+    output and chunk states the same way (`ssd_out`, `ssd_states`), so the
+    recomputed block holds no `ssd_fwd` either."""
     return nn.remat(Block, policy=jax.checkpoint_policies
-                    .save_only_these_names("flash_out", "flash_lse"))
+                    .save_only_these_names("flash_out", "flash_lse",
+                                           "ssd_out", "ssd_states"))
 
 
 class Transformer(nn.Module):
@@ -1716,7 +1848,8 @@ class Transformer(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="token_embed",
                          dtype=dtype)
         x = _embed_out_constrain(embed(tokens), cfg)
-        if not cfg.rope:  # RoPE rotates q/k inside attention instead
+        if not cfg.rope and cfg.learned_positions:  # RoPE rotates q/k
+            # inside attention instead
             pos_ids = jnp.arange(tokens.shape[1])
             if cfg.decode:
                 # incremental steps look up absolute positions
@@ -1737,15 +1870,19 @@ class Transformer(nn.Module):
                            dtype=dtype)(pos_ids)
             x = x + pos
         block_cls = remat_block() if cfg.remat else Block
-        for i in range(cfg.n_layers):
-            # every k-th layer is MoE, counting so that moe_every=1 means
-            # every layer (k=2 keeps the old odd-layer placement); the
-            # first `moe_dense_layers` stay dense
-            use_moe = (cfg.num_experts > 0 and i >= cfg.moe_dense_layers
-                       and i % cfg.moe_every == cfg.moe_every - 1)
-            kind = cfg.layer_types[i] if cfg.layer_types else FULL
-            x = block_cls(cfg, use_moe=use_moe, layer_type=kind,
-                          name=f"layer_{i}")(x)
+        if cfg.layer_pattern:
+            for i, part in enumerate(cfg.layer_pattern):
+                x = block_cls(cfg, part=part, name=f"layer_{i}")(x)
+        else:
+            for i in range(cfg.n_layers):
+                # every k-th layer is MoE, counting so that moe_every=1
+                # means every layer (k=2 keeps the old odd-layer placement);
+                # the first `moe_dense_layers` stay dense
+                use_moe = (cfg.num_experts > 0 and i >= cfg.moe_dense_layers
+                           and i % cfg.moe_every == cfg.moe_every - 1)
+                kind = cfg.layer_types[i] if cfg.layer_types else FULL
+                x = block_cls(cfg, use_moe=use_moe, layer_type=kind,
+                              name=f"layer_{i}")(x)
         ahead = ()
         if cfg.mtp_modules and (return_hidden or self.is_initializing()):
             ahead = self._predict_ahead(embed, tokens, x, block_cls)

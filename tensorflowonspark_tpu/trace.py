@@ -96,8 +96,11 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     ``[n, 128]`` copy of it; Lion's leaves count under the same names);
     and of one that traces a transformer block
     (``models.transformer``, the same way): mixer.calls.attention
-    mixer.calls.conv  mixer.calls.latent (what a step program's sequence
-    mixers are)  moe.shared.calls (its shared experts)
+    mixer.calls.conv  mixer.calls.latent  mixer.calls.mamba (what a step
+    program's sequence mixers are)  moe.shared.calls (its shared experts);
+    of one that traces a Mamba-2 mixer's scan (``ops.ssd``, the same
+    way): ssd.calls.kernel  ssd.calls.reference (the Pallas kernels, or
+    the same chunked algorithm in jax.numpy)
 
 Scopes of the train step in the DEVICE trace (``jax.named_scope``: metadata
 on the operations they cover, a component of each one's ``op_name`` beside
@@ -128,6 +131,16 @@ the step's device time by these paths; after each scope the metric of
                kernels, ``optimizer/adamw_fused``, are adamw_kernel_ms.*'s)
     stem       no metric of its own, lowers device_unnamed_pct.img:
                ``models.resnet.ResNet``, the stem's activation and max-pool
+    conv       mamba_outside_ssd_ms.nem: the causal depthwise filter over
+               x, B, C with its bias, and the SiLU after it
+    ssd        ssd_kernel_ms.nem (its ``ssd_fwd`` / ``ssd_bwd`` kernels),
+               mamba_outside_ssd_ms.nem (the rest): the scan with its
+               steps and running sums, forward and backward
+    gate_norm  mamba_outside_ssd_ms.nem: the skip term, the gate and the
+               norm by groups
+        (these three in ``models.transformer.Mamba2Mixer``, under
+        ``layer_<n>/mamba/``; with the two projections they are what
+        mamba_outside_ssd_ms.nem reads, less the kernels)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

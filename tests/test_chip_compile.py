@@ -82,7 +82,8 @@ def _compile(fn, *args):
 
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "mla_fwd", "mla_dq",
-           "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm")
+           "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm", "ssd_fwd",
+           "ssd_bwd")
 
 
 def _kernel_calls(text):
@@ -311,6 +312,41 @@ def test_grouped_matmul_lowers(chip, cell):
     # sum's own forward is dead code)
     assert text.count("tpu_custom_call") >= 5
     assert _kernels(text) == {"moe_gmm", "moe_tgmm"}
+
+
+def test_a_rematerialised_mamba_layer_runs_the_scan_kernel_once(
+        chip, monkeypatch):
+    """Two Mamba-2 layers of `nemotron-labs-twotower-30b-a3b.fed_s8k_b2`
+    (64 heads of 64 in 8 groups of a state of 128, chunks of 128, 2 rows of
+    8192) under `remat=True`, the gradient: the SSD kernels lower at the
+    cell's widths, and the block's policy keeps their output and chunk
+    states (`ssd_out`, `ssd_states`), so the step holds ONE `ssd_fwd` a
+    layer beside its `ssd_bwd`."""
+    import tensorflowonspark_tpu.ops as ops
+    from tensorflowonspark_tpu.models.transformer import (
+        Transformer, TransformerConfig, lm_loss)
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=2688, n_heads=32, n_kv_heads=2,
+        head_dim=128, n_layers=2, max_seq_len=8192, rope=False,
+        learned_positions=False, layer_pattern="MM", ssm_heads=64,
+        ssm_head_dim=64, ssm_groups=8, ssm_state=128, conv_kernel=4,
+        norm_type="rmsnorm", ln_eps=1e-5, dtype="bfloat16",
+        attention_impl="flash", remat=True)
+    model = Transformer(cfg)
+    tokens = chip((2, 8192), jnp.int32)
+    one = tokens.sharding
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def loss(p, t):
+        return lm_loss(model.apply({"params": p}, t), t)
+
+    calls = _kernel_calls(_compile(jax.grad(loss), params, tokens))
+    assert sorted(calls) == ["ssd_bwd"] * 2 + ["ssd_fwd"] * 2, calls
 
 
 def test_short_conv_mixer_compiles_to_fusions_and_no_convolution(chip):
