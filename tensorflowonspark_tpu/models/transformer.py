@@ -805,13 +805,15 @@ class Mamba2Mixer(nn.Module):
     `y = SSD(x, dt, A, B, C) + D x` (`ops.ssd`: head h reads group
     h // (heads / groups)); `y = RMSNorm(y * silu(z))` over groups of
     heads x head_dim / groups lanes, one scale of heads x head_dim;
-    `out_proj(y)`.  The SSD scan takes the Pallas kernels where
-    `attention_impl` asks for them (`auto` on the chip) and no mesh is
-    set, the same algorithm in `jax.numpy` elsewhere."""
+    `out_proj(y)`.  The SSD scan and the skip, gate and norm
+    (`ops.gate_norm`) take their Pallas kernels where `attention_impl`
+    asks for them (`auto` on the chip) and no mesh is set, the same
+    arithmetic in `jax.numpy` elsewhere."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x, mask=None):
+        from tensorflowonspark_tpu.ops.gate_norm import gate_norm
         from tensorflowonspark_tpu.ops.ssd import ssd
 
         cfg = self.cfg
@@ -830,8 +832,8 @@ class Mamba2Mixer(nn.Module):
             return QuantDense(features, use_bias=cfg.use_bias, name=name,
                               dtype=dtype, impl=cfg.quant_matmul_impl)(h)
 
-        z, xbc, dt = jnp.split(proj("in_proj", inner + width + H, x),
-                               [inner, inner + width], axis=-1)
+        zxbcdt = proj("in_proj", inner + width + H, x)
+        _, xbc, dt = jnp.split(zxbcdt, [inner, inner + width], axis=-1)
         taps = self.param("conv_taps", nn.initializers.lecun_normal(),
                           (width, cfg.conv_kernel), f32).astype(dtype)
         bias = self.param("conv_bias", nn.initializers.zeros, (width,),
@@ -843,20 +845,15 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("conv"):
             xbc = nn.silu(causal_conv(xbc, taps, bias))
         xs, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
-        xs = xs.reshape(nb, S, H, P)
+        kernel = _flash_wanted(cfg, mask) and _ambient_mesh() is None
         with jax.named_scope("ssd"):
-            y = ssd(xs, jax.nn.softplus(dt.astype(f32) + dt_bias),
+            y = ssd(xs.reshape(nb, S, H, P),
+                    jax.nn.softplus(dt.astype(f32) + dt_bias),
                     -jnp.exp(a_log), b.reshape(nb, S, G, N),
-                    c.reshape(nb, S, G, N),
-                    kernel=_flash_wanted(cfg, mask)
-                    and _ambient_mesh() is None)
-        with jax.named_scope("gate_norm"):
-            y = y.astype(f32) + skip[:, None] * xs.astype(f32)
-            y = (y.reshape(nb, S, inner) * nn.silu(z.astype(f32))).reshape(
-                nb, S, G, inner // G)
-            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1,
-                                           keepdims=True) + cfg.ln_eps)
-            y = (y.reshape(nb, S, inner) * scale).astype(dtype)
+                    c.reshape(nb, S, G, N), kernel=kernel)
+        with jax.named_scope("gate_norm"):       # z: in_proj's first lanes
+            y = gate_norm(y.reshape(nb, S, inner), xs, zxbcdt, skip, scale,
+                          G, cfg.ln_eps, kernel=kernel)
         return proj("out_proj", cfg.d_model, y)
 
 

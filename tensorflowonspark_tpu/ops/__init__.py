@@ -29,6 +29,10 @@ interpret-mode path so the same kernels are testable on the CPU mesh.
   over [occupied context pages || chunk]; O(chunk) traffic, no dense
   [B, max_seq] kv view (see ops/paged_prefill.py; the default S>1
   paged path, TransformerConfig.paged_prefill_impl)
+- gate_norm : the Mamba-2 mixer's skip term, gate and norm by groups —
+  one read of y, x, z and one write a pass, float32 inside the kernel,
+  the backward recomputing the norm in VMEM (see ops/gate_norm.py; beside
+  the SSD scan's kernels, ops/ssd.py, under `attention_impl`)
 - quant_matmul : weight-stationary matmul over int8 / nibble-packed
   int4 kernels — weight tiles dequantize in VMEM (per-channel or
   per-group scales), the dense bf16/f32 kernel never exists in HBM

@@ -100,7 +100,10 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     program's sequence mixers are)  moe.shared.calls (its shared experts);
     of one that traces a Mamba-2 mixer's scan (``ops.ssd``, the same
     way): ssd.calls.kernel  ssd.calls.reference (the Pallas kernels, or
-    the same chunked algorithm in jax.numpy)
+    the same chunked algorithm in jax.numpy); and of one that traces its
+    skip, gate and norm (``ops.gate_norm``, the same way):
+    gate_norm.calls.kernel  gate_norm.calls.reference (the
+    ``gate_norm_fwd`` / ``gate_norm_bwd`` kernels, or jax.numpy)
 
 Scopes of the train step in the DEVICE trace (``jax.named_scope``: metadata
 on the operations they cover, a component of each one's ``op_name`` beside
@@ -136,8 +139,10 @@ the step's device time by these paths; after each scope the metric of
     ssd        ssd_kernel_ms.nem (its ``ssd_fwd`` / ``ssd_bwd`` kernels),
                mamba_outside_ssd_ms.nem (the rest): the scan with its
                steps and running sums, forward and backward
-    gate_norm  mamba_outside_ssd_ms.nem: the skip term, the gate and the
-               norm by groups
+    gate_norm  mamba_gate_norm_ms.nem (within mamba_outside_ssd_ms.nem):
+               the skip term, the gate and the norm by groups, the
+               ``gate_norm_fwd`` / ``gate_norm_bwd`` kernels with what
+               surrounds them
         (these three in ``models.transformer.Mamba2Mixer``, under
         ``layer_<n>/mamba/``; with the two projections they are what
         mamba_outside_ssd_ms.nem reads, less the kernels)

@@ -83,7 +83,7 @@ def _compile(fn, *args):
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "mla_fwd", "mla_dq",
            "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm", "ssd_fwd",
-           "ssd_bwd")
+           "ssd_bwd", "gate_norm_fwd", "gate_norm_bwd")
 
 
 def _kernel_calls(text):
@@ -314,19 +314,16 @@ def test_grouped_matmul_lowers(chip, cell):
     assert _kernels(text) == {"moe_gmm", "moe_tgmm"}
 
 
-def test_a_rematerialised_mamba_layer_runs_the_scan_kernel_once(
-        chip, monkeypatch):
-    """Two Mamba-2 layers of `nemotron-labs-twotower-30b-a3b.fed_s8k_b2`
-    (64 heads of 64 in 8 groups of a state of 128, chunks of 128, 2 rows of
-    8192) under `remat=True`, the gradient: the SSD kernels lower at the
-    cell's widths, and the block's policy keeps their output and chunk
-    states (`ssd_out`, `ssd_states`), so the step holds ONE `ssd_fwd` a
-    layer beside its `ssd_bwd`."""
+@pytest.fixture(scope="module")
+def mamba_step(chip):
+    """The compiled gradient of two Mamba-2 layers of
+    `nemotron-labs-twotower-30b-a3b.fed_s8k_b2` (64 heads of 64 in 8 groups
+    of a state of 128, chunks of 128, 2 rows of 8192) under `remat=True`,
+    on the kernels."""
     import tensorflowonspark_tpu.ops as ops
     from tensorflowonspark_tpu.models.transformer import (
         Transformer, TransformerConfig, lm_loss)
 
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
     cfg = TransformerConfig(
         vocab_size=1024, d_model=2688, n_heads=32, n_kv_heads=2,
         head_dim=128, n_layers=2, max_seq_len=8192, rope=False,
@@ -345,8 +342,58 @@ def test_a_rematerialised_mamba_layer_runs_the_scan_kernel_once(
     def loss(p, t):
         return lm_loss(model.apply({"params": p}, t), t)
 
-    calls = _kernel_calls(_compile(jax.grad(loss), params, tokens))
-    assert sorted(calls) == ["ssd_bwd"] * 2 + ["ssd_fwd"] * 2, calls
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "default_interpret", lambda: False)
+        return _compile(jax.grad(loss), params, tokens)
+
+
+def test_a_rematerialised_mamba_layer_runs_the_scan_kernel_once(mamba_step):
+    """The SSD kernels and the skip, gate and norm's lower at the cell's
+    widths, and the block's policy keeps the scan's output and chunk
+    states (`ssd_out`, `ssd_states`), so the step holds ONE `ssd_fwd` a
+    layer beside its `ssd_bwd`.  The norm's output is not kept: its
+    `gate_norm_fwd` runs in the forward and again in the recomputed
+    forward, two a layer beside one `gate_norm_bwd`."""
+    calls = _kernel_calls(mamba_step)
+    assert sorted(calls) == (["gate_norm_bwd"] * 2 + ["gate_norm_fwd"] * 4
+                             + ["ssd_bwd"] * 2 + ["ssd_fwd"] * 2), calls
+
+
+def test_the_norm_kernels_lie_in_their_scope_and_no_other_kernels_reader(
+        mamba_step):
+    """What the benchmark reads of a device trace, whose events are these
+    instructions: every `gate_norm_*` kernel falls in the region
+    `layer/mamba/gate_norm` (`benchmark/tracered.region_of` of its
+    `op_name`), the backward's too, where `mamba_gate_norm_ms.nem` reads
+    it, and no metric that finds a kernel by its name takes it for its
+    own among the metrics of the cell."""
+    import json
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import tracered
+
+    cell = "nemotron-labs-twotower-30b-a3b.fed_s8k_b2"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        metrics = [m["name"] for m in json.load(f)["per_layer"]
+                   if cell in m.get("workloads", [cell])]
+
+    kernels = [line for line in mamba_step.splitlines()
+               if "tpu_custom_call" in line and "%gate_norm_" in line]
+    passes = sorted(
+        (tracered.region_of(re.search(r'op_name="([^"]*)"', line).group(1)),
+         "bwd" if "%gate_norm_bwd" in line else "fwd") for line in kernels)
+    here = "layer/mamba/gate_norm"
+    assert passes == [(("backward", here), "bwd")] * 2 + [
+        (("forward", here), "fwd")] * 2 + [(("recomputed", here), "fwd")] * 2
+    for metric in metrics:
+        with open(os.path.join(REPO, "benchmark", "metrics",
+                               metric + ".json")) as f:
+            args = json.load(f).get("args", {})
+        for line in kernels:
+            name = line.strip()
+            assert not ("pattern" in args
+                        and re.search(args["pattern"], name)), metric
+            assert not ("less" in args and re.search(
+                args["less"], tracered.kind_of(name))), metric
 
 
 def test_short_conv_mixer_compiles_to_fusions_and_no_convolution(chip):
