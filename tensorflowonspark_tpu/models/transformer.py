@@ -52,9 +52,10 @@ class TransformerConfig:
     head_dim: Optional[int] = None  # None = d_model // n_heads; set where
     # n_heads * head_dim is not d_model (out is [n_heads*head_dim, d_model])
     layer_types: Optional[tuple] = None  # one kind a layer, "full_attention",
-    # "sliding_attention" or "conv" (None = all full); a sliding layer sees
-    # the last `sliding_window` keys only and rotates by `rope_local_theta`;
-    # a conv layer mixes the sequence with `ShortConv`, not attention
+    # "sliding_attention", "conv" or "kda" (None = all full); a sliding layer
+    # sees the last `sliding_window` keys only and rotates by
+    # `rope_local_theta`; a conv layer mixes the sequence with `ShortConv`, a
+    # kda layer with `KimiDeltaMixer`, not attention
     sliding_window: Optional[int] = None
     layer_pattern: Optional[str] = None  # one character a layer, each layer
     # holding ONE part under one norm, `x + part(norm(x))` (Nemotron-H's
@@ -62,23 +63,31 @@ class TransformerConfig:
     # sparse experts (MoEMLP); None = every layer holds a mixer
     # (`layer_types`) and a feed-forward part
     conv_kernel: int = 3          # taps of a conv layer's depthwise filter
-    # (and of a Mamba-2 mixer's, over x, B and C, with a bias)
+    # (and of a Mamba-2 mixer's, over x, B and C, with a bias; and of a kda
+    # layer's three, over q, k and v, without)
     ssm_heads: int = 0            # Mamba-2 mixer: heads of `ssm_head_dim`
     # lanes of x, each reading the group h // (heads / groups) of the
     # `ssm_groups` groups of B and C of `ssm_state` lanes (`Mamba2Mixer`)
     ssm_head_dim: int = 0
     ssm_groups: int = 1
     ssm_state: int = 0
+    kda_heads: int = 0            # Kimi Delta Attention (`KimiDeltaMixer`):
+    # heads of `kda_head_dim` key and value lanes, each with a state of
+    # `kda_head_dim` x `kda_head_dim`; the decay's and the output gate's
+    # low rank is `kda_head_dim` too
+    kda_head_dim: int = 0
     qk_norm: bool = False         # RMSNorm over head_dim on every query and
     # key head (a scale of head_dim each), before the rotation
     kv_lora_rank: Optional[int] = None  # latent attention (MLA): keys and
     # values come up from a latent of this rank (`kv_a` [D, rank +
     # qk_rope_head_dim], RMSNorm on the latent, `kv_b` [rank, H * (nope +
     # v)]), queries through one of `q_lora_rank` (`q_a`, RMSNorm, `q_b`
-    # [rank, H * (nope + rope)]); a head scores `[q_nope | q_rope]`
-    # against `[k_nope | k_rope]`, where `k_rope` is ONE rotated vector a
-    # token shared by all heads, under a scale of (nope + rope) ** -0.5,
-    # and its values are `v_head_dim` wide (`out` is [H * v, D])
+    # [rank, H * (nope + rope)]; None: `query` [D, H * (nope + rope)]
+    # straight from the input); a head scores `[q_nope | q_rope]` against
+    # `[k_nope | k_rope]`, where `k_rope` is ONE vector a token shared by
+    # all heads (rotated with its query's lanes under `rope`, as it is
+    # without), under a scale of (nope + rope) ** -0.5, and its values are
+    # `v_head_dim` wide (`out` is [H * v, D])
     q_lora_rank: Optional[int] = None
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0     # only these lanes are rotated
@@ -232,19 +241,22 @@ class TransformerConfig:
                                      and self.ssm_state):
             raise ValueError("Mamba layers need ssm_heads, ssm_head_dim and "
                              "ssm_state")
-        if ((CONV in kinds or MAMBA in pattern)
+        if KDA in kinds and not (self.kda_heads and self.kda_head_dim):
+            raise ValueError("kda layers need kda_heads and kda_head_dim")
+        if ((CONV in kinds or KDA in kinds or MAMBA in pattern)
                 and (self.ring_attention_axis or self.ulysses_axis)):
             raise NotImplementedError(
-                "conv and Mamba layers run along a sequence that is whole "
-                "on the device: not with sequence-parallel attention")
+                "conv, kda and Mamba layers run along a sequence that is "
+                "whole on the device: not with sequence-parallel attention")
         latent = self.kv_lora_rank is not None
-        if latent and not (self.q_lora_rank and self.qk_nope_head_dim
-                           and self.qk_rope_head_dim and self.v_head_dim):
+        if latent and not (self.qk_nope_head_dim and self.qk_rope_head_dim
+                           and self.v_head_dim):
             raise ValueError(
-                "kv_lora_rank (latent attention) needs q_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                "kv_lora_rank (latent attention) needs qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim, and q_lora_rank or None "
+                "(the query straight from the input)")
         if latent and (self.ring_attention_axis or self.ulysses_axis
-                       or set(kinds) - {FULL}):
+                       or set(kinds) - {FULL, KDA}):
             raise NotImplementedError(
                 "latent attention with layer kinds or sequence-parallel "
                 "attention")
@@ -262,6 +274,7 @@ class TransformerConfig:
             ("mtp_modules", bool(self.mtp_modules)),
             ("mtp_loss_weight", bool(self.mtp_loss_weight)),
             ("layer_types", bool(kinds)),
+            ("kda_heads", bool(self.kda_heads)),
             ("sliding_window", bool(self.sliding_window)),
             ("qk_norm", self.qk_norm),
             ("moe_experts_held", self.moe_experts_held is not None),
@@ -273,13 +286,15 @@ class TransformerConfig:
             raise NotImplementedError(
                 f"decode=True with {', '.join(missing)}: the kv cache keeps "
                 "no window, no latent, no conv layer's last rows and no "
-                "Mamba state, its incremental attention no layer kinds and "
-                "no query/key norms, routing has no incremental form here "
-                "and a prediction module no consumer (ROADMAP R1/R5)")
+                "Mamba or KDA state, its incremental attention no layer "
+                "kinds and no query/key norms, routing has no incremental "
+                "form here and a prediction module no consumer (ROADMAP "
+                "R1/R5)")
 
 
-FULL, SLIDING, CONV = "full_attention", "sliding_attention", "conv"
-LAYER_KINDS = (FULL, SLIDING, CONV)
+FULL, SLIDING, CONV, KDA = ("full_attention", "sliding_attention", "conv",
+                            "kda")
+LAYER_KINDS = (FULL, SLIDING, CONV, KDA)
 MAMBA = "M"
 PARTS = "M*E"         # `layer_pattern`: Mamba-2, attention, experts
 
@@ -565,9 +580,10 @@ class Attention(nn.Module):
         """Latent attention (MLA; `TransformerConfig.kv_lora_rank`).  On
         the chip the scores and the values go through
         `ops.flash_attention.flash_attention_latent`, which reads the one
-        rotated key a token as it is; under a key-padding mask, a mesh or
-        `attention_impl='dense'` the dense core computes the same over the
-        key written out a head."""
+        shared key a token as it is (rotated under `rope`, as it comes
+        without: Kimi Linear's MLA has no positions); under a key-padding
+        mask, a mesh or `attention_impl='dense'` the dense core computes
+        the same over the key written out a head."""
         cfg = self.cfg
         trace.counters().inc("mixer.calls.latent")      # once a traced call
         dtype = jnp.dtype(cfg.dtype)
@@ -580,20 +596,27 @@ class Attention(nn.Module):
             return nn.RMSNorm(name=name, dtype=jnp.float32,
                               epsilon=cfg.ln_eps)(h).astype(dtype)
 
-        cq = norm("q_a_norm", self._proj("q_a", cfg.q_lora_rank, x, dtype))
-        q = self._proj("q_b", H * (dn + dr), cq, dtype).reshape(
-            B, S, H, dn + dr)
+        if cfg.q_lora_rank is None:
+            q = self._proj("query", H * (dn + dr), x, dtype)
+        else:
+            q = self._proj("q_b", H * (dn + dr), norm(
+                "q_a_norm", self._proj("q_a", cfg.q_lora_rank, x, dtype)),
+                dtype)
+        q = q.reshape(B, S, H, dn + dr)
         kv_a = self._proj("kv_a", rank + dr, x, dtype)
         ckv = norm("kv_a_norm", kv_a[..., :rank])
         kv = self._proj("kv_b", H * (dn + dv), ckv, dtype).reshape(
             B, S, H, dn + dv)
         k_nope, v = kv[..., :dn], kv[..., dn:]
-        rope = dict(inv_freq=rope_inv_freq(dr, cfg.rope_theta),
-                    interleave=cfg.rope_interleave)
-        pos = jnp.arange(S)
-        q = jnp.concatenate(
-            [q[..., :dn], apply_rope(q[..., dn:], pos, **rope)], axis=-1)
-        k_rope = apply_rope(kv_a[:, :, None, rank:], pos, **rope)[:, :, 0]
+        if cfg.rope:
+            rope = dict(inv_freq=rope_inv_freq(dr, cfg.rope_theta),
+                        interleave=cfg.rope_interleave)
+            pos = jnp.arange(S)
+            q = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], pos, **rope)], axis=-1)
+            k_rope = apply_rope(kv_a[:, :, None, rank:], pos, **rope)[:, :, 0]
+        else:                   # no positions: the lanes as they come
+            k_rope = kv_a[..., rank:]
         flash = _flash_wanted(cfg, mask) and _ambient_mesh() is None
         _count_remat(cfg, flash)
         if flash:
@@ -855,6 +878,78 @@ class Mamba2Mixer(nn.Module):
             y = gate_norm(y.reshape(nb, S, inner), xs, zxbcdt, skip, scale,
                           G, cfg.ln_eps, kernel=kernel)
         return proj("out_proj", cfg.d_model, y)
+
+
+def _kda_a_log_init(key, shape, dtype=jnp.float32):
+    """Kimi Linear's: A drawn uniform over [1, 16], a value a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class KimiDeltaMixer(nn.Module):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692), a `kda`
+    layer's sequence mixer, `kda_heads` heads of `kda_head_dim` (P) lanes:
+    `q, k, v = silu(causal_conv(x W))` each (`conv_kernel` taps, no bias),
+    q and k L2-normalised a head (eps 1e-6); `beta = sigmoid(x W_b)` a
+    head; the decay a key channel, `g = -exp(A_log) softplus(f_b(f_a(x)) +
+    dt_bias)` (`f_a` to P lanes); `o = KDA(q, k, v, g, beta)` (`ops.kda`);
+    `o = RMSNorm(o) * w * sigmoid(g_b(g_a(x)))` a head (`w` of P, `g_b`
+    with a bias); `o_proj(o)`.  The recurrence takes its Pallas kernels
+    where `attention_impl` asks for them (`auto` on the chip) and no mesh
+    is set, the same chunked algorithm in `jax.numpy` elsewhere."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from tensorflowonspark_tpu.ops.kda import kda
+
+        cfg = self.cfg
+        if mask is not None:
+            raise NotImplementedError(
+                "key-padding masks are not supported in kda layers")
+        trace.counters().inc("mixer.calls.kda")         # once a traced call
+        dtype = jnp.dtype(cfg.dtype)
+        H, P = cfg.kda_heads, cfg.kda_head_dim
+        inner = H * P
+        nb, S, _ = x.shape
+        f32 = jnp.float32
+
+        def proj(name, features, h, bias=False):
+            return QuantDense(features, use_bias=bias, name=name,
+                              dtype=dtype, impl=cfg.quant_matmul_impl)(h)
+
+        def heads(t):
+            return t.reshape(nb, S, H, P)
+
+        def unit(t):                     # L2 norm a head, in float32
+            t = heads(t).astype(f32)
+            return (t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                      + 1e-6)).astype(dtype)
+
+        qkv = [proj(f"{n}_proj", inner, x) for n in "qkv"]
+        taps = [self.param(f"{n}_conv", nn.initializers.lecun_normal(),
+                           (inner, cfg.conv_kernel), f32).astype(dtype)
+                for n in "qkv"]
+        with jax.named_scope("conv"):
+            q, k, v = (nn.silu(causal_conv(h, w)) for h, w in zip(qkv, taps))
+        with jax.named_scope("decay"):
+            a_log = self.param("A_log", _kda_a_log_init, (H,), f32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32)
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(heads(
+                proj("f_b", inner, proj("f_a", P, x)).astype(f32)
+                + dt_bias))
+            beta = jax.nn.sigmoid(proj("b_proj", H, x).astype(f32))
+        kernel = _flash_wanted(cfg, mask) and _ambient_mesh() is None
+        with jax.named_scope("scan"):
+            o = kda(unit(q), unit(k), heads(v), g, beta, kernel=kernel)
+        with jax.named_scope("out_norm"):
+            gate = proj("g_b", inner, proj("g_a", P, x), bias=True)
+            w = self.param("out_norm", nn.initializers.ones, (P,), f32)
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + cfg.ln_eps) * w
+            o = (o.reshape(nb, S, inner)
+                 * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
+        return proj("o_proj", cfg.d_model, o)
 
 
 def _kv_quantize(x):
@@ -1796,8 +1891,12 @@ class Block(nn.Module):
             return x + mixer(h, mask=mask)
         ln1 = _make_ln(cfg, "ln1")
         ln2 = _make_ln(cfg, "ln2")
-        attn = (ShortConv(cfg, name="conv") if self.layer_type == CONV
-                else Attention(cfg, self.layer_type, name="attn"))
+        if self.layer_type == CONV:
+            attn = ShortConv(cfg, name="conv")
+        elif self.layer_type == KDA:
+            attn = KimiDeltaMixer(cfg, name="kda")
+        else:
+            attn = Attention(cfg, self.layer_type, name="attn")
         mlp = (MoEMLP(cfg, name="moe") if self.use_moe
                else DenseMLP(cfg, name="mlp"))
         x = _sp_constrain(x, cfg)
@@ -1822,11 +1921,13 @@ def remat_block():
     `attention_impl='dense'`, latent attention under a mesh) names nothing
     and is recomputed whole; `remat.attention.saved` / `.rerun` count
     which a traced mixer was.  A Mamba-2 mixer's SSD kernels name their
-    output and chunk states the same way (`ssd_out`, `ssd_states`), so the
-    recomputed block holds no `ssd_fwd` either."""
+    output and chunk states the same way (`ssd_out`, `ssd_states`), and a
+    kda layer's kernels theirs (`kda_out`, `kda_states`), so the
+    recomputed block holds no `ssd_fwd` or `kda_fwd` either."""
     return nn.remat(Block, policy=jax.checkpoint_policies
                     .save_only_these_names("flash_out", "flash_lse",
-                                           "ssd_out", "ssd_states"))
+                                           "ssd_out", "ssd_states",
+                                           "kda_out", "kda_states"))
 
 
 class Transformer(nn.Module):

@@ -96,14 +96,18 @@ Span and counter names of the feed plane (``node.py``, ``feed.py``,
     ``[n, 128]`` copy of it; Lion's leaves count under the same names);
     and of one that traces a transformer block
     (``models.transformer``, the same way): mixer.calls.attention
-    mixer.calls.conv  mixer.calls.latent  mixer.calls.mamba (what a step
-    program's sequence mixers are)  moe.shared.calls (its shared experts);
+    mixer.calls.conv  mixer.calls.latent  mixer.calls.mamba  mixer.calls.kda
+    (what a step program's sequence mixers are)  moe.shared.calls (its
+    shared experts);
     of one that traces a Mamba-2 mixer's scan (``ops.ssd``, the same
     way): ssd.calls.kernel  ssd.calls.reference (the Pallas kernels, or
     the same chunked algorithm in jax.numpy); and of one that traces its
     skip, gate and norm (``ops.gate_norm``, the same way):
     gate_norm.calls.kernel  gate_norm.calls.reference (the
-    ``gate_norm_fwd`` / ``gate_norm_bwd`` kernels, or jax.numpy)
+    ``gate_norm_fwd`` / ``gate_norm_bwd`` kernels, or jax.numpy); and of
+    one that traces a KDA mixer's recurrence (``ops.kda``, the same way):
+    kda.calls.kernel  kda.calls.reference (the ``kda_fwd`` / ``kda_bwd``
+    kernels, or the same chunked algorithm in jax.numpy)
 
 Scopes of the train step in the DEVICE trace (``jax.named_scope``: metadata
 on the operations they cover, a component of each one's ``op_name`` beside
@@ -146,6 +150,21 @@ the step's device time by these paths; after each scope the metric of
         (these three in ``models.transformer.Mamba2Mixer``, under
         ``layer_<n>/mamba/``; with the two projections they are what
         mamba_outside_ssd_ms.nem reads, less the kernels)
+    conv       kda_outside_scan_ms.kim: the causal depthwise filters over
+               q, k and v, and the SiLU after each
+    decay      kda_outside_scan_ms.kim: the decay's low-rank projection,
+               its softplus and scale, and beta's sigmoid
+    scan       kda_kernel_ms.kim (its ``kda_fwd`` / ``kda_bwd`` kernels,
+               which sum the decay along each chunk themselves),
+               kda_outside_scan_ms.kim (the rest): the L2 norms, beta into
+               k and v, forward and backward
+    out_norm   kda_outside_scan_ms.kim: the output gate's projections, the
+               norm a head and the gate's sigmoid
+        (these four in ``models.transformer.KimiDeltaMixer``, under
+        ``layer_<n>/kda/``; with the q, k, v, beta and output projections
+        they are what kda_outside_scan_ms.kim reads, less the kernels.  The
+        kernels' forward rule names its output ``kda_out`` and its chunk
+        states ``kda_states``, which ``remat_block``'s policy keeps)
 
 Lifecycle discipline: a span handed out by :meth:`Recorder.begin` must
 reach exactly one of :meth:`Recorder.end` / :meth:`Recorder.abandon`

@@ -83,7 +83,7 @@ def _compile(fn, *args):
 
 KERNELS = ("flash_fwd", "flash_dq", "flash_dkv", "mla_fwd", "mla_dq",
            "mla_dkv", "adamw_fused", "moe_tgmm", "moe_gmm", "ssd_fwd",
-           "ssd_bwd", "gate_norm_fwd", "gate_norm_bwd")
+           "ssd_bwd", "gate_norm_fwd", "gate_norm_bwd", "kda_fwd", "kda_bwd")
 
 
 def _kernel_calls(text):
@@ -394,6 +394,81 @@ def test_the_norm_kernels_lie_in_their_scope_and_no_other_kernels_reader(
                         and re.search(args["pattern"], name)), metric
             assert not ("less" in args and re.search(
                 args["less"], tracered.kind_of(name))), metric
+
+
+@pytest.fixture(scope="module")
+def kda_step(chip):
+    """The compiled gradient of two KDA layers of
+    `kimi-linear-48b-a3b.fed_s8k_b2` (32 heads of 128 over a width of 2304,
+    convs of 4 taps, 2 rows of 8192) under `remat=True`, on the kernels."""
+    import tensorflowonspark_tpu.ops as ops
+    from tensorflowonspark_tpu.models.transformer import (
+        Transformer, TransformerConfig, lm_loss)
+
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=2304, n_heads=32, n_layers=2, d_ff=1024,
+        max_seq_len=8192, rope=False, learned_positions=False,
+        layer_types=("kda", "kda"), kda_heads=32, kda_head_dim=128,
+        conv_kernel=4, norm_type="rmsnorm", ln_eps=1e-5, mlp_style="gated",
+        activation="silu", dtype="bfloat16", attention_impl="flash",
+        remat=True)
+    model = Transformer(cfg)
+    tokens = chip((2, 8192), jnp.int32)
+    one = tokens.sharding
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+
+    def loss(p, t):
+        return lm_loss(model.apply({"params": p}, t), t)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "default_interpret", lambda: False)
+        return _compile(jax.grad(loss), params, tokens)
+
+
+def test_kda_kernels_run_once_a_layer_in_their_scope_and_no_other_reader(
+        kda_step):
+    """The KDA kernels lower at the cell's widths; the block's policy keeps
+    their output and chunk states (`kda_out`, `kda_states`), so the step
+    holds ONE `kda_fwd` a layer beside its `kda_bwd`; each falls in the
+    region `layer/kda/scan` (`benchmark/tracered.region_of` of its
+    `op_name`), and among the cell's metrics only `kda_kernel_ms.kim` and
+    `kda_roofline.kim` find them by name, and only
+    `kda_outside_scan_ms.kim` takes them out of its regions."""
+    import json
+    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    import tracered
+
+    calls = _kernel_calls(kda_step)
+    assert sorted(calls) == ["kda_bwd"] * 2 + ["kda_fwd"] * 2, calls
+    kernels = [line for line in kda_step.splitlines()
+               if "tpu_custom_call" in line and "%kda_" in line]
+    passes = sorted(
+        (tracered.region_of(re.search(r'op_name="([^"]*)"', line).group(1)),
+         "bwd" if "%kda_bwd" in line else "fwd") for line in kernels)
+    here = "layer/kda/scan"
+    assert passes == [(("backward", here), "bwd")] * 2 + [
+        (("forward", here), "fwd")] * 2
+    cell = "kimi-linear-48b-a3b.fed_s8k_b2"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        metrics = [m["name"] for m in json.load(f)["per_layer"]
+                   if cell in m.get("workloads", [cell])]
+    found, taken = set(), set()
+    for metric in metrics:
+        with open(os.path.join(REPO, "benchmark", "metrics",
+                               metric + ".json")) as f:
+            args = json.load(f).get("args", {})
+        for line in kernels:
+            name = line.strip()
+            if "pattern" in args and re.search(args["pattern"], name):
+                found.add(metric)
+            if "less" in args and re.search(args["less"],
+                                            tracered.kind_of(name)):
+                taken.add(metric)
+    assert found == {"kda_kernel_ms.kim", "kda_roofline.kim"}
+    assert taken == {"kda_outside_scan_ms.kim"}
 
 
 def test_short_conv_mixer_compiles_to_fusions_and_no_convolution(chip):
