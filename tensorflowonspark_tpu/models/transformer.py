@@ -891,11 +891,17 @@ class KimiDeltaMixer(nn.Module):
     `q, k, v = silu(causal_conv(x W))` each (`conv_kernel` taps, no bias),
     q and k L2-normalised a head (eps 1e-6); `beta = sigmoid(x W_b)` a
     head; the decay a key channel, `g = -exp(A_log) softplus(f_b(f_a(x)) +
-    dt_bias)` (`f_a` to P lanes); `o = KDA(q, k, v, g, beta)` (`ops.kda`);
+    dt_bias)` (`f_a` to P lanes); `o = KDA(q, k, v, g, beta)`;
     `o = RMSNorm(o) * w * sigmoid(g_b(g_a(x)))` a head (`w` of P, `g_b`
-    with a bias); `o_proj(o)`.  The recurrence takes its Pallas kernels
-    where `attention_impl` asks for them (`auto` on the chip) and no mesh
-    is set, the same chunked algorithm in `jax.numpy` elsewhere."""
+    with a bias); `o_proj(o)`.  `ops.kda` takes q, k, v and g as the convs
+    and the decay leave them, `[B, S, H * P]`, and beta `[B, H, S]`, and
+    gives `RMSNorm(o) * w` in the same layout: the L2 norms, beta's scaling
+    and the norm a head are its own, so the mixer makes no view a head.
+    The convs' output goes to it in float32, as XLA computes it: rounded
+    to bf16 before the norms, it took the training further from float32.
+    The recurrence takes its Pallas kernels where `attention_impl` asks
+    for them (`auto` on the chip) and no mesh is set, the same chunked
+    algorithm in `jax.numpy` elsewhere."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -910,45 +916,34 @@ class KimiDeltaMixer(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         H, P = cfg.kda_heads, cfg.kda_head_dim
         inner = H * P
-        nb, S, _ = x.shape
         f32 = jnp.float32
 
         def proj(name, features, h, bias=False):
             return QuantDense(features, use_bias=bias, name=name,
                               dtype=dtype, impl=cfg.quant_matmul_impl)(h)
 
-        def heads(t):
-            return t.reshape(nb, S, H, P)
-
-        def unit(t):                     # L2 norm a head, in float32
-            t = heads(t).astype(f32)
-            return (t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
-                                      + 1e-6)).astype(dtype)
-
         qkv = [proj(f"{n}_proj", inner, x) for n in "qkv"]
         taps = [self.param(f"{n}_conv", nn.initializers.lecun_normal(),
                            (inner, cfg.conv_kernel), f32).astype(dtype)
                 for n in "qkv"]
         with jax.named_scope("conv"):
-            q, k, v = (nn.silu(causal_conv(h, w)) for h, w in zip(qkv, taps))
+            q, k, v = (nn.silu(causal_conv(h, w)).astype(f32)
+                       for h, w in zip(qkv, taps))
         with jax.named_scope("decay"):
             a_log = self.param("A_log", _kda_a_log_init, (H,), f32)
             dt_bias = self.param("dt_bias", _dt_bias_init, (inner,), f32)
-            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(heads(
-                proj("f_b", inner, proj("f_a", P, x)).astype(f32)
-                + dt_bias))
-            beta = jax.nn.sigmoid(proj("b_proj", H, x).astype(f32))
+            g = -jnp.repeat(jnp.exp(a_log), P) * jax.nn.softplus(
+                proj("f_b", inner, proj("f_a", P, x)).astype(f32) + dt_bias)
+            beta = jax.nn.sigmoid(proj("b_proj", H, x).astype(f32)
+                                  ).transpose(0, 2, 1)          # [B, H, S]
+        w = self.param("out_norm", nn.initializers.ones, (P,), f32)
         kernel = _flash_wanted(cfg, mask) and _ambient_mesh() is None
         with jax.named_scope("scan"):
-            o = kda(unit(q), unit(k), heads(v), g, beta, kernel=kernel)
+            o = kda(q, k, v, g, beta, w, cfg.ln_eps, kernel=kernel,
+                    dtype=dtype)
         with jax.named_scope("out_norm"):
             gate = proj("g_b", inner, proj("g_a", P, x), bias=True)
-            w = self.param("out_norm", nn.initializers.ones, (P,), f32)
-            o = o.astype(f32)
-            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                                  + cfg.ln_eps) * w
-            o = (o.reshape(nb, S, inner)
-                 * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
+            o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(dtype)
         return proj("o_proj", cfg.d_model, o)
 
 

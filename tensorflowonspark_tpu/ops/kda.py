@@ -39,18 +39,43 @@ forward again from its inputs and its starting state, and carries the
 state's gradient the same way.  The forward rule names its output `kda_out`
 and the chunk states `kda_states` (`jax.ad_checkpoint.checkpoint_name`): a
 rematerialised block whose policy saves them
-(`models.transformer.remat_block`) does not run `kda_fwd` again.  The
-kernels take every operand heads-major, `[B, H, S, D]`: read in the
-projections' own `[B, S, H * D]`, a head's lanes a block, the mixer's
-views a head (`[B, S, H, D]`, its L2 norms) and back cost the step two
-relayouts an operand, more than the transposes cost.  Products run in
-float32.  Chunks of 128 (the default): against 64, on the chip at the
-cell's shape of `benchmark/configs/kimi-linear-48b-a3b.json`, `kda_fwd`
-10.3 against 14.0 ms a call and `kda_bwd` 25.1 against 26.2, and half the
-chunk states.
+(`models.transformer.remat_block`) does not run `kda_fwd` again; the
+backward reads the raw operands, which the block recomputes anyway.
 
-`kda(..., kernel=False)` computes the same chunked algorithm in `jax.numpy`
-(XLA's autodiff, no kernel): the CPU's path.  The process counters
+The kernels read q, k, v and g as the convs and the decay lay them out,
+`[B, S, H * D]`, a chunk of head h the block `(1, C, D)` at lane tile h,
+and beta as a row of `[B, H, 1, S]`, turned into a column in VMEM.  Each
+row-local step around the recurrence is theirs: the L2 norms of q and k,
+`beta k` and `beta v` (all float32, never rounded in between), and the
+head's RMSNorm and weight on the output, whose un-normalised value the
+backward computes again from the chunk's forward.  The backward carries
+the gradients back through the norm, the recurrence, beta and the L2 norms
+and writes dq, dk, dv, dg in the same layout, dbeta as a row and the
+norm weight's gradient summed over a (row, head)'s chunks.  So no view a
+head and no heads-major copy stands between the projections and the
+kernels.  Taking the operands heads-major, `[B, H, S, D]`, cost a
+transpose each way around every call; reading them in this layout with the
+norms and beta left to XLA cost two relayouts of its view a head
+(`[B, S, H, D]`) an operand instead, more than the transposes.  With the
+norms inside, on one TPU v5e at the cell's shape of
+`benchmark/configs/kimi-linear-48b-a3b.json`, the mixers' XLA work
+outside the kernels fell from 320.0 to 200.9 ms a step and the step from
+834 to 731 ms, the kernels rising from 142.7 to 159.0.
+
+q, k and v come in float32, the convs' output as XLA computes it, and o,
+dq, dk and dv go out in the activations' type (`dtype`).  An f32 product
+in these kernels is one bf16 pass of the MXU (Mosaic's default precision),
+which rounds the normalised q and k and `beta k`, `beta v` once; the
+convs' output rounded to bf16 before the norms as well put the cell's
+training on the chip several times further from its float32 reference.
+Chunks of 128 (the default): against 64, at the
+cell's shape, `kda_fwd` 10.3 against 14.0 ms a call and `kda_bwd` 25.1
+against 26.2, and half the chunk states.  Natively a head must be whole
+lane tiles of 128.
+
+`kda(..., kernel=False)` computes the same prologue, chunked algorithm and
+epilogue in `jax.numpy`, on views a head (XLA's autodiff, no kernel): the
+CPU's path and the path under a mesh.  The process counters
 `kda.calls.kernel` and `kda.calls.reference` say which a traced call took.
 """
 import functools
@@ -66,45 +91,69 @@ from tensorflowonspark_tpu.ops.ssd import _column, _dot, _dot_t, _row
 
 _F32 = jnp.float32
 SUB = 16            # positions of a sub-chunk
+_L2_EPS = 1e-6      # of the L2 norms of q and k
 
 
-def kda(q, k, v, g, beta, chunk=128, kernel=False, interpret=None):
-    """`o` [batch, S, H, D] in v's type, before the output norm.
+def kda(q, k, v, g, beta, w, eps, chunk=128, kernel=False, interpret=None,
+        dtype=None):
+    """Each head's KDA output under its RMSNorm, `RMSNorm(o) * w`,
+    `[batch, S, H * D]` in `dtype` (None: v's type).
 
-    `q`, `k` [batch, S, H, D] (L2-normalised over D), `v` [batch, S, H, D];
-    `g` [batch, S, H, D] float32, the log decay of each key channel (<= 0);
-    `beta` [batch, S, H] float32.  A row is padded to whole chunks of
-    `chunk` (zeros: no input, no decay) and cut back after.  `kernel`: the
-    Pallas kernels (`interpret`: None = on the chip natively, elsewhere in
-    the interpreter); else the same algorithm in `jax.numpy`."""
-    nb, S, H, D = q.shape
+    `q`, `k`, `v` [batch, S, H * D] as the convs leave them, a head D lanes
+    (q and k not yet normalised: each head's L2 norm, eps 1e-6, is taken
+    here, in float32); `g` [batch, S, H * D] float32, the log decay of each
+    key channel (<= 0); `beta` [batch, H, S] float32, which scales k and v
+    (in float32) before the recurrence; `w` [D] float32 and `eps`, the head
+    norm's weight and epsilon, applied to o in float32.  A row is padded to
+    whole chunks of `chunk` (zeros: no input, no decay) and cut back after.
+    `kernel`: the Pallas kernels, which read and write every operand as it
+    lies (`interpret`: None = on the chip natively, elsewhere in the
+    interpreter; natively, D must be whole lane tiles of 128), which write
+    o and the gradients of q, k and v in `dtype` and hand the gradients on
+    in the inputs' types; else the same algorithm in `jax.numpy`, on views
+    a head."""
+    nb, S, width = q.shape
+    H, D = beta.shape[1], w.shape[0]
+    dtype = jnp.dtype(dtype or v.dtype)
     if chunk % SUB:
         raise ValueError(f"chunk {chunk} is not whole sub-chunks of {SUB}")
     pad = -S % chunk
-    kb = (k.astype(_F32) * beta[..., None]).astype(k.dtype)
-    vb = (v.astype(_F32) * beta[..., None]).astype(v.dtype)
-    if pad:
-        def padded(t):
-            return jnp.pad(t, [(0, 0), (0, pad), (0, 0), (0, 0)])
-        q, k, kb, vb, g = (padded(t) for t in (q, k, kb, vb, g))
     scale = D ** -0.5
     if kernel:
         trace.counters().inc("kda.calls.kernel")       # once a traced call
         if interpret is None:
             from tensorflowonspark_tpu.ops import default_interpret
             interpret = default_interpret()
-
-        def heads(t):
-            return t.transpose(0, 2, 1, 3)
-
-        o = heads(_kda(heads(q), heads(k), heads(kb), heads(vb), heads(g),
-                       chunk, scale, bool(interpret)))
+        if not interpret and D % 128:
+            raise ValueError(
+                f"the KDA kernels take a head as whole lane tiles of 128 on "
+                f"the chip; kda_head_dim={D} is not")
+        if pad:
+            q, k, v, g = (jnp.pad(t, [(0, 0), (0, pad), (0, 0)])
+                          for t in (q, k, v, g))
+            beta = jnp.pad(beta, [(0, 0), (0, 0), (0, pad)])
+        o = _kda(q, k, v, g, beta[:, :, None, :], w[None, :], chunk, scale,
+                 eps, bool(interpret), dtype)
     else:
         trace.counters().inc("kda.calls.reference")
+
+        def heads(t):
+            t = t.reshape(nb, S, H, D).astype(_F32)
+            return jnp.pad(t, [(0, 0), (0, pad), (0, 0), (0, 0)])
+
+        def unit(t):
+            return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True)
+                                     + _L2_EPS)
+
+        b = jnp.pad(beta.transpose(0, 2, 1), [(0, 0), (0, pad), (0, 0)])
+        b = b[..., None]
+        q, k, v = unit(heads(q)), unit(heads(k)), heads(v)
         n = (S + pad) // chunk
-        G = jnp.cumsum(g.reshape(nb, n, chunk, H, D), axis=2).reshape(
+        G = jnp.cumsum(heads(g).reshape(nb, n, chunk, H, D), axis=2).reshape(
             nb, -1, H, D)
-        o = _kda_jnp(q, k, kb, vb, G, chunk, scale)
+        o = _kda_jnp(q, k, b * k, b * v, G, chunk, scale)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        o = (o * w).reshape(nb, -1, width).astype(dtype)
     return o[:, :S]
 
 
@@ -140,7 +189,7 @@ def _products_jnp(xs, y, G, c):
 
 def _kda_jnp(q, k, kb, vb, G, L, scale):
     """The chunked algorithm in `jax.numpy`: `[b, S, H, D]` operands, S
-    whole chunks of L; T applied by a triangular solve."""
+    whole chunks of L; T applied by a triangular solve; o in float32."""
     nb, S, H, D = q.shape
     n = S // L
 
@@ -167,7 +216,7 @@ def _kda_jnp(q, k, kb, vb, G, L, scale):
 
     _, o = jax.lax.scan(step, jnp.zeros((nb, H, D, D), _F32),
                         (w, big_u, q * eg, aqk, kh, jnp.exp(last)))
-    return o.transpose(1, 0, 3, 2, 4).reshape(nb, S, H, D).astype(vb.dtype)
+    return o.transpose(1, 0, 3, 2, 4).reshape(nb, S, H, D)
 
 
 # ---- the kernels -----------------------------------------------------------
@@ -319,46 +368,72 @@ def _chunk(q, k, kb, vb, G, S, c):
     return aqk, t, w, big_u, big_u - _dot(w, S)
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, st_ref, s_scr,
-                *, scale):
-    """One chunk of one (row, head): its output, the state at its start
-    written out, the state carried on in VMEM."""
+def _inputs(q_ref, k_ref, v_ref, beta_ref):
+    """A chunk of a head's operands in float32, as the recurrence takes
+    them: `(q, 1/|q|, k, 1/|k|, v, beta)`, q and k L2-normalised a row,
+    beta the column `[C, 1]` of its row `[1, C]`."""
+    def unit(x):
+        r = jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + _L2_EPS)
+        return x * r, r
+
+    q, rq = unit(q_ref[0].astype(_F32))
+    k, rk = unit(k_ref[0].astype(_F32))
+    return q, rq, k, rk, v_ref[0].astype(_F32), _column(beta_ref[0, 0])
+
+
+def _norm(y, eps):
+    """`y` under RMSNorm a row (without its weight), and 1/rms."""
+    r = jax.lax.rsqrt(jnp.mean(y * y, axis=1, keepdims=True) + eps)
+    return y * r, r
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, o_ref, st_ref,
+                s_scr, *, scale, eps):
+    """One chunk of one (row, head): its output under the head's norm, the
+    state at its start written out, the state carried on in VMEM."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    q, k, kb, vb = (r[0, 0].astype(_F32) for r in (q_ref, k_ref, kb_ref,
-                                                   vb_ref))
-    G, S = _running(g_ref[0, 0]), s_scr[...]
+    q, _, k, _, v, beta = _inputs(q_ref, k_ref, v_ref, beta_ref)
+    G, S = _running(g_ref[0]), s_scr[...]
     C = q.shape[0]
-    aqk, _, _, _, u = _chunk(q, k, kb, vb, G, S, SUB)
+    aqk, _, _, _, u = _chunk(q, k, beta * k, beta * v, G, S, SUB)
     o = scale * (_dot(q * jnp.exp(G), S) + _dot(aqk, u))
-    o_ref[0, 0] = o.astype(o_ref.dtype)
+    o_ref[0] = (_norm(o, eps)[0] * w_ref[...]).astype(o_ref.dtype)
     st_ref[0, 0, 0] = S
     last = G[C - 1:C, :]
     s_scr[...] = _column(jnp.exp(last)) * S + _dot_tl(
         k * jnp.exp(last - G), u)
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
-                dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, ds_scr, *, scale):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, st_ref, dn_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dnw_ref, ds_scr,
+                *, scale, eps):
     """One chunk of one (row, head), the chunks taken last first: the
-    chunk's forward again, the gradients of its inputs, the gradient of the
-    state at its start carried on."""
+    chunk's forward again, the gradients of its inputs through the head's
+    norm, the recurrence, beta and the L2 norms, the gradient of the state
+    at its start carried on, the norm's weight's summed over the chunks."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_scr[...] = jnp.zeros_like(ds_scr)
+        dnw_ref[...] = jnp.zeros_like(dnw_ref)
 
-    q, k, kb, vb = (r[0, 0].astype(_F32) for r in (q_ref, k_ref, kb_ref,
-                                                   vb_ref))
-    G, S = _running(g_ref[0, 0]), st_ref[0, 0, 0]
-    do = scale * do_ref[0, 0].astype(_F32)
+    q, rq, k, rk, v, beta = _inputs(q_ref, k_ref, v_ref, beta_ref)
+    kb, vb = beta * k, beta * v
+    G, S = _running(g_ref[0]), st_ref[0, 0, 0]
     ds1 = ds_scr[...]                       # d(the state passed on)
     C = q.shape[0]
     rows, cols = _iota((C, C), 0), _iota((C, C), 1)
     aqk, t, w, big_u, u = _chunk(q, k, kb, vb, G, S, SUB)
     eg = jnp.exp(G)
     qe, kbe = q * eg, kb * eg
+    # out = n w, n = o r, r = (mean(o^2) + eps)^-1/2;  o = scale (qe S + Aqk u)
+    n, r = _norm(scale * (_dot(qe, S) + _dot(aqk, u)), eps)
+    dn = dn_ref[0].astype(_F32)
+    dnw_ref[0, 0] += jnp.sum(dn * n, axis=0, keepdims=True)
+    dn = dn * w_ref[...]
+    do = scale * r * (dn - n * jnp.mean(dn * n, axis=1, keepdims=True))
     last = G[C - 1:C, :]
     keep, dec = jnp.exp(last), jnp.exp(last - G)
     kh = k * dec
@@ -382,79 +457,104 @@ def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, st_ref, do_ref,
     dg = (dkbe * kbe + dqe * qe - dkh * kh + q * r_q + kb * r_kb - k * z
           + jnp.where(_iota((C, 1), 0) == C - 1, dlast, 0.0))
     ds_scr[...] = ds0
-    dq_ref[0, 0] = (dqe * eg + r_q).astype(dq_ref.dtype)
-    dk_ref[0, 0] = (dkh * dec + z).astype(dk_ref.dtype)
-    dkb_ref[0, 0] = (dkbe * eg + r_kb).astype(dkb_ref.dtype)
-    dvb_ref[0, 0] = dvb.astype(dvb_ref.dtype)
-    dg_ref[0, 0] = _running(dg, back=True)
+    # kb = beta k, vb = beta v;  q, k = x r, r = (|x|^2 + eps)^-1/2
+    dkb = dkbe * eg + r_kb
+    dq = dqe * eg + r_q
+    dk = dkh * dec + z + beta * dkb
+    dq_ref[0] = (rq * (dq - q * jnp.sum(q * dq, axis=1, keepdims=True))
+                 ).astype(dq_ref.dtype)
+    dk_ref[0] = (rk * (dk - k * jnp.sum(k * dk, axis=1, keepdims=True))
+                 ).astype(dk_ref.dtype)
+    dv_ref[0] = (beta * dvb).astype(dv_ref.dtype)
+    dg_ref[0] = _running(dg, back=True)
+    dbeta_ref[0, 0] = _row(jnp.sum(dkb * k + dvb * v, axis=1,
+                                   keepdims=True))
 
 
-def _specs(q, L, reverse):
-    """Block specs over the grid (row, head, chunk): a chunk of a head of
-    `[B, H, S, D]`, a chunk's state of `[B, H, chunks, D, D]`; `reverse`
-    walks the chunks last first."""
-    D = q.shape[3]
-    n = q.shape[2] // L
-
+def _specs(D, n, L, reverse):
+    """Block specs over the grid (row, head, chunk): a chunk of a head's
+    lanes of `[B, S, H * D]`, a chunk of a head's row of `[B, H, 1, S]`,
+    a chunk's state of `[B, H, chunks, D, D]`, the norm's weight `[1, D]`;
+    `reverse` walks the chunks last first."""
     def at(c):
         return n - 1 - c if reverse else c
 
-    return n, (pl.BlockSpec((1, 1, L, D), lambda b, h, c: (b, h, at(c), 0)),
-               pl.BlockSpec((1, 1, 1, D, D),
-                            lambda b, h, c: (b, h, at(c), 0, 0)))
+    return (pl.BlockSpec((1, L, D), lambda b, h, c: (b, at(c), h)),
+            pl.BlockSpec((1, 1, 1, L), lambda b, h, c: (b, h, 0, at(c))),
+            pl.BlockSpec((1, 1, 1, D, D),
+                         lambda b, h, c: (b, h, at(c), 0, 0)),
+            pl.BlockSpec((1, D), lambda b, h, c: (0, 0)))
 
 
-def _call(kernel, name, q, n, in_specs, out_specs, out_shape, interpret,
-          scale):
-    nb, H, _, D = q.shape
+def _call(kernel, name, grid, D, in_specs, out_specs, out_shape, interpret,
+          scale, eps):
     return pl.pallas_call(
-        functools.partial(kernel, scale=scale), name=name,
-        interpret=interpret, grid=(nb, H, n), in_specs=in_specs,
+        functools.partial(kernel, scale=scale, eps=eps), name=name,
+        interpret=interpret, grid=grid, in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((D, D), _F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")))
 
 
+def _grid(q, beta, L):
+    """`(grid, D)`: (rows, heads, chunks) and a head's lanes."""
+    nb, H, _, S = beta.shape
+    return (nb, H, S // L), q.shape[2] // H
+
+
 # Both implementations are jitted on their own, as the SSD kernels are: a
 # model calls them once a layer with the same shapes.
-@functools.partial(jax.jit, static_argnames=("L", "scale", "interpret"))
-def _fwd_impl(q, k, kb, vb, g, L, scale, interpret):
-    """`(o [b, H, S, D], states f32[b, H, S / L, D, D])`."""
-    nb, H, _, D = q.shape
-    n, (seq, state) = _specs(q, L, reverse=False)
-    return _call(_fwd_kernel, "kda_fwd", q, n, [seq] * 5, [seq, state],
-                 [jax.ShapeDtypeStruct(q.shape, vb.dtype),
-                  jax.ShapeDtypeStruct((nb, H, n, D, D), _F32)],
-                 interpret, scale)(q, k, kb, vb, g)
+@functools.partial(jax.jit, static_argnames=("L", "scale", "eps",
+                                             "interpret", "dtype"))
+def _fwd_impl(q, k, v, g, beta, w, L, scale, eps, interpret, dtype):
+    """`(out [b, S, H * D], states f32[b, H, S / L, D, D])`."""
+    grid, D = _grid(q, beta, L)
+    seq, row, state, weight = _specs(D, grid[2], L, reverse=False)
+    return _call(_fwd_kernel, "kda_fwd", grid, D,
+                 [seq] * 4 + [row, weight], [seq, state],
+                 [jax.ShapeDtypeStruct(q.shape, dtype),
+                  jax.ShapeDtypeStruct(grid + (D, D), _F32)],
+                 interpret, scale, eps)(q, k, v, g, beta, w)
 
 
-@functools.partial(jax.jit, static_argnames=("L", "scale", "interpret"))
-def _bwd_impl(q, k, kb, vb, g, states, do, L, scale, interpret):
-    """`(dq, dk, dkb, dvb, dg)` in the operands' shapes and types."""
-    n, (seq, state) = _specs(q, L, reverse=True)
-    return tuple(_call(
-        _bwd_kernel, "kda_bwd", q, n, [seq] * 5 + [state, seq], [seq] * 5,
-        [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (q, k, kb, vb, g)],
-        interpret, scale)(q, k, kb, vb, g, states, do))
+@functools.partial(jax.jit, static_argnames=("L", "scale", "eps",
+                                             "interpret", "dtype"))
+def _bwd_impl(q, k, v, g, beta, w, states, dout, L, scale, eps, interpret,
+              dtype):
+    """`(dq, dk, dv, dg, dbeta, dw)` in the operands' shapes and types,
+    dq, dk and dv written in `dtype`."""
+    grid, D = _grid(q, beta, L)
+    seq, row, state, weight = _specs(D, grid[2], L, reverse=True)
+    by_head = pl.BlockSpec((1, 1, 1, D), lambda b, h, c: (b, h, 0, 0))
+    *grads, dw = _call(
+        _bwd_kernel, "kda_bwd", grid, D,
+        [seq] * 4 + [row, weight, state, seq], [seq] * 4 + [row, by_head],
+        [jax.ShapeDtypeStruct(t.shape, dtype) for t in (q, k, v)]
+        + [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in (g, beta)]
+        + [jax.ShapeDtypeStruct(grid[:2] + (1, D), _F32)],
+        interpret, scale, eps)(q, k, v, g, beta, w, states, dout)
+    return (*(d.astype(t.dtype) for d, t in zip(grads, (q, k, v, g, beta))),
+            jnp.sum(dw, axis=(0, 1)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _kda(q, k, kb, vb, g, L, scale, interpret):
-    return _fwd_impl(q, k, kb, vb, g, L, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _kda(q, k, v, g, beta, w, L, scale, eps, interpret, dtype):
+    return _fwd_impl(q, k, v, g, beta, w, L, scale, eps, interpret, dtype)[0]
 
 
-def _kda_vjp_fwd(q, k, kb, vb, g, L, scale, interpret):
-    o, states = _fwd_impl(q, k, kb, vb, g, L, scale, interpret)
-    o = checkpoint_name(o, "kda_out")
+def _kda_vjp_fwd(q, k, v, g, beta, w, L, scale, eps, interpret, dtype):
+    out, states = _fwd_impl(q, k, v, g, beta, w, L, scale, eps, interpret,
+                            dtype)
+    out = checkpoint_name(out, "kda_out")
     states = checkpoint_name(states, "kda_states")
-    return o, (q, k, kb, vb, g, states)
+    return out, (q, k, v, g, beta, w, states)
 
 
-def _kda_vjp_bwd(L, scale, interpret, res, do):
-    q, k, kb, vb, g, states = res
-    return _bwd_impl(q, k, kb, vb, g, states, do.astype(vb.dtype), L, scale,
-                     interpret)
+def _kda_vjp_bwd(L, scale, eps, interpret, dtype, res, dout):
+    q, k, v, g, beta, w, states = res
+    return _bwd_impl(q, k, v, g, beta, w, states, dout.astype(dtype), L,
+                     scale, eps, interpret, dtype)
 
 
 _kda.defvjp(_kda_vjp_fwd, _kda_vjp_bwd)

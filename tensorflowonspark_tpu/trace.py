@@ -155,11 +155,12 @@ the step's device time by these paths; after each scope the metric of
     decay      kda_outside_scan_ms.kim: the decay's low-rank projection,
                its softplus and scale, and beta's sigmoid
     scan       kda_kernel_ms.kim (its ``kda_fwd`` / ``kda_bwd`` kernels,
-               which sum the decay along each chunk themselves),
-               kda_outside_scan_ms.kim (the rest): the L2 norms, beta into
-               k and v, forward and backward
-    out_norm   kda_outside_scan_ms.kim: the output gate's projections, the
-               norm a head and the gate's sigmoid
+               which sum the decay along each chunk, take the L2 norms,
+               scale k and v by beta and apply the norm a head
+               themselves), kda_outside_scan_ms.kim (the rest: little
+               more than the norm weight's gradient summed)
+    out_norm   kda_outside_scan_ms.kim: the output gate's projections and
+               its sigmoid
         (these four in ``models.transformer.KimiDeltaMixer``, under
         ``layer_<n>/kda/``; with the q, k, v, beta and output projections
         they are what kda_outside_scan_ms.kim reads, less the kernels.  The

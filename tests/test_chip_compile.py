@@ -471,6 +471,31 @@ def test_kda_kernels_run_once_a_layer_in_their_scope_and_no_other_reader(
     assert taken == {"kda_outside_scan_ms.kim"}
 
 
+def test_kda_kernels_read_and_write_the_projections_layout(kda_step):
+    """The KDA kernels take q, k, v and g as the projections and convs
+    leave them, `[B, S, H*128]`, beta as a row a head, and write the
+    output under the head's norm and the gradients the same way, so no
+    `copy`, `transpose` or `reshape` of the step makes or undoes a layout a
+    head of an array of that size: neither heads-major `[2,32,8192,128]`
+    nor the view a head `[2,8192,32,128]`, in bf16 or f32."""
+    b, s, h, d = 2, 8192, 32, 128
+    kernels = [line for line in kda_step.splitlines()
+               if "tpu_custom_call" in line and "%kda_" in line]
+    shapes = {
+        m.group(1) for line in kernels
+        for m in re.finditer(r"(?:bf16|f32)\[([\d,]+)\]",
+                             line.split("frontend_attributes")[0])}
+    assert shapes == {f"{b},{s},{h * d}", f"{b},{h},1,{s}", f"1,{d}",
+                      f"{b},{h},{s // 128},{d},{d}", f"{b},{h},1,{d}"}, shapes
+    staged = [
+        line.strip()[:120]
+        for line in kda_step[kda_step.index("\nENTRY"):].splitlines()
+        for m in [re.match(r"\s*(?:ROOT )?%[\w.-]+ = (?:bf16|f32)\[([\d,]+)\]"
+                           r"\S* (?:copy|transpose|reshape)\(", line)]
+        if m and math.prod(map(int, m.group(1).split(","))) == b * s * h * d]
+    assert not staged, staged
+
+
 def test_short_conv_mixer_compiles_to_fusions_and_no_convolution(chip):
     """The conv layer's mixer at `lfm2-8b-a1b.fed_s8k_b2`'s shapes, forward
     and gradient: three matmuls a direction and elementwise fusions; no

@@ -46,41 +46,75 @@ def _counted(fn, *names):
 # ---- (a) the chunked delta rule against the recurrence ----------------------
 
 def _unit(x):
-    return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    """The reference's L2 norm a head."""
+    return x / jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
 def _kda_inputs(key, S, H=2, D=16, decay=1.0):
-    k = jax.random.split(key, 6)
-    q = _unit(jax.random.normal(k[0], (2, S, H, D)))
-    kk = _unit(jax.random.normal(k[1], (2, S, H, D)))
-    v = jax.random.normal(k[2], (2, S, H, D))
-    g = -decay * jax.nn.softplus(jax.random.normal(k[3], (2, S, H, D)) - 2.0)
-    beta = jax.nn.sigmoid(jax.random.normal(k[4], (2, S, H)))
-    return q, kk, v, g, beta, jax.random.normal(k[5], (2, S, H, D))
+    """q, k, v, g `[2, S, H * D]` as the mixer gives them to `kda` (q and k
+    not normalised), beta `[2, H, S]`, the head norm's weight `[D]`, and a
+    cotangent."""
+    k = jax.random.split(key, 7)
+    q = 2.0 * jax.random.normal(k[0], (2, S, H * D))
+    kk = jax.random.normal(k[1], (2, S, H * D))
+    v = jax.random.normal(k[2], (2, S, H * D))
+    g = -decay * jax.nn.softplus(
+        jax.random.normal(k[3], (2, S, H * D)) - 2.0)
+    beta = jax.nn.sigmoid(jax.random.normal(k[4], (2, H, S)))
+    w = 0.5 + jax.random.uniform(k[5], (D,))
+    return q, kk, v, g, beta, w, jax.random.normal(k[6], (2, S, H * D))
 
 
-def _recurrence(q, k, v, g, beta, fault=None):
-    return FAMILY._delta(q, k, v, g, beta, fault)
+EPS = 1e-5      # the head norm's
 
 
-def _matches(q, k, v, g, beta, w, chunk):
-    """Forward and the five gradients of the chunked form, on the kernels
-    and on the `jax.numpy` chunks, against the recurrence's."""
+def _recurrence(q, k, v, g, beta, w=None, fault=None):
+    """The reference on views a head: the L2 norms of q and k, then the
+    recurrence, which scales k and v by beta itself; then, given `w`, the
+    head's RMSNorm."""
+    nb, S, _ = q.shape
+    H = beta.shape[1]
+
+    def heads(t):
+        return t.reshape(nb, S, H, -1)
+
+    o = FAMILY._delta(_unit(heads(q)), _unit(heads(k)), heads(v), heads(g),
+                      beta.transpose(0, 2, 1), fault)
+    if w is not None:
+        o = o / jnp.sqrt(jnp.mean(o * o, axis=-1, keepdims=True) + EPS) * w
+    return o.reshape(nb, S, -1)
+
+
+def _on_o_scale(out, o, w):
+    """`out`, KDA's output under the head norm, taken back to the scale of
+    the reference's un-normalised `o` by o's own rms a head: compared
+    there, the tolerance is on o's error, not on o's error over its rms."""
+    heads = out.shape[:2] + (-1, w.shape[0])
+    rms = jnp.sqrt(jnp.mean(o.reshape(heads) ** 2, axis=-1, keepdims=True)
+                   + EPS)
+    return (out.reshape(heads) / w * rms).reshape(out.shape)
+
+
+def _matches(q, k, v, g, beta, w, ct, chunk, kernels=(True, False)):
+    """Forward and the six gradients (q, k, v, g, beta, the norm's weight)
+    of the chunked form under the head norm, on the kernels and on the
+    `jax.numpy` chunks, against the recurrence's."""
     def loss(fn):
-        return lambda *a: jnp.sum(fn(*a) * w)
+        return lambda *a: jnp.sum(fn(*a) * ct)
 
-    args = (q, k, v, g, beta)
+    args = (q, k, v, g, beta, w)
+    every = tuple(range(6))
     with jax.default_matmul_precision("highest"):
-        want = _recurrence(*args)
-        want_g = jax.grad(loss(_recurrence), argnums=(0, 1, 2, 3, 4))(*args)
-        for kernel in (True, False):
+        want = _recurrence(*args[:5])
+        want_g = jax.grad(loss(_recurrence), argnums=every)(*args)
+        for kernel in kernels:
             def fn(*a):
-                return kda(*a, chunk, kernel=kernel, interpret=True)
+                return kda(*a, EPS, chunk, kernel=kernel, interpret=True)
 
-            np.testing.assert_allclose(fn(*args), want, rtol=1e-5,
-                                       atol=2e-6)
-            got_g = jax.grad(loss(fn), argnums=(0, 1, 2, 3, 4))(*args)
-            for name, a, b in zip(("q", "k", "v", "g", "beta"), got_g,
+            np.testing.assert_allclose(_on_o_scale(fn(*args), want, w),
+                                       want, rtol=1e-5, atol=2e-6)
+            got_g = jax.grad(loss(fn), argnums=every)(*args)
+            for name, a, b in zip(("q", "k", "v", "g", "beta", "w"), got_g,
                                   want_g):
                 scale = float(jnp.max(jnp.abs(b)))
                 np.testing.assert_allclose(a, b, atol=2e-5 * scale,
@@ -90,6 +124,14 @@ def _matches(q, k, v, g, beta, w, chunk):
 def test_kda_matches_the_recurrence_and_its_gradients():
     """Three whole chunks of 32 and half of a fourth."""
     _matches(*_kda_inputs(jax.random.key(112), 112), 32)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernels", "chunks"])
+def test_kda_matches_the_recurrence_at_the_cells_head_width(kernel):
+    """Heads of 128, as the cell's, two of them, a row of one chunk of 32
+    and a half: the lanes of a head are one tile of `[B, S, H * 128]`."""
+    _matches(*_kda_inputs(jax.random.key(128), 48, D=128), 32,
+             kernels=(kernel,))
 
 
 def test_a_decay_that_overflows_a_factored_form():
@@ -106,29 +148,63 @@ def test_a_decay_that_overflows_a_factored_form():
 def test_a_state_crosses_three_chunks():
     """Keys and values in the first chunk only, a slow decay: what the
     fourth chunk's queries read came across three chunk boundaries."""
-    q, k, v, g, beta, _ = _kda_inputs(jax.random.key(3), 64, decay=0.01)
-    k = k.at[:, 16:].set(0.0)
+    q, k, v, g, beta, w, _ = _kda_inputs(jax.random.key(3), 64,
+                                         decay=0.01)
+    k = k.at[:, 16:].set(0.0)                 # a zero key stays zero
     v = v.at[:, 16:].set(0.0)
     late = slice(48, 64)
     with jax.default_matmul_precision("highest"):
         want = _recurrence(q, k, v, g, beta)[:, late]
         assert float(jnp.abs(want).mean()) > 1e-2
         for kernel in (True, False):
-            got = kda(q, k, v, g, beta, 16, kernel=kernel, interpret=True)
-            np.testing.assert_allclose(got[:, late], want, rtol=1e-5,
-                                       atol=1e-6)
+            got = kda(q, k, v, g, beta, w, EPS, 16, kernel=kernel,
+                      interpret=True)
+            np.testing.assert_allclose(_on_o_scale(got[:, late], want, w),
+                                       want, rtol=1e-5, atol=1e-6)
 
 
 def test_kda_calls_are_counted_by_path_and_chunks_checked():
-    q, k, v, g, beta, _ = _kda_inputs(jax.random.key(4), 32)
+    q, k, v, g, beta, w, _ = _kda_inputs(jax.random.key(4), 32)
     for kernel, name in ((True, "kda.calls.kernel"),
                          (False, "kda.calls.reference")):
         got = _counted(lambda: jax.jit(lambda *a: kda(
-            *a, 16, kernel=kernel, interpret=True)).lower(q, k, v, g, beta),
+            *a, EPS, 16, kernel=kernel, interpret=True)).lower(
+                q, k, v, g, beta, w),
             "kda.calls.kernel", "kda.calls.reference")
         assert got == ([1, 0] if kernel else [0, 1])
     with pytest.raises(ValueError, match="sub-chunks"):
-        kda(q, k, v, g, beta, 24)
+        kda(q, k, v, g, beta, w, EPS, 24)
+    # natively the kernels take a head as whole lane tiles
+    with pytest.raises(ValueError, match="kda_head_dim=16"):
+        kda(q, k, v, g, beta, w, EPS, 16, kernel=True, interpret=False)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernels", "chunks"])
+def test_float32_inputs_give_a_bfloat16_output(kernel):
+    """The mixer hands `kda` the convs' output in float32 and asks for o in
+    bfloat16: o comes in `dtype`, the gradients in the inputs' types, both
+    the float32 result's to bfloat16's rounding."""
+    q, k, v, g, beta, w, ct = _kda_inputs(jax.random.key(5), 48)
+    args = (q, k, v, g, beta, w)
+
+    def loss(dtype):
+        return lambda *a: jnp.sum(kda(*a, EPS, 16, kernel=kernel,
+                                      interpret=True, dtype=dtype
+                                      ).astype(jnp.float32) * ct)
+
+    with jax.default_matmul_precision("highest"):
+        want = kda(*args, EPS, 16, kernel=kernel, interpret=True)
+        got = kda(*args, EPS, 16, kernel=kernel, interpret=True,
+                  dtype=jnp.bfloat16)
+        want_g = jax.grad(loss(None), argnums=tuple(range(6)))(*args)
+        got_g = jax.grad(loss(jnp.bfloat16), argnums=tuple(range(6)))(*args)
+    assert got.dtype == jnp.bfloat16 and want.dtype == jnp.float32
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=1e-2,
+                               atol=1e-2)
+    for name, a, b in zip(("q", "k", "v", "g", "beta", "w"), got_g, want_g):
+        assert a.dtype == b.dtype == jnp.float32, name
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(a, b, atol=2e-2 * scale, err_msg=name)
 
 
 # ---- (b) the mixers -----------------------------------------------------------
